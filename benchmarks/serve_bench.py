@@ -168,7 +168,9 @@ def _cold_vs_warm(model, params) -> dict:
 
 # sharded scenario: must run in a subprocess — the forced-host device
 # count is fixed at jax import, and this process needs its real single
-# device for every other scenario
+# device for every other scenario.  The child is pinned to the CPU: on a
+# TPU host this process holds the chip, so a child that wanted it would
+# fail or hang
 _SHARDED_SHARDS = 4
 _SHARDED_CHILD = """
 import dataclasses, json, jax, numpy as np
@@ -239,6 +241,7 @@ def _sharded_scenario() -> dict:
     env["XLA_FLAGS"] = (
         f"--xla_force_host_platform_device_count={_SHARDED_SHARDS} "
         + env.get("XLA_FLAGS", ""))
+    env["JAX_PLATFORMS"] = "cpu"
     out = subprocess.run(
         [sys.executable, "-c", _SHARDED_CHILD % {"shards": _SHARDED_SHARDS}],
         capture_output=True, text=True, env=env, timeout=1200)

@@ -71,11 +71,11 @@ from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels.autotune import AttnCall, fmt_tuple, register_kernel
 from repro.kernels.common import (
-    INTERPRET,
     N_STATS,
     ROUNDINGS,
     quantize_block,
     quantize_block_sr,
+    resolve_interpret,
     sr_random_bits,
     stats_delta_row,
     stats_update,
@@ -155,16 +155,22 @@ LOG2E = 1.4426950408889634
 
 _WIDE = (8, 23)
 
+# the softmax weights and scores are f32 values that bf16 cannot hold, so
+# the in-block contractions ask for full f32 precision rather than the
+# backend's default MXU passes (read at trace time)
+PV_PRECISION = jax.lax.Precision.HIGHEST
+
 
 def _pv(p: jnp.ndarray, v: jnp.ndarray) -> jnp.ndarray:
     """Batched ``probs @ values`` contraction in f32: p (..., G, T) with
     v (..., T, D) -> (..., G, D).  One helper shared by the kernels (2D
     operands) and the references (batched operands) so the ideal intra-block
-    contraction is the same primitive in both."""
+    contraction is the same primitive in both, at ``PV_PRECISION``."""
     nb = p.ndim - 2
     batch = tuple(range(nb))
     return jax.lax.dot_general(
         p, v, (((p.ndim - 1,), (nb,)), (batch, batch)),
+        precision=PV_PRECISION,
         preferred_element_type=jnp.float32)
 
 
@@ -419,7 +425,7 @@ def flash_prefill(
     carry: tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray] | None = None,
     return_carry: bool = False,
     call: AttnCall | None = None,
-    interpret: bool = INTERPRET,
+    interpret: bool | None = None,
     rounding: str = "rne",
     sr_seed: int = 0,
 ) -> jnp.ndarray | tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
@@ -487,7 +493,8 @@ def flash_prefill(
                           e_acc=int(e_acc), m_acc=int(m_acc),
                           chunk=int(chunk), block_q=int(block_q),
                           q_offset=int(q_offset), kv_offset=int(kv_offset),
-                          emit_carry=bool(return_carry), interpret=interpret,
+                          emit_carry=bool(return_carry),
+                          interpret=resolve_interpret(interpret),
                           rounding=rounding, sr_seed=int(sr_seed))
 
 
@@ -764,7 +771,7 @@ def paged_attn_decode(
     acc: tuple[int, int] = _WIDE,
     collect_stats: bool = False,
     return_carry: bool = False,
-    interpret: bool = INTERPRET,
+    interpret: bool | None = None,
 ):
     """One decode token of attention per sequence against the paged cache.
 
@@ -815,7 +822,7 @@ def paged_attn_decode(
         packed=packed, e_kv=int(e_kv), m_kv=int(m_kv),
         e_acc=int(e_acc), m_acc=int(m_acc),
         collect_stats=collect_stats, return_carry=return_carry,
-        interpret=interpret)
+        interpret=resolve_interpret(interpret))
     if collect_stats:
         o, stats = out
         return o.reshape(b, h, dh), stats
@@ -1094,7 +1101,7 @@ def flash_prefill_paged(
     start_page=0,
     return_carry: bool = False,
     call: AttnCall | None = None,
-    interpret: bool = INTERPRET,
+    interpret: bool | None = None,
 ) -> jnp.ndarray | tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
     """Bucketed causal prefill straight off the paged KV arena.
 
@@ -1170,7 +1177,8 @@ def flash_prefill_paged(
         jnp.asarray(page_row, jnp.int32), geom, carry_o, carry_m, carry_l,
         packed=packed, e_kv=int(e_kv), m_kv=int(m_kv),
         e_acc=int(e_acc), m_acc=int(m_acc), block_q=int(block_q),
-        emit_carry=bool(return_carry), interpret=interpret)
+        emit_carry=bool(return_carry),
+        interpret=resolve_interpret(interpret))
 
 
 def flash_prefill_paged_reference(q, k_pages, v_pages, k_se, v_se, page_row,

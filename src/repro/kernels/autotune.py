@@ -3,10 +3,11 @@
 The Pallas GEMMs are parameterized by an MXU block decomposition
 (block_m, block_n, block_k).  Only block_m / block_n are free perf knobs;
 block_k is *numerics*: for a narrow accumulator it IS the paper's chunk
-length n1 (the carry is rounded once per K-tile), and even for the wide
-degenerate path it fixes the f32 partial-sum grouping — so the tuner pins
-it (to the policy's chunk, or 128 for wide) and results never depend on
-what is in the tuning table.
+length n1 (the carry is rounded once per n1 products; the kernel loads K
+tiles of ``chunk_tile(n1)`` and folds their chunks in order), and even for
+the wide degenerate path it fixes the f32 partial-sum grouping — so the
+tuner pins it (to the policy's chunk, or 128 for wide) and results never
+depend on what is in the tuning table.
 
 Components:
 
@@ -28,10 +29,9 @@ Components:
   tuples are static under jit, so the lookup is pure Python at trace time
   and free at run time).
 
-On this CPU container the timings run in Pallas interpret mode — a proxy
-that ranks by work per block decomposition, not TPU silicon truth (see
-ROADMAP open items for on-device validation).  The table format is the
-contract; re-tuning on real hardware just rewrites the JSON.
+On the CPU the timings run in Pallas interpret mode — a proxy that ranks
+by work per block decomposition, not TPU silicon truth.  The table format
+is the contract; re-tuning on real hardware just rewrites the JSON.
 """
 
 from __future__ import annotations
@@ -45,11 +45,15 @@ from typing import Any, Callable
 
 import jax
 
+from repro.runtime import CHECKOUT
+
 __all__ = [
     "register_kernel",
     "get_kernel",
     "registered_kernels",
     "VMEM_PER_GENERATION",
+    "DEVICE_KINDS",
+    "device_generation",
     "vmem_budget",
     "vmem_block_bytes",
     "candidate_blocks",
@@ -116,25 +120,49 @@ VMEM_PER_GENERATION = {
     "v6e": 32 * 2**20,
 }
 
+# ``device_kind`` as JAX reports it -> generation
+DEVICE_KINDS = {
+    "TPU v4": "v4",
+    "TPU v5 lite": "v5e",
+    "TPU v5e": "v5e",
+    "TPU v5": "v5p",
+    "TPU v5p": "v5p",
+    "TPU v6 lite": "v6e",
+    "TPU v6e": "v6e",
+}
+
+# what a run off the TPU (the CPU interpreter) plans its tiles for: the
+# chip this repository is measured on
+HOST_GENERATION = "v5e"
+
+
+def device_generation(device=None) -> str:
+    """The TPU generation of ``device`` (default: the first device), from
+    its ``device_kind``.  Off the TPU it is ``HOST_GENERATION``; on a TPU
+    whose kind is not known here it is an error, never a guess."""
+    device = device or jax.devices()[0]
+    if device.platform != "tpu":
+        return HOST_GENERATION
+    gen = DEVICE_KINDS.get(device.device_kind)
+    if gen is None:
+        raise ValueError(
+            f"unknown TPU device_kind {device.device_kind!r}; add it to "
+            f"repro.kernels.autotune.DEVICE_KINDS (known: {sorted(DEVICE_KINDS)})")
+    return gen
+
 
 def vmem_budget(generation: str | None = None) -> int:
     """The VMEM working-set budget for one grid step: half the generation's
-    VMEM (REPRO_TPU_GENERATION, default v4), or REPRO_VMEM_BUDGET verbatim
-    when set."""
+    VMEM (default: the generation of the device this process runs on), or
+    REPRO_VMEM_BUDGET verbatim when set."""
     env = os.environ.get("REPRO_VMEM_BUDGET")
     if env:
         return int(env)
-    gen = generation or os.environ.get("REPRO_TPU_GENERATION", "v4")
-    return VMEM_PER_GENERATION.get(gen, VMEM_PER_GENERATION["v4"]) // 2
+    return VMEM_PER_GENERATION[generation or device_generation()] // 2
 
 
 # alias for functions whose keyword argument shadows the name
 _vmem_budget = vmem_budget
-
-# import-time snapshot, kept for callers that want a plain constant; code
-# in this package resolves vmem_budget() dynamically so REPRO_TPU_GENERATION
-# set after import is still honored
-VMEM_BUDGET_BYTES = vmem_budget()
 
 # MXU-aligned tile edges the tuner considers (lane width 128 and multiples).
 _TILE_EDGES = (128, 256, 512)
@@ -170,8 +198,7 @@ def candidate_blocks(m: int, k: int, n: int, *, chunk: int = 0,
     schedule-only (the per-output-element reduction order over K is
     untouched) — are tuned.
 
-    ``vmem_budget=None`` resolves the generation ceiling at call time, so
-    REPRO_TPU_GENERATION set after import is honored.
+    ``vmem_budget=None`` resolves the generation ceiling at call time.
     """
     if vmem_budget is None:
         vmem_budget = _vmem_budget()
@@ -180,12 +207,15 @@ def candidate_blocks(m: int, k: int, n: int, *, chunk: int = 0,
         padded = max(-(-dim // 128) * 128, 128)
         return [t for t in _TILE_EDGES if t <= padded] or [128]
 
+    from repro.kernels.common import chunk_tile
+
     bk = chunk if chunk > 0 else 128
+    tile_k = chunk_tile(bk)  # the K tile the kernel loads for this chunk
     out = [
         (bm, bn, bk)
         for bm in edges(m)
         for bn in edges(n)
-        if vmem_block_bytes(bm, bn, bk, emit_quantized=emit_quantized,
+        if vmem_block_bytes(bm, bn, tile_k, emit_quantized=emit_quantized,
                             operand_bytes=operand_bytes,
                             residual_bytes=residual_bytes) <= vmem_budget
     ]
@@ -213,9 +243,7 @@ def time_kernel(fn: Callable, *args, reps: int = 3) -> float:
 # --------------------------------------------------------------------------
 
 DEFAULT_TABLE_PATH = os.environ.get(
-    "REPRO_AUTOTUNE_TABLE",
-    os.path.join(os.path.expanduser("~"), ".cache", "repro", "autotune.json"),
-)
+    "REPRO_AUTOTUNE_TABLE", os.path.join(CHECKOUT, ".autotune", "table.json"))
 
 
 def operand_dtype(a_packed: bool = False, b_packed: bool = False) -> str:
@@ -674,10 +702,14 @@ def autotune_bwd_pair(
     xq = _rand_operand(kx, (t, k), packed, repr_fmt)
     wq = _rand_operand(kw, (k, n), packed, repr_fmt)
 
-    np_ = max(-(-n // bn) * bn, bn)
+    from repro.kernels.common import chunk_tile
+
+    tile_t, tile_n = chunk_tile(bt), chunk_tile(bn)
+    np_ = max(-(-n // tile_n) * tile_n, tile_n)
     cands = [bk for bk in _TILE_EDGES
              if bk <= max(-(-k // 128) * 128, 128)
-             and pair_vmem_bytes(bt, bk, bn, np_, packed=packed) <= budget]
+             and pair_vmem_bytes(tile_t, bk, tile_n, np_,
+                                 packed=packed) <= budget]
     cands = cands or [128]
     best: tuple[float, int] | None = None
     for bk in cands:

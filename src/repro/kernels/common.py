@@ -13,7 +13,10 @@ __all__ = [
     "threefry2x32",
     "sr_random_bits",
     "ROUNDINGS",
-    "INTERPRET",
+    "LANES",
+    "chunk_tile",
+    "fold_chunks",
+    "resolve_interpret",
     "pad2d",
     "count_pallas_calls",
     "count_pallas_executions",
@@ -32,10 +35,63 @@ __all__ = [
     "stats_update",
 ]
 
-# Pallas kernels target TPU; on any other backend (this container is
-# CPU-only) they run in interpret mode, which executes the kernel body with
-# the same block decomposition.
-INTERPRET = jax.default_backend() != "tpu"
+def resolve_interpret(interpret: bool | None) -> bool:
+    """``interpret=None`` means: interpret only on the CPU backend, asked at
+    call time.  Pallas kernels target the TPU; on the CPU the interpreter
+    runs the kernel body with the same block decomposition.  A TPU that
+    failed to start therefore makes the kernels interpret visibly on a CPU
+    backend rather than behind an import-time constant."""
+    if interpret is None:
+        return jax.default_backend() == "cpu"
+    return bool(interpret)
+
+
+# Lane width of a TPU vector register: the minor dimension of a kernel's
+# block shape must be a multiple of it (or span the whole array).
+LANES = 128
+
+
+def chunk_tile(n1: int) -> int:
+    """The contraction tile that holds a whole number of n1-chunks: the
+    lane width when n1 divides it, else n1 itself when it is a multiple of
+    the lane width.  The chunk length n1 is numerics (the paper's chunk);
+    the tile is the block the TPU can load.  Any other n1 is refused."""
+    n1 = int(n1)
+    if n1 > 0 and LANES % n1 == 0:
+        return LANES
+    if n1 > 0 and n1 % LANES == 0:
+        return n1
+    raise ValueError(
+        f"chunk length n1={n1} must divide {LANES} or be a multiple of it: "
+        f"the TPU loads contraction tiles in multiples of {LANES} lanes")
+
+
+def fold_chunks(acc, a, b, dims=(((1,), (0,)), ((), ())), *, n1, tile_idx,
+                rounding, seed_ref, e_acc, m_acc, row0, col0, n_cols,
+                on_chunk=None):
+    """Fold one contraction tile into a carry, one n1-chunk at a time: each
+    chunk's product is contracted in ideal f32 (intra-chunk) and the carry
+    is rounded after it (inter-chunk) under the chunk's GLOBAL index
+    ``tile_idx * (tile // n1) + s``.  A tile of several chunks thus keeps
+    the carry order and the SR dither stream of one chunk per grid step.
+    ``on_chunk(prev, new, partial, last)`` observes each update (the
+    telemetry epilogues); ``last`` marks the tile's final chunk."""
+    (ca,), (cb,) = dims[0]
+    per = a.shape[ca] // n1
+    for s in range(per):
+        lo, hi = s * n1, (s + 1) * n1
+        partial = jax.lax.dot_general(
+            jax.lax.slice_in_dim(a, lo, hi, axis=ca),
+            jax.lax.slice_in_dim(b, lo, hi, axis=cb),
+            dims, preferred_element_type=jnp.float32)
+        prev = acc
+        acc = carry_update(
+            prev, partial, e_acc=e_acc, m_acc=m_acc, rounding=rounding,
+            seed_ref=seed_ref, step=tile_idx * per + s, row0=row0,
+            col0=col0, n_cols=n_cols)
+        if on_chunk is not None:
+            on_chunk(prev, acc, partial, s == per - 1)
+    return acc
 
 
 def pad2d(x: jnp.ndarray, rows: int, cols: int,

@@ -56,12 +56,13 @@ from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels.autotune import fmt_tuple, register_kernel
 from repro.kernels.common import (
-    INTERPRET,
     N_STATS,
     ROUNDINGS,
-    carry_update,
+    chunk_tile,
+    fold_chunks,
     pad2d,
     quantize_block,
+    resolve_interpret,
     stats_delta_row,
     stats_update,
 )
@@ -80,7 +81,6 @@ def as_sr_seed(seed) -> jnp.ndarray:
 
 # identity quantization (folds away inside quantize_block at trace time)
 _WIDE = (8, 23)
-_carry_update = carry_update
 
 
 def _load_operand(ref, *, packed: bool, q: bool, e_r: int, m_r: int):
@@ -105,7 +105,7 @@ def _emit_output(o_ref, acc, *, e_o: int, m_o: int, pack_out: bool):
 
 
 def _fused_kernel(*refs, e_r, m_r, qa, qb, e_acc, m_acc, a_packed, b_packed,
-                  e_o, m_o, pack_out, rounding, n):
+                  e_o, m_o, pack_out, rounding, n, n1):
     if rounding == "sr":
         a_ref, b_ref, seed_ref, o_ref, acc_ref = refs
     else:
@@ -119,13 +119,12 @@ def _fused_kernel(*refs, e_r, m_r, qa, qb, e_acc, m_acc, a_packed, b_packed,
     # representation quantization / unpacking of the operand tiles (VPU)
     a = _load_operand(a_ref, packed=a_packed, q=qa, e_r=e_r, m_r=m_r)
     b = _load_operand(b_ref, packed=b_packed, q=qb, e_r=e_r, m_r=m_r)
-    # intra-chunk: one MXU tile contraction, ideal (f32) accumulation
-    partial = jnp.dot(a, b, preferred_element_type=jnp.float32)
-    # inter-chunk: carry update rounded to the (1, e_acc, m_acc) format
+    # intra-chunk: MXU contractions in ideal (f32) accumulation; inter-chunk:
+    # carry update rounded to the (1, e_acc, m_acc) format
     bm, bn = acc_ref.shape
-    acc_ref[...] = _carry_update(
-        acc_ref[...], partial, e_acc=e_acc, m_acc=m_acc, rounding=rounding,
-        seed_ref=seed_ref, step=pl.program_id(2),
+    acc_ref[...] = fold_chunks(
+        acc_ref[...], a, b, n1=n1, tile_idx=pl.program_id(2),
+        rounding=rounding, seed_ref=seed_ref, e_acc=e_acc, m_acc=m_acc,
         row0=pl.program_id(0) * bm, col0=pl.program_id(1) * bn, n_cols=n)
 
     @pl.when(pl.program_id(2) == pl.num_programs(2) - 1)
@@ -134,7 +133,7 @@ def _fused_kernel(*refs, e_r, m_r, qa, qb, e_acc, m_acc, a_packed, b_packed,
 
 
 def _fused_kernel_emitq(*refs, e_r, m_r, qa, qb, e_acc, m_acc, packr,
-                        e_o, m_o, pack_out, rounding, n):
+                        e_o, m_o, pack_out, rounding, n, n1):
     if rounding == "sr":
         a_ref, b_ref, seed_ref, o_ref, aq_ref, bq_ref, acc_ref = refs
     else:
@@ -159,11 +158,10 @@ def _fused_kernel_emitq(*refs, e_r, m_r, qa, qb, e_acc, m_acc, packr,
     def _store_b():
         bq_ref[...] = pack_block(b, e_r, m_r) if packr else b
 
-    partial = jnp.dot(a, b, preferred_element_type=jnp.float32)
     bm, bn = acc_ref.shape
-    acc_ref[...] = _carry_update(
-        acc_ref[...], partial, e_acc=e_acc, m_acc=m_acc, rounding=rounding,
-        seed_ref=seed_ref, step=pl.program_id(2),
+    acc_ref[...] = fold_chunks(
+        acc_ref[...], a, b, n1=n1, tile_idx=pl.program_id(2),
+        rounding=rounding, seed_ref=seed_ref, e_acc=e_acc, m_acc=m_acc,
         row0=pl.program_id(0) * bm, col0=pl.program_id(1) * bn, n_cols=n)
 
     @pl.when(pl.program_id(2) == pl.num_programs(2) - 1)
@@ -173,7 +171,7 @@ def _fused_kernel_emitq(*refs, e_r, m_r, qa, qb, e_acc, m_acc, packr,
 
 def _fused_kernel_stats(*refs, e_r, m_r, qa, qb, e_acc, m_acc,
                         a_packed, b_packed, e_o, m_o, pack_out,
-                        m, n, block_m, block_n, rounding):
+                        m, n, block_m, block_n, rounding, n1):
     """The swamping-telemetry variant (``collect_stats=True``): the SAME
     chunked accumulation — identical values, identical order — plus a wide
     (f32) shadow carry and an (1, N_STATS) stats reduction (see
@@ -204,17 +202,6 @@ def _fused_kernel_stats(*refs, e_r, m_r, qa, qb, e_acc, m_acc,
 
     a = _load_operand(a_ref, packed=a_packed, q=qa, e_r=e_r, m_r=m_r)
     b = _load_operand(b_ref, packed=b_packed, q=qb, e_r=e_r, m_r=m_r)
-    partial = jnp.dot(a, b, preferred_element_type=jnp.float32)
-
-    prev = acc_ref[...]
-    new = _carry_update(
-        prev, partial, e_acc=e_acc, m_acc=m_acc, rounding=rounding,
-        seed_ref=seed_ref, step=kk,
-        row0=i * block_m, col0=j * block_n, n_cols=n)
-    acc_ref[...] = new
-    ideal = ideal_ref[...] + partial
-    ideal_ref[...] = ideal
-
     # valid-region mask: zero-padding is a fixed point of the whole pipeline
     # (the padded outputs are exact), but including them in the ensemble
     # would bias the variance estimate toward zero
@@ -223,8 +210,19 @@ def _fused_kernel_stats(*refs, e_r, m_r, qa, qb, e_acc, m_acc,
     cols = j * block_n + jax.lax.broadcasted_iota(
         jnp.int32, (block_m, block_n), 1)
     mask = (rows < m) & (cols < n)
-    delta, step_max = stats_delta_row(new, prev, ideal, partial, mask, last_k)
-    stats_update(stats_acc, delta[None, :], step_max[None])
+    ideal = [ideal_ref[...]]
+
+    def on_chunk(prev, new, partial, last):
+        ideal[0] = ideal[0] + partial
+        delta, step_max = stats_delta_row(new, prev, ideal[0], partial, mask,
+                                          last_k & last)
+        stats_update(stats_acc, delta[None, :], step_max[None])
+
+    acc_ref[...] = fold_chunks(
+        acc_ref[...], a, b, n1=n1, tile_idx=kk, rounding=rounding,
+        seed_ref=seed_ref, e_acc=e_acc, m_acc=m_acc, row0=i * block_m,
+        col0=j * block_n, n_cols=n, on_chunk=on_chunk)
+    ideal_ref[...] = ideal[0]
 
     @pl.when(last_k)
     def _emit():
@@ -239,16 +237,17 @@ def _fused_kernel_stats(*refs, e_r, m_r, qa, qb, e_acc, m_acc,
 @functools.partial(
     jax.jit,
     static_argnames=("e_r", "m_r", "e_acc", "m_acc", "block_m", "block_n",
-                     "block_k", "qa", "qb", "emitq", "packr", "a_packed",
+                     "n1", "qa", "qb", "emitq", "packr", "a_packed",
                      "b_packed", "e_o", "m_o", "pack_out", "collect_stats",
                      "rounding", "interpret"),
 )
 def _qmatmul_fused(a, b, sr_seed, *, e_r, m_r, e_acc, m_acc, block_m,
-                   block_n, block_k, qa, qb, emitq, packr, a_packed,
+                   block_n, n1, qa, qb, emitq, packr, a_packed,
                    b_packed, e_o, m_o, pack_out, collect_stats=False,
                    rounding="rne", interpret=False):
     m, k = a.shape
     _, n = b.shape
+    block_k = chunk_tile(n1)
     a32 = pad2d(a, block_m, block_k, dtype=jnp.int8 if a_packed else jnp.float32)
     b32 = pad2d(b, block_k, block_n, dtype=jnp.int8 if b_packed else jnp.float32)
     mp, kp = a32.shape
@@ -256,7 +255,8 @@ def _qmatmul_fused(a, b, sr_seed, *, e_r, m_r, e_acc, m_acc, block_m,
     grid = (mp // block_m, np_ // block_n, kp // block_k)
 
     kw = dict(e_r=e_r, m_r=m_r, qa=qa, qb=qb, e_acc=e_acc, m_acc=m_acc,
-              e_o=e_o, m_o=m_o, pack_out=pack_out, rounding=rounding, n=n)
+              e_o=e_o, m_o=m_o, pack_out=pack_out, rounding=rounding, n=n,
+              n1=n1)
     in_specs = [
         pl.BlockSpec((block_m, block_k), lambda i, j, kk: (i, kk)),
         pl.BlockSpec((block_k, block_n), lambda i, j, kk: (kk, j)),
@@ -354,7 +354,7 @@ def qmatmul_fused(
     collect_stats: bool = False,
     rounding: str = "rne",
     sr_seed=0,
-    interpret: bool = INTERPRET,
+    interpret: bool | None = None,
 ):
     """C[M, N] = Q(A)[M, K] @ Q(B)[K, N] with chunked (1, e_acc, m_acc)
     accumulation, quantization fused into the GEMM (one ``pallas_call``).
@@ -367,9 +367,13 @@ def qmatmul_fused(
     * ``a_packed`` / ``b_packed`` — the operand arrives as int8 ``(1, e_r,
       m_r)`` codes (a ``QTensor`` payload) and is unpacked in VMEM; implies
       the operand needs no quantization.
-    * ``block_k`` is the chunk length n1; ``block_m``/``block_n`` are
+    * ``block_k`` is the chunk length n1 (numerics: the carry is rounded
+      once per n1 products).  It must divide 128 or be a multiple of it:
+      the kernel loads K tiles of ``chunk_tile(n1)`` elements and folds
+      each tile's chunks in order.  ``block_m``/``block_n`` are
       schedule-only (any choice is bit-identical — the per-output-element
       reduction order over K is fixed).
+    * ``interpret=None`` interprets on the CPU backend only.
     * ``return_quantized=True`` returns ``(c, q_a, q_b)``: the quantized
       operands are emitted from the same kernel for residual saving, as int8
       codes when ``pack_residuals=True`` (each block written on its first
@@ -416,11 +420,11 @@ def qmatmul_fused(
     return _qmatmul_fused(
         a, b, as_sr_seed(sr_seed),
         e_r=int(e_r), m_r=int(m_r), e_acc=e_acc, m_acc=m_acc,
-        block_m=block_m, block_n=block_n, block_k=block_k,
+        block_m=block_m, block_n=block_n, n1=int(block_k),
         qa=quantize_a and not a_packed, qb=quantize_b and not b_packed,
         emitq=return_quantized, packr=pack_residuals,
         a_packed=a_packed, b_packed=b_packed,
         e_o=int(e_o), m_o=int(m_o), pack_out=pack_out,
         collect_stats=collect_stats, rounding=rounding,
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )

@@ -58,7 +58,7 @@ from repro.kernels.bwd_pair import (
     qmatmul_bwd_pair,
     qmatmul_bwd_pair_nsplit,
 )
-from repro.kernels.common import ROUNDINGS, threefry2x32
+from repro.kernels.common import ROUNDINGS, chunk_tile, threefry2x32
 from repro.kernels.fused import qmatmul_fused
 from repro.kernels.qmatmul import qmatmul_pallas
 from repro.kernels.quantize import quantize_pallas
@@ -203,12 +203,13 @@ def pair_n_segments(cfg: QDotConfig, t: int, k: int, n: int,
         return 0
     if vmem is None:
         vmem = vmem_budget()
-    bt, bn = _pair_chunks(cfg)
+    n1_t, n1_n = _pair_chunks(cfg)
+    bt, bn = chunk_tile(n1_t), chunk_tile(n1_n)  # the kernel's T / N tiles
     for s in range(1, MAX_PAIR_SEGMENTS + 1):
-        seg = pair_segment_width(n, s, bn)
+        seg = pair_segment_width(n, s, n1_n)
         if pair_vmem_bytes(bt, 128, bn, seg, packed=cfg.packs) <= vmem:
             return s
-        if seg == bn:  # already a single chunk wide; no smaller segment
+        if seg == bn:  # already a single tile wide; no smaller segment
             break
     return 0
 

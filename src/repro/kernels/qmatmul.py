@@ -23,7 +23,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels.autotune import register_kernel
-from repro.kernels.common import INTERPRET, pad2d, quantize_block
+from repro.kernels.common import pad2d, quantize_block, resolve_interpret
 
 __all__ = ["qmatmul_pallas"]
 
@@ -59,7 +59,7 @@ def qmatmul_pallas(
     block_m: int = 128,
     block_n: int = 128,
     block_k: int = 128,
-    interpret: bool = INTERPRET,
+    interpret: bool | None = None,
 ) -> jnp.ndarray:
     """C[M, N] = A[M, K] @ B[K, N] with chunked (1, e_acc, m_acc) accumulation.
 
@@ -91,6 +91,6 @@ def qmatmul_pallas(
         # accumulator (its value is always exactly representable in
         # (1, e_acc, m_acc) after the per-chunk rounding).
         scratch_shapes=[pltpu.VMEM((block_m, block_n), jnp.float32)],
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(a32, b32)
     return out[:m, :n]

@@ -14,7 +14,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 from repro.kernels.autotune import register_kernel
-from repro.kernels.common import INTERPRET, quantize_block
+from repro.kernels.common import quantize_block, resolve_interpret
 
 __all__ = ["quantize_pallas"]
 
@@ -31,7 +31,7 @@ def quantize_pallas(
     e: int,
     m: int,
     block_rows: int = 256,
-    interpret: bool = INTERPRET,
+    interpret: bool | None = None,
 ) -> jnp.ndarray:
     """Quantize ``x`` to (1, e, m), returned as float32.
 
@@ -56,6 +56,6 @@ def quantize_pallas(
         in_specs=[pl.BlockSpec((block_rows, lanes), lambda i: (i, 0))],
         out_specs=pl.BlockSpec((block_rows, lanes), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((rows_padded, lanes), jnp.float32),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(x2)
     return out.reshape(-1)[:n].reshape(shape)

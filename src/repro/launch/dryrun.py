@@ -175,20 +175,6 @@ def _memory(compiled) -> dict:
                 out[k] = v
         except Exception:
             pass
-    if "peak_memory_in_bytes" not in out:
-        # jax < 0.5 CompiledMemoryStats has no peak field; the device
-        # working set is bounded by args + outputs + temps + code.  The
-        # synthesis is version-gated: on a modern jax a missing peak is a
-        # real API change to investigate, not something to paper over
-        # (tests/test_shims.py reminds us to delete this with the floor).
-        from repro.sharding.compat import LEGACY_SHIMS_NEEDED
-
-        if LEGACY_SHIMS_NEEDED:
-            parts = [out.get(k, 0) for k in (
-                "argument_size_in_bytes", "output_size_in_bytes",
-                "temp_size_in_bytes", "generated_code_size_in_bytes")]
-            if any(parts):
-                out["peak_memory_in_bytes"] = sum(parts)
     return out
 
 

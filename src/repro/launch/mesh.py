@@ -11,22 +11,33 @@ whatever mesh the restarted job builds.
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
 
-__all__ = ["make_production_mesh", "make_serve_mesh", "make_smoke_mesh",
-           "AXES"]
+__all__ = ["make_mesh", "make_production_mesh", "make_serve_mesh",
+           "make_smoke_mesh", "AXES"]
 
 AXES = ("pod", "data", "model")
+
+
+def make_mesh(shape, axes, *, devices=None):
+    """Every mesh of the repo: ``Auto`` axes, so sharding follows the
+    ``in_shardings``/``shard_map`` specs and the propagation rules the
+    sharding layer is written against (``jax.make_mesh`` defaults to
+    ``Explicit`` axes)."""
+    return jax.make_mesh(tuple(shape), tuple(axes),
+                         axis_types=(AxisType.Auto,) * len(axes),
+                         devices=devices)
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return make_mesh(shape, axes)
 
 
 def make_smoke_mesh(n_data: int = 2, n_model: int = 2):
     """Small mesh over however many (fake) devices a test process has."""
-    return jax.make_mesh((n_data, n_model), ("data", "model"))
+    return make_mesh((n_data, n_model), ("data", "model"))
 
 
 def make_serve_mesh(n_shards: int | None = None):
@@ -42,4 +53,4 @@ def make_serve_mesh(n_shards: int | None = None):
         raise ValueError(
             f"serve mesh wants {n} shards but only {len(jax.devices())} "
             "devices are visible")
-    return jax.make_mesh((n,), ("model",))
+    return make_mesh((n,), ("model",))

@@ -35,10 +35,12 @@ import jax.numpy as jnp
 from repro.configs import get_config, get_smoke_config
 from repro.core.policy import AccumulationPolicy, plan_for_model
 from repro.data.pipeline import DataConfig, SyntheticLM, with_extras
+from repro.launch.mesh import make_serve_mesh
 from repro.models import encdec
 from repro.models.api import get_model
 from repro.models.layers import Dist
 from repro.models.lm import PAGED_FAMILIES
+from repro.runtime import enable_compile_cache
 
 
 def parse_args(argv=None):
@@ -149,8 +151,31 @@ def _restore_params(ckpt_dir: str, cfg, policy, model, params,
     return cfg, model, params
 
 
+def _to_bf16(params):
+    return jax.tree.map(
+        lambda x: x.astype(jnp.bfloat16) if x.dtype == jnp.float32 else x,
+        params)
+
+
+def _init_params(model, key, *, mesh=None, logit_wire: str = "gather"):
+    """Random serving weights, made in bf16 directly where they live: on
+    the default device, or already sharded over the serve ``mesh`` — a
+    model that fits only sharded never exists whole, nor in f32, on one
+    device."""
+    init = jax.jit(lambda k: _to_bf16(model.init_params(k)))
+    if mesh is None:
+        return init(key)
+    from repro.sharding.specs import named_shardings, serve_param_specs
+
+    specs = serve_param_specs(jax.eval_shape(init, key),
+                              n_shards=mesh.shape["model"],
+                              logit_wire=logit_wire)
+    return jax.jit(init, out_shardings=named_shardings(specs, mesh))(key)
+
+
 def main(argv=None) -> dict:
     args = parse_args(argv)
+    enable_compile_cache()
     cfg = get_smoke_config(args.arch) if args.smoke else get_config(args.arch)
     if args.prompt_lens:
         prompt_lens = [int(x) for x in args.prompt_lens.split(",")]
@@ -162,14 +187,16 @@ def main(argv=None) -> dict:
     cfg = plan_for_model(cfg, seq_len=max_ctx, global_batch=len(prompt_lens),
                          policy=policy)
     model = get_model(cfg)
-    params = model.init_params(jax.random.PRNGKey(args.seed))
+    mesh = make_serve_mesh(args.serve_mesh) if args.serve_mesh else None
     if args.ckpt_dir:
+        params = model.init_params(jax.random.PRNGKey(args.seed))
         cfg, model, params = _restore_params(
             args.ckpt_dir, cfg, policy, model, params,
             seq_len=max_ctx, global_batch=len(prompt_lens))
-    params = jax.tree.map(
-        lambda x: x.astype(jnp.bfloat16) if x.dtype == jnp.float32 else x,
-        params)
+        params = _to_bf16(params)
+    else:
+        params = _init_params(model, jax.random.PRNGKey(args.seed),
+                              mesh=mesh, logit_wire=args.logit_wire)
 
     if args.legacy or cfg.family not in PAGED_FAMILIES:
         return _legacy_main(args, cfg, model, params)
@@ -189,9 +216,8 @@ def main(argv=None) -> dict:
                                      page_size=args.page_size,
                                      kv_fmt=FPFormat(e=5, m=2))
         executor = ShardedModelExecutor(
-            model, params, pc, kv_fmt=pc.kv_fmt,
-            n_shards=args.serve_mesh, max_batch=args.max_batch,
-            logit_wire=args.logit_wire)
+            model, params, pc, kv_fmt=pc.kv_fmt, mesh=mesh,
+            max_batch=args.max_batch, logit_wire=args.logit_wire)
         print(f"serve mesh: {executor.n_shards} tensor-parallel shards, "
               f"logit wire {args.logit_wire}")
     tracer = None
@@ -227,8 +253,7 @@ def main(argv=None) -> dict:
                                    global_batch=len(prompt_lens),
                                    policy=policy)
         draft_model = get_model(draft_cfg)
-        draft_params = jax.tree.map(
-            lambda x: x.astype(jnp.bfloat16) if x.dtype == jnp.float32 else x,
+        draft_params = _to_bf16(
             draft_model.init_params(jax.random.PRNGKey(args.seed + 7)))
         eng = SpecDecodeEngine(model, params, spec_k=args.spec_decode,
                                draft_model=draft_model,
@@ -310,7 +335,7 @@ def main(argv=None) -> dict:
            "kv_ratio": f32 / packed, "max_concurrent": eng.max_concurrent,
            "preemptions": eng.preemptions, "restores": eng.restores,
            "utilization": eng.utilization(), "events": list(eng.events),
-           "compile_stats": cstats}
+           "compile_stats": cstats, "engine": eng}
     if args.spec_decode:
         out.update(spec_rounds=eng.spec_rounds,
                    acceptance_rate=eng.acceptance_rate(),

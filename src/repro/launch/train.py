@@ -28,9 +28,10 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 from repro.configs import get_config, get_smoke_config
 from repro.core.policy import AccumulationPolicy, plan_for_model
 from repro.data.pipeline import DataConfig, SyntheticLM, with_extras
-from repro.launch.flags import apply_tpu_flags
+from repro.launch.mesh import make_mesh
 from repro.models.api import get_model, param_count
 from repro.models.layers import Dist
+from repro.runtime import enable_compile_cache
 from repro.sharding.specs import (
     ShardingRules,
     batch_spec,
@@ -112,15 +113,15 @@ def build_mesh(spec: str):
     if spec == "auto":
         if n == 1:
             return None
-        return jax.make_mesh((n, 1), ("data", "model"))
+        return make_mesh((n, 1), ("data", "model"))
     dims = tuple(int(x) for x in spec.split("x"))
     axes = ("data", "model") if len(dims) == 2 else ("pod", "data", "model")
-    return jax.make_mesh(dims, axes)
+    return make_mesh(dims, axes)
 
 
 def main(argv=None) -> dict:
     args = parse_args(argv)
-    apply_tpu_flags() if jax.default_backend() == "tpu" else None
+    enable_compile_cache()
 
     cfg = get_smoke_config(args.arch) if args.smoke else get_config(args.arch)
     if args.rounding == "sr" and args.policy == "exact":
@@ -263,6 +264,7 @@ def main(argv=None) -> dict:
     metrics_f = open(args.metrics_out, "a") if args.metrics_out else None
     t0 = time.time()
     last_loss = float("nan")
+    losses, batch = [], None
     for step in range(start, args.steps):
         if step == args.crash_at_step and start == 0:
             # one-shot transient-fault injection: only a FRESH incarnation
@@ -301,6 +303,7 @@ def main(argv=None) -> dict:
             step_fn = jit_step(model)
         if (step + 1) % args.log_every == 0 or step + 1 == args.steps:
             last_loss = float(m["loss"])
+            losses.append(last_loss)
             rec = {"step": step + 1, "loss": last_loss,
                    "grad_norm": float(m["grad_norm"]),
                    "lr": float(m["lr"]),
@@ -331,7 +334,10 @@ def main(argv=None) -> dict:
             registry.export_jsonl(args.obs_metrics)
         if args.obs_prometheus:
             registry.export_prometheus(args.obs_prometheus)
-    return {"final_loss": last_loss, "steps": args.steps}
+    # the jitted step and its last operands let a caller inspect the
+    # program that ran (``step_fn.lower(state, batch).compile()``)
+    return {"final_loss": last_loss, "steps": args.steps, "losses": losses,
+            "step_fn": step_fn, "state": state, "batch": batch}
 
 
 class _null:

@@ -15,11 +15,11 @@ from typing import Any
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
 from repro.kernels.ops import QDotConfig, qdot
 from repro.models.config import ModelConfig, MoEConfig, SSMConfig
-from repro.sharding.compat import shard_map
 
 Params = dict[str, Any]
 
@@ -76,6 +76,18 @@ def _constrain(x: jnp.ndarray, dist: Dist, spec: P) -> jnp.ndarray:
 # --------------------------------------------------------------------------
 
 
+def _exact_f32(a: jnp.ndarray) -> jnp.ndarray:
+    """f32 carrier of a GEMM operand whose bf16 values stay exactly bf16.
+    XLA may keep a fused bf16 intermediate in f32 (excess precision), and
+    whether it does depends on the surrounding program; the quantizer in
+    ``qdot`` would then see different inputs in, say, the single-device and
+    the tensor-parallel decode step.  The explicit rounding pins them."""
+    f = a.astype(jnp.float32)
+    if a.dtype == jnp.bfloat16:
+        f = jax.lax.reduce_precision(f, exponent_bits=8, mantissa_bits=7)
+    return f
+
+
 def dense(x: jnp.ndarray, w: jnp.ndarray, qcfg: QDotConfig | None = None,
           bias: jnp.ndarray | None = None,
           out_fmt=None) -> jnp.ndarray:
@@ -98,7 +110,7 @@ def dense(x: jnp.ndarray, w: jnp.ndarray, qcfg: QDotConfig | None = None,
     if qcfg is not None and not qcfg.is_exact:
         if out_fmt is not None and out_fmt != qcfg.out_fmt:
             qcfg = dataclasses.replace(qcfg, out_fmt=out_fmt)
-        y = qdot(x.astype(jnp.float32), w.astype(jnp.float32), qcfg)
+        y = qdot(_exact_f32(x), _exact_f32(w), qcfg)
         y = y.astype(COMPUTE_DTYPE)
     else:
         # bf16 output dtype: on TPU the MXU still accumulates the local

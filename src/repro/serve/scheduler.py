@@ -110,7 +110,7 @@ from repro.serve.plan import (
 from repro.telemetry.stats import EnsembleStats
 
 __all__ = ["Request", "ModelExecutor", "ShardedModelExecutor", "ServeEngine",
-           "measure_decode_vrr"]
+           "measure_decode_vrr", "sharded_divergence"]
 
 
 @dataclass
@@ -343,6 +343,14 @@ class ModelExecutor:
 
     def decode(self, req: DecodeRequest) -> list[int]:
         """One batched decode token per row; returns the next tokens."""
+        logits = self.decode_logits(req)
+        return [int(t) for t in np.asarray(
+            jnp.argmax(logits[:len(req.last_tokens), 0], axis=-1))]
+
+    def decode_logits(self, req: DecodeRequest) -> jnp.ndarray:
+        """One batched decode step; returns the (max_batch, 1, vocab)
+        logits (rows past the request's are padding) and advances the
+        arena."""
         stats = self._cache["stats"]
         before = stats["compiles"]
         pt_in = np.asarray(req.page_table, np.int32)
@@ -362,8 +370,7 @@ class ModelExecutor:
             self.params, jnp.asarray(tokens), self.kv, jnp.asarray(pt),
             jnp.asarray(pos), jnp.asarray(sl))
         self._count_dispatch(before)
-        return [int(t) for t in np.asarray(
-            jnp.argmax(logits[:n, 0], axis=-1))]
+        return logits
 
     def _verify_fn(self, acc: tuple[int, int], s_v: int):
         import functools
@@ -625,9 +632,8 @@ class ShardedModelExecutor(ModelExecutor):
     def _decode_fn(self, acc: tuple[int, int]):
         import functools
 
+        from jax import shard_map
         from jax.sharding import PartitionSpec as P
-
-        from repro.sharding.compat import shard_map
 
         inner = functools.partial(self.pm.decode, dist=self.dist,
                                   kv_fmt=self.kv_fmt, acc=acc,
@@ -643,9 +649,8 @@ class ShardedModelExecutor(ModelExecutor):
     def _prefill_fn(self, acc: tuple[int, int], final: bool, call=None):
         import functools
 
+        from jax import shard_map
         from jax.sharding import PartitionSpec as P
-
-        from repro.sharding.compat import shard_map
 
         key = (("prefill", call.static_signature(), final)
                if call is not None else ("prefill", acc, final))
@@ -662,9 +667,8 @@ class ShardedModelExecutor(ModelExecutor):
     def _verify_fn(self, acc: tuple[int, int], s_v: int):
         import functools
 
+        from jax import shard_map
         from jax.sharding import PartitionSpec as P
-
-        from repro.sharding.compat import shard_map
 
         if self.pm.verify is None:
             raise NotImplementedError(
@@ -1264,3 +1268,50 @@ class ServeEngine:
         return kv_bytes_per_token(
             self.pc, carrier_bytes=carrier_bytes,
             tp_shards=self.tp_shards if per_shard else 1)
+
+
+def sharded_divergence(model, params, prompts, plan: AttnPlan, shards: int,
+                       *, gen: int, n_pages: int, kv_fmt: FPFormat,
+                       max_batch: int) -> str | None:
+    """The sharded serving contract, checked: serve ``prompts`` (``gen``
+    tokens each) on one device and on a ``shards``-way ``model`` mesh with
+    the same weights and ``plan``, then decode one more step of the first
+    prompt in each arena.  None when the token streams are equal and the
+    arenas and that step's logits are bitwise equal, else what diverged."""
+    page = plan.page_size
+    pc = PagedKVConfig.for_model(model.cfg, n_pages=n_pages, page_size=page,
+                                 kv_fmt=kv_fmt)
+    n = len(prompts[0])
+    b = plan.buckets[-1]
+    row = np.zeros((1, b.max_pages(page)), np.int32)
+    used = -(-(n + 1) // page)
+    row[0, :used] = np.arange(1, used + 1)
+    step = DecodeRequest(rids=(0,), last_tokens=(int(prompts[0][-1]),),
+                         page_table=row, positions=(n,), seq_lens=(n + 1,),
+                         acc=b.acc)
+
+    def drive(executor):
+        eng = ServeEngine(model, params, n_pages=n_pages, page_size=page,
+                          max_batch=max_batch, executor=executor, plan=plan,
+                          prefill_chunk_tokens=plan.prefill_chunk)
+        rids = [eng.submit(list(p), gen) for p in prompts]
+        out = eng.run()
+        arena = {k: np.asarray(v) for k, v in eng.kv.items()}
+        logits = np.asarray(executor.decode_logits(step))[0, 0]
+        return [out[r] for r in rids], arena, logits
+
+    out1, kv1, l1 = drive(ModelExecutor(model, params, pc, kv_fmt=kv_fmt,
+                                        max_batch=max_batch))
+    out_n, kv_n, l_n = drive(ShardedModelExecutor(
+        model, params, pc, kv_fmt=kv_fmt, n_shards=shards,
+        max_batch=max_batch))
+    if out1 != out_n:
+        return f"token streams {out1} vs {out_n}"
+    bad = [k for k in kv1 if not np.array_equal(kv1[k], kv_n[k])]
+    if bad:
+        return f"arena {bad}"
+    if not np.array_equal(l1, l_n):
+        d = np.abs(l1.astype(np.float32) - l_n.astype(np.float32))
+        return (f"decode logits: {int((d > 0).sum())} of {d.size} differ, "
+                f"max |diff| {float(d.max())}")
+    return None

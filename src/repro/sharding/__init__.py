@@ -1,4 +1,3 @@
-from repro.sharding.compat import shard_map  # noqa: F401
 from repro.sharding.specs import (  # noqa: F401
     ShardingRules,
     batch_spec,

@@ -23,8 +23,11 @@ def repo_root() -> str:
 
 
 def run_child(code: str, *, devices: int = 8, timeout: int = 600) -> str:
-    """Run ``code`` in a fresh python with ``devices`` fake XLA devices."""
+    """Run ``code`` in a fresh python with ``devices`` fake XLA devices,
+    on the CPU backend (a TPU, if the host has one, belongs to the test
+    process, never to its children)."""
     env = dict(os.environ)
+    env["JAX_PLATFORMS"] = "cpu"
     env["PYTHONPATH"] = SRC + os.pathsep + env.get("PYTHONPATH", "")
     env["XLA_FLAGS"] = (
         f"--xla_force_host_platform_device_count={devices} "

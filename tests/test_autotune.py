@@ -193,11 +193,27 @@ def test_table_key_carries_dtype_and_vmem_ceiling(tmp_table):
 
 def test_vmem_budget_per_generation(monkeypatch):
     monkeypatch.delenv("REPRO_VMEM_BUDGET", raising=False)
-    monkeypatch.setenv("REPRO_TPU_GENERATION", "v6e")
+
+    class FakeTPU:
+        platform = "tpu"
+
+        def __init__(self, kind):
+            self.device_kind = kind
+
+    # the generation comes from the device kind JAX reports
+    assert autotune.device_generation(FakeTPU("TPU v5 lite")) == "v5e"
+    assert autotune.device_generation(FakeTPU("TPU v6 lite")) == "v6e"
+    assert autotune.device_generation(FakeTPU("TPU v4")) == "v4"
+    # an unknown TPU is an error, never a guessed ceiling
+    with pytest.raises(ValueError, match="unknown TPU device_kind"):
+        autotune.device_generation(FakeTPU("TPU v99"))
+    # off the TPU the budget plans for the chip the repo is measured on
+    assert autotune.vmem_budget() == autotune.VMEM_PER_GENERATION[
+        autotune.HOST_GENERATION] // 2
+    monkeypatch.setattr(autotune, "device_generation",
+                        lambda device=None: "v6e")
     assert autotune.vmem_budget() == autotune.VMEM_PER_GENERATION["v6e"] // 2
-    monkeypatch.setenv("REPRO_TPU_GENERATION", "v4")
-    assert autotune.vmem_budget() == autotune.VMEM_PER_GENERATION["v4"] // 2
-    assert autotune.vmem_budget("v6e") == autotune.VMEM_PER_GENERATION["v6e"] // 2
+    assert autotune.vmem_budget("v4") == autotune.VMEM_PER_GENERATION["v4"] // 2
     monkeypatch.setenv("REPRO_VMEM_BUDGET", "12345")
     assert autotune.vmem_budget() == 12345
 

@@ -40,7 +40,8 @@ def _rand(m, k, n, seed):
 
 
 @pytest.mark.parametrize("m,k,n", SHAPES)
-@pytest.mark.parametrize("m_acc,block_k", [(5, 64), (9, 128)])
+@pytest.mark.parametrize("m_acc,block_k", [(5, 64), (9, 128), (6, 32),
+                                           (8, 256)])
 def test_fused_matches_unfused_composition_bitexact(m, k, n, m_acc, block_k):
     a, b = _rand(m, k, n, hash((m, k, n, m_acc)) % 2**32)
     got = np.asarray(qmatmul_fused(
@@ -49,6 +50,18 @@ def test_fused_matches_unfused_composition_bitexact(m, k, n, m_acc, block_k):
         quantize_pallas(a, e=5, m=2), quantize_pallas(b, e=5, m=2),
         e_acc=6, m_acc=m_acc, block_k=block_k))
     np.testing.assert_array_equal(got, want)
+
+
+def test_chunk_length_off_the_lanes_is_refused():
+    # n1 = 48 neither divides the 128-lane K tile nor is a multiple of it:
+    # refused on every backend, before any kernel is built
+    a, b = _rand(8, 96, 16, 5)
+    with pytest.raises(ValueError, match="must divide 128"):
+        qmatmul_fused(a, b, repr_fmt=FP8_152, e_acc=6, m_acc=5, block_k=48)
+    g = jnp.zeros((8, 16), jnp.float32)
+    with pytest.raises(ValueError, match="must divide 128"):
+        qmatmul_bwd_pair(g, a, b, repr_fmt=FP8_152, packed=False,
+                         block_t=48)
 
 
 @pytest.mark.parametrize("m,k,n", SHAPES)
@@ -227,7 +240,7 @@ def test_qdot_wide_n_takes_nsplit_path_not_fallback(monkeypatch):
     t, k, n = 32, 64, 1024
     cfg = _cfg()
     assert pair_n_segments(cfg, t, k, n) == 1
-    monkeypatch.setenv("REPRO_VMEM_BUDGET", str(360_000))
+    monkeypatch.setenv("REPRO_VMEM_BUDGET", str(450_000))
     segs = pair_n_segments(cfg, t, k, n)
     assert segs > 1, "budget should force the N-split path"
 

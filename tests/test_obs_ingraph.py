@@ -156,10 +156,12 @@ def test_to_raw_round_trips_ensemble_stats():
 
 
 def test_stats_axis_psums_and_masks_to_shard_zero():
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
-    from repro.sharding.compat import shard_map
 
-    mesh = jax.make_mesh((1,), ("x",))
+    from repro.launch.mesh import make_mesh
+
+    mesh = make_mesh((1,), ("x",))
     x, w = _rand(64, 128, 16, 5)
     plain = QDotConfig(fwd=_prec(6), repr_fmt=FP8_152, stats_tag="t")
     from dataclasses import replace

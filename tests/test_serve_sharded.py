@@ -246,6 +246,41 @@ print("OK")
 
 
 @pytest.mark.slow
+def test_sharded_engine_bitwise_parity_quantized_plan():
+    """The same contract on the paper's quantized serve path (``--policy
+    predicted``: every dense GEMM through ``qdot``): 4-shard token streams,
+    arena and decode logits bitwise equal to single-device.  The bf16
+    activations feeding the quantizer must not depend on how XLA fuses the
+    two programs (``layers._exact_f32``)."""
+    run_child(
+        _SHARD_CFG + """
+import jax, numpy as np
+from repro.core.policy import AccumulationPolicy, plan_for_model
+from repro.models.api import get_model
+from repro.quant.formats import FPFormat
+from repro.serve.plan import plan_attention
+from repro.serve.scheduler import sharded_divergence
+
+cfg = plan_for_model(cfg, seq_len=32, global_batch=3,
+                     policy=AccumulationPolicy(mode="predicted"))
+model = get_model(cfg)
+params = model.init_params(jax.random.PRNGKey(0))
+N_PAGES, PAGE = 24, 4
+prompts = [list(np.random.RandomState(s).randint(1, cfg.vocab_size, n))
+           for s, n in ((1, 5), (2, 8), (3, 11))]
+plan = plan_attention((N_PAGES - 1) * PAGE, PAGE, prefill_chunk_tokens=8,
+                      tp_shards=4)
+diff = sharded_divergence(model, params, prompts, plan, 4, gen=5,
+                          n_pages=N_PAGES, kv_fmt=FPFormat(e=5, m=2),
+                          max_batch=3)
+assert diff is None, diff
+print("OK")
+""",
+        devices=4,
+    )
+
+
+@pytest.mark.slow
 def test_sharded_oracle_parity_with_single_device():
     """The jnp-oracle (reference-kernel) serve path under the same 4-shard
     mesh: token streams and arenas bitwise equal to single-device
@@ -296,9 +331,10 @@ def test_psum_carry_matches_sequential_merge():
     run_child(
         """
 import jax, jax.numpy as jnp, numpy as np
+from repro.launch.mesh import make_mesh
 from jax.sharding import PartitionSpec as P
 from repro.kernels.attention import NEG, finalize_carry, merge_carries, psum_carry
-from repro.sharding.compat import shard_map
+from jax import shard_map
 
 S, H, DH = 4, 8, 16
 rng = np.random.RandomState(0)
@@ -318,7 +354,7 @@ for i in range(S):
     l[i, lo:hi] = np.abs(rng.randn(hi - lo)).astype(np.float32) + 0.5
 o[3, 7] = 0.0; m[3, 7] = NEG; l[3, 7] = 0.0
 
-mesh = jax.make_mesh((4,), ("model",))
+mesh = make_mesh((4,), ("model",))
 f = shard_map(lambda oo, mm, ll: psum_carry(oo[0], mm[0], ll[0], "model"),
               mesh=mesh, in_specs=(P("model"), P("model"), P("model")),
               out_specs=(P(), P(), P()), check_vma=False)
@@ -354,8 +390,9 @@ def test_ensemble_stats_psum_under_real_shard_map():
     run_child(
         """
 import jax, jax.numpy as jnp, numpy as np
+from repro.launch.mesh import make_mesh
 from jax.sharding import PartitionSpec as P
-from repro.sharding.compat import shard_map
+from jax import shard_map
 from repro.telemetry.stats import EnsembleStats
 
 S, N = 4, 64
@@ -371,7 +408,7 @@ def local_stats(q, i):
         m2_i=jnp.sum((i - mi) ** 2), max_abs=jnp.max(jnp.abs(q)),
         swamped=jnp.float32(0.0), adds=jnp.float32(q.shape[0]))
 
-mesh = jax.make_mesh((4,), ("model",))
+mesh = make_mesh((4,), ("model",))
 f = shard_map(lambda q, i: local_stats(q[0], i[0]).psum("model"),
               mesh=mesh, in_specs=(P("model"), P("model")),
               out_specs=P(), check_vma=False)
@@ -401,8 +438,9 @@ def test_int8_logit_wire_bit_parity_on_lattice_inputs():
     run_child(
         _SHARD_CFG + """
 import jax, jax.numpy as jnp, numpy as np
+from repro.launch.mesh import make_mesh
 from jax.sharding import PartitionSpec as P
-from repro.sharding.compat import shard_map
+from jax import shard_map
 from repro.train.compression import compressed_psum
 
 S, B, V = 4, 3, 16
@@ -411,7 +449,7 @@ rng = np.random.RandomState(3)
 x = rng.randint(-127, 128, size=(S, B, V)).astype(np.float32)
 x[0, 0, 0] = 127.0  # pin the pmax'd amax
 
-mesh = jax.make_mesh((4,), ("model",))
+mesh = make_mesh((4,), ("model",))
 wire = shard_map(lambda v: compressed_psum(v[0], "model")[0],
                  mesh=mesh, in_specs=P("model"), out_specs=P(),
                  check_vma=False)

@@ -79,6 +79,7 @@ def test_sharded_loss_matches_local():
     run_child(
         """
 import jax, jax.numpy as jnp
+from repro.launch.mesh import make_mesh
 from jax.sharding import NamedSharding, PartitionSpec as P
 from repro.configs import get_smoke_config
 from repro.models.api import get_model
@@ -92,7 +93,7 @@ for arch in ("qwen2-1.5b", "moonshot-v1-16b-a3b"):
     tokens = jax.random.randint(jax.random.PRNGKey(1), (4, 32), 0, cfg.vocab_size)
     local_loss, _ = jax.jit(lambda p, t: model.loss_fn(p, {"tokens": t}, cfg, LOCAL))(params, tokens)
 
-    mesh = jax.make_mesh((2, 2), ("data", "model"))
+    mesh = make_mesh((2, 2), ("data", "model"))
     dist = Dist(mesh=mesh, data_axes=("data",))
     specs = build_param_specs(params, ShardingRules(mesh))
     sh = named_shardings(specs, mesh)
@@ -115,6 +116,7 @@ def test_elastic_checkpoint_restore_across_meshes():
     run_child(
         """
 import tempfile, jax, jax.numpy as jnp, numpy as np
+from repro.launch.mesh import make_mesh
 from repro.configs import get_smoke_config
 from repro.models.api import get_model
 from repro.sharding.specs import ShardingRules, build_param_specs, named_shardings
@@ -124,12 +126,12 @@ cfg = get_smoke_config("qwen2-1.5b")
 model = get_model(cfg)
 params = model.init_params(jax.random.PRNGKey(0))
 
-mesh1 = jax.make_mesh((2, 2), ("data", "model"))
+mesh1 = make_mesh((2, 2), ("data", "model"))
 sh1 = named_shardings(build_param_specs(params, ShardingRules(mesh1)), mesh1)
 p1 = jax.device_put(params, sh1)
 with tempfile.TemporaryDirectory() as d:
     save_checkpoint(d, 1, p1)
-    mesh2 = jax.make_mesh((4, 1), ("data", "model"))
+    mesh2 = make_mesh((4, 1), ("data", "model"))
     sh2 = named_shardings(build_param_specs(params, ShardingRules(mesh2)), mesh2)
     like = jax.tree.map(lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype), params)
     p2, _ = restore_checkpoint(d, 1, like, shardings=sh2)
@@ -146,11 +148,12 @@ def test_compressed_psum_matches_plain():
     run_child(
         """
 import jax, jax.numpy as jnp, numpy as np
+from repro.launch.mesh import make_mesh
 from jax.sharding import PartitionSpec as P
-from repro.sharding.compat import shard_map
+from jax import shard_map
 from repro.train.compression import compressed_psum
 
-mesh = jax.make_mesh((4,), ("pod",))
+mesh = make_mesh((4,), ("pod",))
 x = jax.random.normal(jax.random.PRNGKey(0), (4, 256))
 
 def f(xs):

@@ -1,12 +1,4 @@
-"""Sunset tests for the project's compatibility shims.
-
-Two jax < 0.5 API shims: ``repro.sharding.compat.shard_map`` (the
-``jax.experimental.shard_map`` / ``check_rep`` fallback) and
-``repro.launch.dryrun._memory`` (synthesized ``peak_memory_in_bytes``).
-Both are gated on ``compat.LEGACY_SHIMS_NEEDED``; the jax-floor test
-below FAILS — naming the exact deletions — once the project's jax floor
-in pyproject.toml passes 0.5, so the dead branches cannot outlive the
-API they bridge (ROADMAP "jax API drift").
+"""Sunset tests for the project's retired compatibility shims.
 
 The four PR-6 paged-protocol shims (``lm.prefill_paged``,
 ``lm.decode_step_paged``, ``lm.prefill_chunk_paged``,
@@ -17,61 +9,7 @@ pins that they do not creep back.
 
 from __future__ import annotations
 
-import os
-import re
-
-import jax
-
 from repro.models import encdec, lm
-from repro.sharding import compat
-
-_PYPROJECT = os.path.join(os.path.dirname(os.path.dirname(
-    os.path.abspath(__file__))), "pyproject.toml")
-
-
-def _jax_floor() -> tuple[int, int]:
-    """The jax lower bound declared in pyproject.toml dependencies."""
-    text = open(_PYPROJECT).read()
-    m = re.search(r'"jax\s*>=\s*(\d+)\.(\d+)', text)
-    assert m, "pyproject.toml no longer declares a jax>=X.Y dependency"
-    return (int(m.group(1)), int(m.group(2)))
-
-
-def test_shims_sunset_with_the_jax_floor():
-    """FAILS when the floor passes 0.5: time to delete the shims."""
-    floor = _jax_floor()
-    assert floor < (0, 5), (
-        f"pyproject's jax floor is now {floor[0]}.{floor[1]} >= 0.5 — every "
-        "supported jax has the modern APIs, so DELETE (1) the "
-        "jax.experimental.shard_map fallback branch in "
-        "repro/sharding/compat.py and (2) the peak_memory_in_bytes "
-        "synthesis in repro/launch/dryrun._memory, then remove this test "
-        "and the ROADMAP 'jax API drift' item")
-
-
-def test_legacy_gate_matches_running_jax():
-    version = tuple(int(p) for p in jax.__version__.split(".")[:2])
-    assert compat.JAX_VERSION == version
-    assert compat.LEGACY_SHIMS_NEEDED == (version < (0, 5))
-
-
-def test_shard_map_prefers_modern_entry_point():
-    """Whenever the running jax has jax.shard_map, the shim must use it —
-    the legacy branch is only reachable on a < 0.5 runtime."""
-    if not hasattr(jax, "shard_map"):
-        assert compat.LEGACY_SHIMS_NEEDED, (
-            "jax.shard_map missing on a >= 0.5 jax: the compat shim would "
-            "raise; the experimental fallback no longer applies")
-    # construction must not raise regardless of branch
-    import numpy as np
-    from jax.sharding import PartitionSpec as P
-
-    mesh = jax.make_mesh((1,), ("x",))
-    f = compat.shard_map(lambda a: a, mesh=mesh, in_specs=P(), out_specs=P(),
-                         check_vma=False)
-    out = f(np.ones((2,), np.float32))
-    assert out.shape == (2,)
-
 
 # --------------------------------------------------------------------------
 # paged-protocol shims (PR 6): retired at their 0.2 sunset

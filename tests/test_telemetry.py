@@ -136,12 +136,14 @@ def test_welford_merge_equals_pooled_ensemble():
 
 
 def test_psum_matches_merge_across_shards():
-    from repro.sharding.compat import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
+
+    from repro.launch.mesh import make_mesh
 
     if len(jax.devices()) < 1:
         pytest.skip("no devices")
-    mesh = jax.make_mesh((1,), ("x",))
+    mesh = make_mesh((1,), ("x",))
     a, b = _rand(32, 256, 16, 6)
     _, s = gemm_stats(a, b, precision=_prec(6), repr_fmt=FP8_152)
 
