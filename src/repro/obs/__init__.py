@@ -23,12 +23,13 @@ from repro.obs.metrics import (
     record_spec_events,
     set_registry,
 )
-from repro.obs.sink import JsonlSink, RingBuffer, jsonl_append
+from repro.obs.sink import RingBuffer, jsonl_append
 from repro.obs.trace import (
     Span,
     Tracer,
     percentile,
     request_latencies,
+    span,
     span_forest,
 )
 
@@ -37,6 +38,7 @@ __all__ = [
     "MetricsRegistry", "get_registry", "set_registry",
     "collect_process_metrics", "record_controller_events",
     "record_spec_events",
-    "JsonlSink", "RingBuffer", "jsonl_append",
-    "Span", "Tracer", "span_forest", "request_latencies", "percentile",
+    "RingBuffer", "jsonl_append",
+    "Span", "Tracer", "span", "span_forest", "request_latencies",
+    "percentile",
 ]

@@ -3,10 +3,9 @@
 ``jsonl_append`` is the single implementation of the
 make-the-directory-then-append-one-object-per-line logic that used to be
 copy-pasted between ``serve/scheduler.py`` (monitor log) and
-``telemetry/controller.py`` (controller event log).  ``JsonlSink`` wraps it
-with a fixed path; ``RingBuffer`` bounds in-memory event growth
-(``ServeEngine.events`` used to grow without limit for the life of the
-engine).
+``telemetry/controller.py`` (controller event log).  ``RingBuffer`` bounds
+in-memory event growth (``ServeEngine.events`` used to grow without limit
+for the life of the engine).
 """
 
 from __future__ import annotations
@@ -16,7 +15,7 @@ import os
 from collections import deque
 from typing import Iterable, Iterator
 
-__all__ = ["jsonl_append", "JsonlSink", "RingBuffer"]
+__all__ = ["jsonl_append", "RingBuffer"]
 
 
 def jsonl_append(path: str, records: Iterable[dict]) -> None:
@@ -31,18 +30,6 @@ def jsonl_append(path: str, records: Iterable[dict]) -> None:
     with open(path, "a") as f:
         for r in records:
             f.write(json.dumps(r) + "\n")
-
-
-class JsonlSink:
-    """A JSONL appender bound to one path (``path=None`` disables it, so
-    call sites need no guard)."""
-
-    def __init__(self, path: str | None):
-        self.path = path
-
-    def emit(self, *records: dict) -> None:
-        if self.path:
-            jsonl_append(self.path, records)
 
 
 class RingBuffer:

@@ -5,16 +5,31 @@ The serve scheduler emits one **root span per request** (``name="request"``,
 the request passes through::
 
     request(rid)
-    ├─ queued            admission wait (submit -> admit)
-    ├─ prefill_slab ×N   one per chunked-prefill slab
-    ├─ swapped ×M        preempt -> swap-out ... swap-in -> restored
-    └─ [token events]    one per emitted token, on the root span
+    ├─ queued                 admission wait (submit -> admit)
+    ├─ serve.prefill_slab ×N  one per chunked-prefill slab
+    │  └─ executor.prefill    (⊃ executor.prefill.wait on the final slab)
+    ├─ swapped ×M             preempt -> swap-out ... swap-in -> restored
+    └─ [token events]         one per emitted token, on the root span
 
-plus engine-level ``decode_step`` spans (no trace_id — they batch many
-requests; the ``rids`` attr links them).  Token events on the root span make
-every emitted token attributable to exactly one request, which is what the
-sim fuzz suite pins and what TTFT/TPOT are computed from
-(``request_latencies``).
+plus one engine-level tree per tick (no trace_id — a tick batches many
+requests; ``serve.decode_step``'s ``rids`` attr links it to them)::
+
+    serve.step
+    ├─ serve.admit            restore-or-admit
+    ├─ serve.decode_step      page table, executor call, token bookkeeping
+    │  └─ executor.decode     padding, host-to-device copies, dispatch
+    │     └─ executor.decode.wait   the host blocks on the device
+    └─ serve.monitor          the VRR probe, on its cadence
+
+Token events on the root span make every emitted token attributable to
+exactly one request, which is what the sim fuzz suite pins and what
+TTFT/TPOT are computed from (``request_latencies``).
+
+Every scoped span goes through ``span``, which also writes it into the
+profiler's trace as a ``jax.profiler.TraceAnnotation`` of the same name, so
+a ``jax.profiler`` capture puts the engine's and executor's host work beside
+the device's ops.  ``request``, ``queued`` and ``swapped`` live across many
+ticks and stay tracer-only: profiler scopes must nest on one thread.
 
 Timestamps come from an injected ``Clock`` (``repro.obs.clock``), so the
 scheduler sim's virtual clock produces schedule-deterministic span trees;
@@ -26,10 +41,13 @@ from __future__ import annotations
 
 from dataclasses import dataclass, field
 
+from jax.profiler import TraceAnnotation
+
 from repro.obs.clock import Clock, SystemClock
 from repro.obs.sink import RingBuffer, jsonl_append
 
-__all__ = ["Span", "Tracer", "span_forest", "request_latencies", "percentile"]
+__all__ = ["Span", "Tracer", "span", "span_forest", "request_latencies",
+           "percentile"]
 
 
 @dataclass
@@ -69,6 +87,7 @@ class Tracer:
         self.clock = clock if clock is not None else SystemClock()
         self.spans: RingBuffer = RingBuffer(capacity)
         self._next_id = 1
+        self._open: list[Span] = []  # the ``span`` scopes now entered
 
     # ------------------------------ record ---------------------------------
     def start(self, name: str, *, trace_id=None,
@@ -102,6 +121,53 @@ class Tracer:
 
     def to_dicts(self) -> list[dict]:
         return [s.to_dict() for s in self.spans]
+
+
+class span:
+    """One scope of host work, in the profiler's trace and in ``tracer``.
+
+    Always enters ``jax.profiler.TraceAnnotation(name, **stats)``, about a
+    microsecond when no profiler session records; ``stats`` (scalars)
+    become the event's stats in the profile.  With a tracer it also
+    records a ``Span`` of the same name whose attributes are ``stats`` and
+    ``attrs`` and whose parent is ``parent``, else the innermost ``span``
+    scope still open.  ``with`` yields that ``Span``, or None without a
+    tracer."""
+
+    __slots__ = ("tracer", "name", "parent", "trace_id", "attrs", "stats",
+                 "_scope", "_span")
+
+    def __init__(self, tracer: Tracer | None, name: str, *,
+                 parent: Span | None = None, trace_id=None,
+                 attrs: dict | None = None, **stats):
+        self.tracer, self.name, self.parent = tracer, name, parent
+        self.trace_id, self.attrs, self.stats = trace_id, attrs, stats
+
+    def __enter__(self) -> Span | None:
+        self._scope = TraceAnnotation(self.name, **self.stats)
+        self._scope.__enter__()
+        tr = self.tracer
+        if tr is None:
+            self._span = None
+            return None
+        parent = self.parent
+        if parent is None and tr._open:
+            parent = tr._open[-1]
+        self._span = tr.start(self.name, parent=parent,
+                              trace_id=self.trace_id, **self.stats,
+                              **(self.attrs or {}))
+        tr._open.append(self._span)
+        return self._span
+
+    def __exit__(self, *exc) -> None:
+        # the span starts and ends just after the profiler's scope: the
+        # profiler's end stamp of a long scope can take tens of µs to
+        # take, and reading the tracer's clock after it keeps the two
+        # durations within a few µs of each other
+        self._scope.__exit__(*exc)
+        if self._span is not None:
+            self.tracer.end(self._span)
+            self.tracer._open.pop()
 
 
 def span_forest(spans) -> dict:

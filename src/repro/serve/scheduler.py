@@ -88,6 +88,7 @@ from repro.models.api import (
 )
 from repro.models.layers import LOCAL, Dist
 from repro.obs.sink import RingBuffer, jsonl_append
+from repro.obs.trace import span
 from repro.quant.formats import FPFormat
 from repro.serve.kvcache import (
     PagedKVConfig,
@@ -228,7 +229,15 @@ class ModelExecutor:
     jitted entries count their own traces — ``compile_stats()`` exposes
     compiles / dispatch hits / misses / warmup compiles, and the serve
     bench gates steady-state compiles at zero.
+
+    ``prefill``/``decode`` are ``executor.*`` spans (``repro.obs.trace.
+    span``), recorded in ``tracer`` when the engine installs one, and each
+    jitted program is named ``serve_<kind>`` (``serve_decode``,
+    ``serve_prefill``, ``serve_prefill_final``, ``serve_verify``,
+    ``serve_rollback``), so a profile tells their device times apart.
     """
+
+    tracer = None  # set by the ``ServeEngine`` that carries a tracer
 
     def __init__(self, model, params, pc: PagedKVConfig, *,
                  kv_fmt: FPFormat, dist: Dist = LOCAL, oracle: bool = False,
@@ -267,7 +276,9 @@ class ModelExecutor:
         """Memoized jit whose wrapped python body counts its own traces:
         the body runs exactly once per compiled signature (jax re-enters
         it only to trace), so ``stats["compiles"]`` is the compile count —
-        including shape-driven retraces the key did not anticipate."""
+        including shape-driven retraces the key did not anticipate.  The
+        program is named after the key's kind (``jit_serve_decode`` in the
+        profile); a prefill that returns logits is ``serve_prefill_final``."""
         fns = self._cache["fns"]
         hit = fns.get(key)
         if hit is None:
@@ -277,6 +288,9 @@ class ModelExecutor:
                 stats["compiles"] += 1
                 return fn(*a, **kw)
 
+            final = key[0] == "prefill" and key[2]
+            counted.__name__ = counted.__qualname__ = (
+                f"serve_{key[0]}" + ("_final" if final else ""))
             hit = fns[key] = jax.jit(counted, **jit_kw)
         return hit
 
@@ -325,27 +339,35 @@ class ModelExecutor:
         before = stats["compiles"]
         page_size = self.pc.page_size
         n_tok = len(req.tokens)
-        width = req.slab_width or n_tok
-        toks = np.zeros((1, width), np.int32)
-        toks[0, :n_tok] = req.tokens
-        n_hist = len(req.hist_pages)
-        n_slab = -(-width // page_size)
-        slab = np.zeros((n_slab,), np.int32)
-        slab[:len(req.slab_pages)] = req.slab_pages
-        row = np.zeros((req.bucket_pages or (n_hist + n_slab),), np.int32)
-        row[:n_hist] = req.hist_pages
-        row[n_hist:n_hist + len(req.slab_pages)] = req.slab_pages
-        logits, self.kv = self._prefill_fn(req.acc, req.final, req.call)(
-            self.params, jnp.asarray(toks), self.kv, jnp.asarray(row),
-            jnp.asarray(slab), jnp.int32(req.t0), jnp.int32(n_tok))
-        self._count_dispatch(before)
-        return int(jnp.argmax(logits[0])) if req.final else None
+        with span(self.tracer, "executor.prefill", tokens=n_tok):
+            width = req.slab_width or n_tok
+            toks = np.zeros((1, width), np.int32)
+            toks[0, :n_tok] = req.tokens
+            n_hist = len(req.hist_pages)
+            n_slab = -(-width // page_size)
+            slab = np.zeros((n_slab,), np.int32)
+            slab[:len(req.slab_pages)] = req.slab_pages
+            row = np.zeros((req.bucket_pages or (n_hist + n_slab),),
+                           np.int32)
+            row[:n_hist] = req.hist_pages
+            row[n_hist:n_hist + len(req.slab_pages)] = req.slab_pages
+            logits, self.kv = self._prefill_fn(req.acc, req.final, req.call)(
+                self.params, jnp.asarray(toks), self.kv, jnp.asarray(row),
+                jnp.asarray(slab), jnp.int32(req.t0), jnp.int32(n_tok))
+            self._count_dispatch(before)
+            if not req.final:
+                return None
+            with span(self.tracer, "executor.prefill.wait"):
+                return int(jnp.argmax(logits[0]))
 
     def decode(self, req: DecodeRequest) -> list[int]:
         """One batched decode token per row; returns the next tokens."""
-        logits = self.decode_logits(req)
-        return [int(t) for t in np.asarray(
-            jnp.argmax(logits[:len(req.last_tokens), 0], axis=-1))]
+        n = len(req.last_tokens)
+        with span(self.tracer, "executor.decode", rows=n):
+            logits = self.decode_logits(req)
+            with span(self.tracer, "executor.decode.wait"):
+                out = np.asarray(jnp.argmax(logits[:n, 0], axis=-1))
+        return [int(t) for t in out]
 
     def decode_logits(self, req: DecodeRequest) -> jnp.ndarray:
         """One batched decode step; returns the (max_batch, 1, vocab)
@@ -738,6 +760,8 @@ class ServeEngine:
         else:
             self.pc = getattr(executor, "pc", None)
         self.executor = executor
+        if tracer is not None and isinstance(executor, ModelExecutor):
+            executor.tracer = tracer  # its executor.* spans join the ticks'
         # tensor-parallel executors advertise their shard count; the engine
         # then allocates through a ShardedPagePool (one logical allocator,
         # N mirrored per-shard pools with lockstep assertions) and the plan
@@ -762,8 +786,9 @@ class ServeEngine:
         self._key = jax.random.PRNGKey(seed)
 
         # observability (all optional): with tracer/metrics None every
-        # guarded block below is skipped — the engine's schedule and model
-        # calls are bit-identical to an uninstrumented build (pinned in
+        # guarded block below is skipped and ``span`` scopes only enter a
+        # profiler annotation — the engine's schedule and model calls are
+        # bit-identical to an uninstrumented build (pinned in
         # tests/test_obs_spans.py).  ``events`` is ring-buffered so
         # monitor/preempt/restore records cannot grow without bound on a
         # long-lived engine (events_capacity=None restores the old
@@ -1047,44 +1072,41 @@ class ServeEngine:
         if not self.reserve_admission:
             if not self._ensure_pages(rid, t1):
                 return None  # stalled; retries this slab next step
-        if self.pool.owns(rid):
-            self.pool.extend(rid, t1 - t0)
-        else:
-            self.pool.allocate(rid, t1)
-        pages = self.pool.pages(rid)
-        n_hist = t0 // self.page_size
         final = t1 == seq.prompt_len
         # the slab runs at the FULL prompt's bucket — every query row's
         # carry format must match the one-shot walk for bit-exactness
         bucket_i, bucket = self.plan.bucket_for(seq.prompt_len)
-        slab_w = self.prefill_chunk or bucket.max_ctx
-        call = (self.plan.kernel_call(
-                    bucket_i, h=self.cfg.n_heads, dh=self.cfg.head_dim,
-                    kv_fmt=self.kv_fmt, slab_tokens=slab_w)
-                if self.cfg is not None else None)
-        slab_span = None
-        if self.tracer is not None:
-            h = self._spans.get(rid)
-            slab_span = self.tracer.start(
-                "prefill_slab", parent=h["root"] if h else None,
-                trace_id=rid, t0=t0, t1=t1, final=final, bucket=bucket_i)
-        tok = self.executor.prefill(PrefillRequest(
-            rid=rid, tokens=tuple(seq.tokens[t0:t1]),
-            hist_pages=tuple(pages[:n_hist]),
-            slab_pages=tuple(pages[n_hist:]), t0=t0, acc=bucket.acc,
-            final=final, bucket_pages=bucket.max_pages(self.page_size),
-            slab_width=slab_w, call=call))
-        if slab_span is not None:
-            self.tracer.end(slab_span)
-        if self.metrics is not None:
-            self._m_slabs.inc()
-        seq.prefilled = t1
-        self.prefill_slabs += 1
-        if final:
-            seq.tokens.append(int(tok))
-            seq.generated.append(int(tok))
-            self._obs_token(rid)
-            self._maybe_finish(seq)
+        h = self._spans.get(rid)
+        with span(self.tracer, "serve.prefill_slab",
+                  parent=h["root"] if h else None, trace_id=rid,
+                  attrs={"t0": t0, "t1": t1, "bucket": bucket_i},
+                  tokens=t1 - t0, final=final):
+            if self.pool.owns(rid):
+                self.pool.extend(rid, t1 - t0)
+            else:
+                self.pool.allocate(rid, t1)
+            pages = self.pool.pages(rid)
+            n_hist = t0 // self.page_size
+            slab_w = self.prefill_chunk or bucket.max_ctx
+            call = (self.plan.kernel_call(
+                        bucket_i, h=self.cfg.n_heads, dh=self.cfg.head_dim,
+                        kv_fmt=self.kv_fmt, slab_tokens=slab_w)
+                    if self.cfg is not None else None)
+            tok = self.executor.prefill(PrefillRequest(
+                rid=rid, tokens=tuple(seq.tokens[t0:t1]),
+                hist_pages=tuple(pages[:n_hist]),
+                slab_pages=tuple(pages[n_hist:]), t0=t0, acc=bucket.acc,
+                final=final, bucket_pages=bucket.max_pages(self.page_size),
+                slab_width=slab_w, call=call))
+            if self.metrics is not None:
+                self._m_slabs.inc()
+            seq.prefilled = t1
+            self.prefill_slabs += 1
+            if final:
+                seq.tokens.append(int(tok))
+                seq.generated.append(int(tok))
+                self._obs_token(rid)
+                self._maybe_finish(seq)
         return rid
 
     # ------------------------------ decode ---------------------------------
@@ -1104,37 +1126,41 @@ class ServeEngine:
             batch.append(seq)
         if not batch:
             return []
-        _, bucket = self.plan.bucket_for(
-            max(self.pool.seq_len(s.rid) for s in batch))
-        width = bucket.max_pages(self.page_size)
-        pt = self.pool.page_table([s.rid for s in batch], width)
-        step_span = None
-        if self.tracer is not None:
-            # engine-level: one decode step batches many requests, so no
-            # trace_id — the rids attr links it to the request trees
-            step_span = self.tracer.start(
-                "decode_step", rids=[s.rid for s in batch])
-        next_toks = self.executor.decode(DecodeRequest(
-            rids=tuple(s.rid for s in batch),
-            last_tokens=tuple(s.tokens[-1] for s in batch),
-            page_table=tuple(tuple(r) for r in pt.tolist()),
-            positions=tuple(s.pos for s in batch),
-            seq_lens=tuple(s.pos + 1 for s in batch), acc=bucket.acc))
-        if step_span is not None:
-            self.tracer.end(step_span)
-        if self.metrics is not None:
-            self._m_decode.inc()
-        finished = []
-        for seq, tok in zip(batch, next_toks):
-            seq.tokens.append(int(tok))
-            seq.generated.append(int(tok))
-            self.decoded_tokens += 1
-            self._obs_token(seq.rid)
-            if self._maybe_finish(seq):
-                finished.append(seq.rid)
+        finished = self._decode_rows(batch)
         self._decode_steps += 1
         if self.monitor_cadence and self._decode_steps % self.monitor_cadence == 0:
             self._monitor()
+        return finished
+
+    def _decode_rows(self, batch: list[_Seq]) -> list[int]:
+        """One batched decode call for ``batch`` (pool pages already
+        extended): a token for each row, bookkept; returns the rids that
+        finished."""
+        rids = [s.rid for s in batch]
+        # engine-level: one decode step batches many requests, so no
+        # trace_id — the rids attr links it to the request trees
+        with span(self.tracer, "serve.decode_step", attrs={"rids": rids},
+                  rows=len(batch)):
+            _, bucket = self.plan.bucket_for(
+                max(self.pool.seq_len(r) for r in rids))
+            width = bucket.max_pages(self.page_size)
+            pt = self.pool.page_table(rids, width)
+            next_toks = self.executor.decode(DecodeRequest(
+                rids=tuple(rids),
+                last_tokens=tuple(s.tokens[-1] for s in batch),
+                page_table=tuple(tuple(r) for r in pt.tolist()),
+                positions=tuple(s.pos for s in batch),
+                seq_lens=tuple(s.pos + 1 for s in batch), acc=bucket.acc))
+            if self.metrics is not None:
+                self._m_decode.inc()
+            finished = []
+            for seq, tok in zip(batch, next_toks):
+                seq.tokens.append(int(tok))
+                seq.generated.append(int(tok))
+                self.decoded_tokens += 1
+                self._obs_token(seq.rid)
+                if self._maybe_finish(seq):
+                    finished.append(seq.rid)
         return finished
 
     def _maybe_finish(self, seq: _Seq) -> bool:
@@ -1153,17 +1179,19 @@ class ServeEngine:
     def step(self) -> dict:
         """One engine tick: <=1 restore-or-admission, <=1 prefill slab, one
         batched decode."""
-        self.steps += 1
-        restored = self._restore_one()
-        admitted = self._admit_one() if restored is None else None
-        self.max_concurrent = max(self.max_concurrent, len(self.active))
-        prefilled = self._prefill_slab()
-        finished = self._decode_batch() if self.active else []
-        if self.metrics is not None:
-            self._m_free.set(self.pool.free_pages)
-            self._m_active.set(len(self.active))
-            self._m_pending.set(len(self.pending))
-            self._m_swapped.set(len(self.swapped))
+        with span(self.tracer, "serve.step"):
+            self.steps += 1
+            with span(self.tracer, "serve.admit"):
+                restored = self._restore_one()
+                admitted = self._admit_one() if restored is None else None
+            self.max_concurrent = max(self.max_concurrent, len(self.active))
+            prefilled = self._prefill_slab()
+            finished = self._decode_batch() if self.active else []
+            if self.metrics is not None:
+                self._m_free.set(self.pool.free_pages)
+                self._m_active.set(len(self.active))
+                self._m_pending.set(len(self.pending))
+                self._m_swapped.set(len(self.swapped))
         return {"admitted": admitted, "restored": restored,
                 "prefilled": prefilled, "finished": finished,
                 "active": len(self.active), "pending": len(self.pending),
@@ -1198,59 +1226,60 @@ class ServeEngine:
         context conservatively, and the knee test is evaluated once per
         (bucket, resumption_count) per process — not once per monitor
         tick."""
-        running = [r for r, s in self.active.items() if not s.in_prefill]
-        if not running:
-            return
-        sid = max(running, key=lambda r: self.pool.seq_len(r))
-        ctx = self.pool.seq_len(sid)
-        bucket_i, bucket = self.plan.bucket_for(ctx)
-        width = bucket.max_pages(self.page_size)
-        self._key, sub = jax.random.split(self._key)
-        stats = self.executor.measure_vrr(
-            self.pool.page_table([sid], width)[0], ctx, bucket.acc, sub)
-        n2 = -(-ctx // self.page_size)
-        swamp = float(stats.swamp_rate)
-        v_pred = certified_log_v(
-            bucket.m_acc, self.plan.m_p, self.page_size, bucket.max_ctx,
-            extra_carry_events(self.page_size, self.plan.prefill_chunk,
-                               bucket.resumptions))
-        breach_m = swamp >= self.swamp_threshold
-        breach_p = v_pred >= CUTOFF_LOG_V
-        breach = breach_m or breach_p
-        if breach:
-            self.plan = self.plan.bumped(bucket_i)
-        # the realized width after the (carrier-clamped) bump — at the
-        # m_acc ceiling a breach is a saturated no-op, and the log says so
-        m_now = self.plan.buckets[bucket_i].m_acc
-        event = {
-            "step": self._decode_steps,
-            "event": ("rebucket" if breach and m_now > bucket.m_acc
-                      else "saturated" if breach else "ok"),
-            "source": ("both" if breach_m and breach_p
-                       else "measured" if breach_m
-                       else "predicted" if breach_p else None),
-            "gemm": "attn_decode", "role": "serve",
-            "bucket": bucket_i, "ctx": ctx, "n1": self.page_size, "n2": n2,
-            "m_acc": m_now,
-            "measured_vrr": round(float(stats.measured_vrr), 6),
-            "log_v": round(float(stats.measured_log_v(n2)), 4),
-            "log_v_pred": round(float(v_pred), 4),
-            "cutoff": round(CUTOFF_LOG_V, 4),
-            "swamp_rate": round(swamp, 6),
-            "swamp_threshold": self.swamp_threshold,
-            # measured KV-magnitude hint from this window: what a re-plan
-            # could certify the e_acc overflow bound with, vs the hint the
-            # current plan was built under
-            "v_hint_plan": self.plan.v_hint,
-            "v_hint_measured": derive_v_hint(stats, ctx),
-        }
-        self.events.append(event)
-        if self.monitor_log:
-            jsonl_append(self.monitor_log, [event])
-        if self.metrics is not None:
-            from repro.obs.metrics import record_controller_events
-            record_controller_events(self.metrics, [event],
-                                     area="serve_monitor")
+        with span(self.tracer, "serve.monitor"):
+            running = [r for r, s in self.active.items() if not s.in_prefill]
+            if not running:
+                return
+            sid = max(running, key=lambda r: self.pool.seq_len(r))
+            ctx = self.pool.seq_len(sid)
+            bucket_i, bucket = self.plan.bucket_for(ctx)
+            width = bucket.max_pages(self.page_size)
+            self._key, sub = jax.random.split(self._key)
+            stats = self.executor.measure_vrr(
+                self.pool.page_table([sid], width)[0], ctx, bucket.acc, sub)
+            n2 = -(-ctx // self.page_size)
+            swamp = float(stats.swamp_rate)
+            v_pred = certified_log_v(
+                bucket.m_acc, self.plan.m_p, self.page_size, bucket.max_ctx,
+                extra_carry_events(self.page_size, self.plan.prefill_chunk,
+                                   bucket.resumptions))
+            breach_m = swamp >= self.swamp_threshold
+            breach_p = v_pred >= CUTOFF_LOG_V
+            breach = breach_m or breach_p
+            if breach:
+                self.plan = self.plan.bumped(bucket_i)
+            # the realized width after the (carrier-clamped) bump — at the
+            # m_acc ceiling a breach is a saturated no-op, and the log says so
+            m_now = self.plan.buckets[bucket_i].m_acc
+            event = {
+                "step": self._decode_steps,
+                "event": ("rebucket" if breach and m_now > bucket.m_acc
+                          else "saturated" if breach else "ok"),
+                "source": ("both" if breach_m and breach_p
+                           else "measured" if breach_m
+                           else "predicted" if breach_p else None),
+                "gemm": "attn_decode", "role": "serve",
+                "bucket": bucket_i, "ctx": ctx, "n1": self.page_size, "n2": n2,
+                "m_acc": m_now,
+                "measured_vrr": round(float(stats.measured_vrr), 6),
+                "log_v": round(float(stats.measured_log_v(n2)), 4),
+                "log_v_pred": round(float(v_pred), 4),
+                "cutoff": round(CUTOFF_LOG_V, 4),
+                "swamp_rate": round(swamp, 6),
+                "swamp_threshold": self.swamp_threshold,
+                # measured KV-magnitude hint from this window: what a re-plan
+                # could certify the e_acc overflow bound with, vs the hint the
+                # current plan was built under
+                "v_hint_plan": self.plan.v_hint,
+                "v_hint_measured": derive_v_hint(stats, ctx),
+            }
+            self.events.append(event)
+            if self.monitor_log:
+                jsonl_append(self.monitor_log, [event])
+            if self.metrics is not None:
+                from repro.obs.metrics import record_controller_events
+                record_controller_events(self.metrics, [event],
+                                         area="serve_monitor")
 
     # ------------------------------ accounting -----------------------------
     def utilization(self) -> float:

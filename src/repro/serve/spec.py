@@ -52,6 +52,7 @@ and every round emits ``draft`` / ``verify`` / ``rollback`` spans.
 from __future__ import annotations
 
 from repro.models.api import DecodeRequest, PrefillRequest, VerifyRequest
+from repro.obs.trace import span
 from repro.serve.kvcache import PagedKVConfig, PagePool
 from repro.serve.plan import plan_attention, plan_verify
 from repro.serve.scheduler import ModelExecutor, ServeEngine, _Seq
@@ -281,7 +282,7 @@ class SpecDecodeEngine(ServeEngine):
         if spec:
             finished += self._spec_round(spec)
         if plain:
-            finished += self._plain_decode(plain)
+            finished += self._decode_rows(plain)
         if spec or plain:
             self._decode_steps += 1
             if self.monitor_cadence \
@@ -340,30 +341,24 @@ class SpecDecodeEngine(ServeEngine):
         k = self.spec_k
         rows = [s for s, _ in batch]
         rids = [s.rid for s in rows]
-        draft_span = None
-        if self.tracer is not None:
-            draft_span = self.tracer.start("draft", rids=rids, k=k)
-        props, steps = self._propose(batch)
-        if draft_span is not None:
-            self.tracer.end(draft_span, steps=steps)
+        with span(self.tracer, "draft", attrs={"rids": rids}, k=k) as sp:
+            props, steps = self._propose(batch)
+            if sp is not None:
+                sp.attrs["steps"] = steps
 
         # target pool already extended to pos + k + 1 per row (_reserve_spec)
         _, bucket = self.verify_plan.bucket_for(
             max(self.pool.seq_len(r) for r in rids))
         width = bucket.max_pages(self.page_size)
         pt = self.pool.page_table(rids, width)
-        verify_span = None
-        if self.tracer is not None:
-            verify_span = self.tracer.start("verify", rids=rids, k=k)
-        outs = self.executor.verify(VerifyRequest(
-            rids=tuple(rids),
-            tokens=tuple((s.tokens[-1], *props[s.rid]) for s in rows),
-            page_table=tuple(tuple(r) for r in pt.tolist()),
-            positions=tuple(s.pos for s in rows),
-            seq_lens=tuple(s.pos + 1 for s in rows),
-            acc=bucket.acc))
-        if verify_span is not None:
-            self.tracer.end(verify_span)
+        with span(self.tracer, "verify", attrs={"rids": rids}, k=k):
+            outs = self.executor.verify(VerifyRequest(
+                rids=tuple(rids),
+                tokens=tuple((s.tokens[-1], *props[s.rid]) for s in rows),
+                page_table=tuple(tuple(r) for r in pt.tolist()),
+                positions=tuple(s.pos for s in rows),
+                seq_lens=tuple(s.pos + 1 for s in rows),
+                acc=bucket.acc))
         if self.metrics is not None:
             self._m_decode.inc()
 
@@ -418,36 +413,4 @@ class SpecDecodeEngine(ServeEngine):
             from repro.obs.metrics import record_spec_events
             record_spec_events(self.metrics, events)
             self._m_spec_acc.set(self.acceptance_rate())
-        return finished
-
-    def _plain_decode(self, batch: list[_Seq]) -> list[int]:
-        """The base engine's batched single-token decode for rows that sat
-        out the spec round (exhausted budget, page pressure, no draft
-        lane) — pool pages already extended by the caller."""
-        _, bucket = self.plan.bucket_for(
-            max(self.pool.seq_len(s.rid) for s in batch))
-        width = bucket.max_pages(self.page_size)
-        pt = self.pool.page_table([s.rid for s in batch], width)
-        step_span = None
-        if self.tracer is not None:
-            step_span = self.tracer.start(
-                "decode_step", rids=[s.rid for s in batch])
-        next_toks = self.executor.decode(DecodeRequest(
-            rids=tuple(s.rid for s in batch),
-            last_tokens=tuple(s.tokens[-1] for s in batch),
-            page_table=tuple(tuple(r) for r in pt.tolist()),
-            positions=tuple(s.pos for s in batch),
-            seq_lens=tuple(s.pos + 1 for s in batch), acc=bucket.acc))
-        if step_span is not None:
-            self.tracer.end(step_span)
-        if self.metrics is not None:
-            self._m_decode.inc()
-        finished = []
-        for seq, tok in zip(batch, next_toks):
-            seq.tokens.append(int(tok))
-            seq.generated.append(int(tok))
-            self.decoded_tokens += 1
-            self._obs_token(seq.rid)
-            if self._maybe_finish(seq):
-                finished.append(seq.rid)
         return finished

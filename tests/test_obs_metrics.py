@@ -1,5 +1,5 @@
 """Unit tests for the unified observability primitives: the metrics
-registry + exporters, the shared JSONL sink, the bounded ring buffer and
+registry + exporters, the shared JSONL appender, the bounded ring buffer and
 the latency percentile helper."""
 
 from __future__ import annotations
@@ -9,7 +9,6 @@ import json
 import pytest
 
 from repro.obs import (
-    JsonlSink,
     MetricsRegistry,
     RingBuffer,
     jsonl_append,
@@ -58,13 +57,6 @@ def test_jsonl_append_creates_dirs_and_appends(tmp_path):
     jsonl_append(str(p), [{"x": 2}, {"x": 3}])
     rows = [json.loads(line) for line in p.read_text().splitlines()]
     assert rows == [{"x": 1}, {"x": 2}, {"x": 3}]
-
-
-def test_jsonl_sink_none_path_is_disabled(tmp_path):
-    JsonlSink(None).emit({"x": 1})  # no-op, no crash
-    s = JsonlSink(str(tmp_path / "s.jsonl"))
-    s.emit({"x": 1}, {"x": 2})
-    assert len((tmp_path / "s.jsonl").read_text().splitlines()) == 2
 
 
 # --------------------------------------------------------------------------

@@ -10,14 +10,20 @@ deterministic scheduler sim (no model, no device):
   function of (trace, seed): two replays are byte-identical;
 * observability OFF is bit-identical to the instrumented engine: same
   token streams, same event log, same scheduling metrics — the guarded
-  blocks add behavior, never change it.
+  blocks add behavior, never change it;
+* each tick is one ``serve.step`` tree, and the same scopes appear in a
+  ``jax.profiler`` capture of a real engine (CPU), nested as in the tracer
+  and as long, beside programs named ``jit_serve_*``.
 """
 
 from __future__ import annotations
 
+import glob
 import os
 
-from repro.obs import MetricsRegistry, Tracer, VirtualClock, span_forest
+import pytest
+
+from repro.obs import MetricsRegistry, Tracer, VirtualClock, span, span_forest
 from repro.serve.scheduler import ServeEngine
 from repro.serve.sim import (
     SimExecutor,
@@ -75,7 +81,7 @@ def check_span_invariants(eng, tracer, *, ctx=""):
     # lifecycle children carry their request's trace_id and close by drain
     swapped = [s for s in spans if s["name"] == "swapped"]
     for s in spans:
-        if s["name"] in ("queued", "swapped", "prefill_slab"):
+        if s["name"] in ("queued", "swapped", "serve.prefill_slab"):
             assert s["parent_id"] is not None and s["trace_id"] in roots, (
                 f"{ctx}: orphan {s['name']} span")
             assert s["t_end"] is not None, (
@@ -248,3 +254,240 @@ def test_tpot_sorts_reordered_token_events():
     (lat,) = request_latencies([span])
     assert lat["ttft"] == 1.0
     assert lat["tpot"] == 2.0
+
+
+# --------------------------------------------------------------------------
+# per-tick scopes: the span helper, the sim engine's tree, the profile
+# --------------------------------------------------------------------------
+
+
+def test_span_helper_nests_and_takes_explicit_parents():
+    assert span(None, "x").__enter__() is None
+    tr = Tracer(clock=VirtualClock())
+    root = tr.start("request", trace_id=5)
+    with span(tr, "serve.step") as step:
+        with span(tr, "serve.decode_step", attrs={"rids": [5]},
+                  rows=1) as dec:
+            with span(tr, "executor.decode", rows=1) as ex:
+                pass
+        with span(tr, "serve.prefill_slab", parent=root, trace_id=5,
+                  tokens=4, final=True) as slab:
+            with span(tr, "executor.prefill", tokens=4) as exp:
+                pass
+    assert step.parent_id is None and not step.open
+    assert dec.parent_id == step.span_id
+    assert dec.attrs == {"rids": [5], "rows": 1}
+    assert ex.parent_id == dec.span_id
+    assert slab.parent_id == root.span_id and slab.trace_id == 5
+    assert exp.parent_id == slab.span_id and exp.trace_id == 5
+    assert not tr._open
+    span_forest(tr.spans)
+
+
+def tick_trees(spans):
+    """{serve.step span id: [(child name, attrs), ...]} in start order."""
+    by_parent = {}
+    for sp in spans:
+        by_parent.setdefault(sp["parent_id"], []).append(sp)
+    out = {}
+    for step in (sp for sp in spans if sp["name"] == "serve.step"):
+        assert step["parent_id"] is None and step["trace_id"] is None
+        out[step["span_id"]] = [
+            (c["name"], c["attrs"]) for c in by_parent.get(
+                step["span_id"], [])]
+    return out
+
+
+def test_tick_span_tree_under_virtual_clock():
+    """Each engine tick is one ``serve.step`` root holding ``serve.admit``
+    and, when any row decodes, ``serve.decode_step`` with its ``rows``;
+    ``serve.prefill_slab`` stays a child of its request, carrying
+    ``tokens`` and ``final``."""
+    eng, tracer, _ = traced_replay(BASE_SEED + 5)
+    spans = tracer.to_dicts()
+    trees = tick_trees(spans)
+    assert len(trees) == eng.steps
+    decodes = 0
+    for kids in trees.values():
+        names = [n for n, _ in kids]
+        assert names[0] == "serve.admit"
+        assert set(names) <= {"serve.admit", "serve.decode_step"}
+        for name, attrs in kids:
+            if name == "serve.decode_step":
+                assert attrs["rows"] == len(attrs["rids"]) >= 1
+                decodes += 1
+    assert decodes == sum(1 for s in spans
+                          if s["name"] == "serve.decode_step")
+    slabs = [s for s in spans if s["name"] == "serve.prefill_slab"]
+    assert len(slabs) == eng.prefill_slabs
+    roots = {s["span_id"]: s for s in spans if s["name"] == "request"}
+    for s in slabs:
+        assert roots[s["parent_id"]]["trace_id"] == s["trace_id"]
+        assert s["attrs"]["tokens"] == s["attrs"]["t1"] - s["attrs"]["t0"]
+    assert sum(s["attrs"]["final"] for s in slabs) == len(eng.finished)
+    assert sum(s["attrs"]["rows"] for s in spans
+               if s["name"] == "serve.decode_step") == eng.decoded_tokens
+
+
+def test_tick_span_trees_replay_identically():
+    seed = BASE_SEED + 6
+    trees = [tick_trees(traced_replay(seed)[1].to_dicts())
+             for _ in range(2)]
+    assert trees[0] == trees[1] and trees[0]
+
+
+SCOPES = ("serve.step", "serve.admit", "serve.decode_step",
+          "serve.prefill_slab", "serve.monitor", "executor.decode",
+          "executor.decode.wait", "executor.prefill",
+          "executor.prefill.wait")
+
+
+def capture(model, params, out):
+    """A tiny real engine with a tracer and one without, run under one
+    ``jax.profiler`` capture into ``out``: (the profile's host events by
+    name, in start order; the tracer; the two engines)."""
+    import gc
+
+    import jax
+    import numpy as np
+    from jax.profiler import ProfileData
+
+    kw = dict(n_pages=12, page_size=4, max_batch=3, prefill_chunk_tokens=4,
+              monitor_cadence=2, warm_start=True)
+    tracer = Tracer()
+    eng_on = ServeEngine(model, params, tracer=tracer, **kw)
+    eng_off = ServeEngine(model, params, **kw)
+    rng = np.random.RandomState(3)
+    reqs = [(list(rng.randint(1, model.cfg.vocab_size, n)), g)
+            for n, g in ((6, 3), (9, 2), (3, 4))]
+    for eng in (eng_on, eng_off):
+        for prompt, g in reqs:
+            eng.submit(prompt, g)
+    # the python tracer would put its own hooks between each scope and
+    # its span; the scopes are TraceMe events, which it does not need
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    gc.disable()  # no collection pause inside a scope being compared
+    try:
+        with jax.profiler.trace(out, profiler_options=opts):
+            eng_on.run()
+            eng_off.run()
+    finally:
+        gc.enable()
+    (path,) = glob.glob(os.path.join(out, "plugins", "profile", "*",
+                                     "*.xplane.pb"))
+    events = {}
+    for plane in ProfileData.from_file(path).planes:
+        for line in plane.lines:
+            for e in line.events:
+                if e.name.startswith(("serve.", "executor.")):
+                    events.setdefault(e.name, []).append(
+                        (int(e.start_ns), int(e.end_ns),
+                         {k: v for k, v in e.stats}))
+    for evs in events.values():
+        evs.sort()
+    return events, tracer, eng_on, eng_off
+
+
+def mismatched(events, tracer, tol_s=50e-6) -> list:
+    """Scopes whose tracer span and profile event differ in duration by
+    more than ``tol_s``: [(name, difference in s)].  The engine with a
+    tracer ran first, so its scopes lead the profile's."""
+    out = []
+    for name in SCOPES:
+        mine = [s for s in tracer.spans if s.name == name]
+        for sp, (a, b, _) in zip(mine, events.get(name, [])):
+            d = (b - a) / 1e9 - sp.duration
+            if abs(d) >= tol_s:
+                out.append((name, d))
+    return out
+
+
+@pytest.fixture(scope="module")
+def profiled(tmp_path_factory):
+    """``capture`` on the CPU.  A busy host can deschedule the thread
+    between a scope's profiler timestamp and its tracer timestamp, which
+    no instrumentation controls, so a capture with such a gap is taken
+    again, at most three times in all."""
+    import jax
+
+    from repro.configs import get_smoke_config
+    from repro.models.api import get_model
+
+    model = get_model(get_smoke_config("qwen2-1.5b"))
+    params = model.init_params(jax.random.PRNGKey(0))
+    for _ in range(3):
+        got = capture(model, params,
+                      str(tmp_path_factory.mktemp("profile")))
+        if not mismatched(*got[:2]):
+            break
+    return got
+
+
+def test_engine_and_executor_spans_in_the_profile(profiled):
+    """``serve.step`` ⊃ ``serve.decode_step`` ⊃ ``executor.decode`` ⊃
+    ``executor.decode.wait`` in the profile's host plane, each profile
+    scope as long as its tracer span to within 50 µs."""
+    events, tracer, eng_on, _ = profiled
+    spans = [s for s in tracer.spans if s.name.startswith(
+        ("serve.", "executor."))]
+    mine = {}
+    for s in spans:
+        mine.setdefault(s.name, []).append(s)
+    for name in SCOPES:
+        assert mine.get(name) and len(events[name]) >= len(mine[name]), name
+    assert mismatched(events, tracer) == []
+    for name, outer in (("serve.decode_step", "serve.step"),
+                        ("executor.decode", "serve.decode_step"),
+                        ("executor.decode.wait", "executor.decode"),
+                        ("executor.prefill", "serve.prefill_slab"),
+                        ("serve.prefill_slab", "serve.step"),
+                        ("serve.monitor", "serve.step")):
+        for a, b, _ in events[name]:
+            assert any(s <= a and b <= e for s, e, _ in events[outer]), (
+                name, outer)
+        for sp in mine[name]:
+            if name != "serve.prefill_slab":  # its parent is the request
+                parent = next(p for p in spans
+                              if p.span_id == sp.parent_id)
+                assert parent.name == outer
+    rows = [st["rows"] for _, _, st in events["serve.decode_step"]]
+    assert rows[:len(mine["serve.decode_step"])] == [
+        s.attrs["rows"] for s in mine["serve.decode_step"]]
+    assert sum(st["final"] for _, _, st in
+               events["serve.prefill_slab"]) == 2 * len(eng_on.finished)
+
+
+def test_engine_without_tracer_records_nothing(profiled):
+    """The engine without a tracer writes its scopes into the profile
+    (twice the tracer's ticks are there) and keeps no span state."""
+    events, tracer, eng_on, eng_off = profiled
+    assert eng_off.tracer is None and eng_off.executor.tracer is None
+    assert not eng_off._spans
+    assert eng_on.executor.tracer is tracer
+    assert len(events["serve.step"]) == eng_on.steps + eng_off.steps
+    assert eng_off.finished == eng_on.finished
+
+
+def test_executor_programs_are_named(profiled):
+    """Every executor program lowers to a module named after its kind, so
+    the profile's ``XLA Modules`` line tells decode from prefill slabs."""
+    import jax.numpy as jnp
+
+    _, _, eng, _ = profiled
+    ex, b = eng.executor, eng.plan.buckets[0]
+    w, mb = b.max_pages(eng.page_size), ex.max_batch
+    dec = ex._decode_fn(b.acc).lower(
+        ex.params, jnp.zeros((mb, 1), jnp.int32), ex.kv,
+        jnp.zeros((mb, w), jnp.int32), jnp.zeros((mb,), jnp.int32),
+        jnp.zeros((mb,), jnp.int32))
+    assert "module @jit_serve_decode " in dec.as_text()
+    call = eng.plan.kernel_call(0, h=eng.cfg.n_heads, dh=eng.cfg.head_dim,
+                                kv_fmt=eng.kv_fmt, slab_tokens=4)
+    for final, module in ((True, "jit_serve_prefill_final"),
+                          (False, "jit_serve_prefill")):
+        low = ex._prefill_fn(b.acc, final, call).lower(
+            ex.params, jnp.zeros((1, 4), jnp.int32), ex.kv,
+            jnp.zeros((w,), jnp.int32), jnp.zeros((1,), jnp.int32),
+            jnp.int32(0), jnp.int32(4))
+        assert f"module @{module} " in low.as_text()
