@@ -20,9 +20,15 @@ inference dividend.  Two kernels cover the serve path:
   folds its own causal slab with a carry-in call.
 * ``paged_attn_decode`` — single-token decode against the paged QTensor
   KV-cache (``repro.serve.kvcache``): the page table and per-page scale
-  exponents ride in as scalar-prefetch operands, each grid step DMAs one
-  int8 page, unpacks it in VMEM (``repro.quant.qtensor`` layout, times the
-  page's power-of-two scale) and folds it into the online softmax — no
+  exponents ride in as scalar-prefetch operands, and each grid step walks
+  a block of up to 256 tokens of one sequence (``_decode_block_pages``
+  pages) for all its KV heads.  The block's int8 pages are DMA'd into a
+  double-buffered VMEM scratch while the previous block computes (heads
+  narrower than the 128 lanes take them as pipelined grid operands;
+  blocks past the sequence's length fetch nothing), unpacked in VMEM
+  (``repro.quant.qtensor`` layout, times each page's power-of-two scale)
+  and scored in one contraction; the online softmax then folds the block
+  page by page, its carry rounded once per page as before — no
   dequantized copy of the cache ever exists in HBM.
 * ``flash_prefill_paged`` — causal prefill rebuilt on the decode kernel's
   scalar-prefetch pattern: the page row, per-page scale exponents and the
@@ -71,6 +77,7 @@ from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels.autotune import AttnCall, fmt_tuple, register_kernel
 from repro.kernels.common import (
+    LANES,
     N_STATS,
     ROUNDINGS,
     quantize_block,
@@ -564,51 +571,137 @@ def _page_values(ref, se_ref, pid, *, packed, e_kv, m_kv):
         se_ref[pid].astype(jnp.float32))
 
 
-def _decode_kernel(pt_ref, sl_ref, kse_ref, vse_ref, q_ref, k_ref, v_ref,
-                   *refs, packed, e_kv, m_kv, e_acc, m_acc,
-                   page_size, scale, emit_carry=False):
-    out_refs, (oacc, mx, lx) = refs[:-3], refs[-3:]
-    b, p = pl.program_id(0), pl.program_id(2)
+def _dequant_block(x, se_ref, pids, *, packed, e_kv, m_kv):
+    """A block of pages (len(pids), ..., page_size, dh) as a list of per-page
+    f32 values: one unpack over the block, then each page's scale."""
+    if not packed:
+        return list(x)
+    u = unpack_block(x, e_kv, m_kv)
+    return [u[i] * jnp.exp2(se_ref[pid].astype(jnp.float32))
+            for i, pid in enumerate(pids)]
 
-    @pl.when(p == 0)
+
+# the serving decode kernel's block: the most pages that divide the page
+# table's width and fit this many tokens make one grid step
+_DECODE_BLOCK_TOKENS = 256
+
+
+def _decode_block_pages(page_size: int, max_pages: int) -> int:
+    """Pages per grid step of the serving decode kernel, from shapes alone:
+    the most that divide ``max_pages`` within ``_DECODE_BLOCK_TOKENS``
+    tokens (the whole table when it is narrower)."""
+    cap = max(1, min(max_pages, _DECODE_BLOCK_TOKENS // page_size))
+    return max(d for d in range(1, cap + 1) if max_pages % d == 0)
+
+
+def _decode_kernel(pt_ref, sl_ref, kse_ref, vse_ref, q_ref, *refs, ppb,
+                   manual, packed, e_kv, m_kv, e_acc, m_acc, page_size,
+                   scale, emit_carry=False):
+    """Grid (B, max_pages // ppb): one step walks ``ppb`` pages of one row
+    for every KV head, and blocks wholly past the row's length fetch and
+    compute nothing.  ``manual``: the arena stays in HBM and a live block's
+    pages are copied (one contiguous (KV, page_size, dh) DMA each) into
+    one slot of a double buffer while the block before it computes.
+    Otherwise each of the block's pages is a grid operand of its own whose
+    index map repeats the row's last live block past its length, so the
+    pipeline fetches nothing there.  Within a block the unpack and the
+    scores of all its pages are batched; the carries then fold page by
+    page through ``_online_update``, rounded once per page as on the
+    one-page grid, so the walk is bit-identical to it."""
+    oacc, mx, lx = refs[-3:]
+    b, j = pl.program_id(0), pl.program_id(1)
+    n_blk = pl.num_programs(1)
+    seq_len = sl_ref[b]
+    blk_tokens = ppb * page_size
+    kv, g = q_ref.shape[1], q_ref.shape[2]
+    live = j * blk_tokens < seq_len
+
+    @pl.when(j == 0)
     def _init():
         oacc[...] = jnp.zeros_like(oacc)
         mx[...] = jnp.full_like(mx, NEG)
         lx[...] = jnp.zeros_like(lx)
 
-    # pages wholly past the sequence's length (the page-table row padding
-    # of a mixed-length batch, pointing at the null page) are provably
-    # carry no-ops — predicate their work away
-    @pl.when(p * page_size < sl_ref[b])
-    def _update():
-        pid = pt_ref[b, p]
-        k = _page_values(k_ref, kse_ref, pid, packed=packed, e_kv=e_kv,
-                         m_kv=m_kv)
-        v = _page_values(v_ref, vse_ref, pid, packed=packed, e_kv=e_kv,
-                         m_kv=m_kv)
-        q = q_ref[0, 0]  # (g, dh)
-        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32) * scale
-        tok = p * page_size + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-        valid = tok < sl_ref[b]
-        s = jnp.where(valid, s, NEG)
-        o_new, m_new, l_new = _online_update(
-            oacc[...], mx[...], lx[...], s, valid, v, e_acc, m_acc)
-        oacc[...] = o_new
-        mx[...] = m_new
-        lx[...] = l_new
+    if manual:
+        k_hbm, v_hbm = refs[:2]
+        out_refs = refs[2:-6]
+        kbuf, vbuf, sem = refs[-6:-3]
+        slot = jax.lax.rem(j, 2)
 
-    @pl.when(p == pl.num_programs(2) - 1)
+        def copies(blk, into):
+            out = []
+            for i in range(ppb):
+                pid = pt_ref[b, blk * ppb + i]
+                out.append(pltpu.make_async_copy(
+                    k_hbm.at[pid], kbuf.at[into, i], sem.at[0, into]))
+                out.append(pltpu.make_async_copy(
+                    v_hbm.at[pid], vbuf.at[into, i], sem.at[1, into]))
+            return out
+
+        # a row's first block starts its own copy; every live block starts
+        # the next one's, into the other slot, before it waits on its own
+        @pl.when((j == 0) & live)
+        def _fetch_first():
+            for c in copies(j, slot):
+                c.start()
+
+        @pl.when((j + 1 < n_blk) & ((j + 1) * blk_tokens < seq_len))
+        def _fetch_next():
+            for c in copies(j + 1, 1 - slot):
+                c.start()
+
+        def load():
+            for c in copies(j, slot):
+                c.wait()
+            return kbuf[slot], vbuf[slot]
+    else:
+        k_refs, v_refs = refs[:ppb], refs[ppb:2 * ppb]
+        out_refs = refs[2 * ppb:-3]
+
+        def load():
+            return (jnp.concatenate([r[...] for r in k_refs]),
+                    jnp.concatenate([r[...] for r in v_refs]))
+
+    # blocks wholly past the sequence's length (the page-table padding of
+    # a mixed-length batch, pointing at the null page) are provably carry
+    # no-ops — predicate their work away
+    @pl.when(live)
+    def _update():
+        kb, vb = load()  # (ppb, KV, page_size, dh) each
+        pids = [pt_ref[b, j * ppb + i] for i in range(ppb)]
+        ks = _dequant_block(kb, kse_ref, pids, packed=packed, e_kv=e_kv,
+                            m_kv=m_kv)
+        vs = _dequant_block(vb, vse_ref, pids, packed=packed, e_kv=e_kv,
+                            m_kv=m_kv)
+        # every head's keys of the block, (KV, ppb * page_size, dh), scored
+        # against the head's query rows in one contraction
+        s = jax.lax.dot_general(
+            q_ref[0], jnp.concatenate(ks, axis=1),
+            (((2,), (2,)), ((0,), (0,))),
+            preferred_element_type=jnp.float32) * scale
+        o, m, l = oacc[...], mx[...], lx[...]
+        tok = j * blk_tokens + jax.lax.broadcasted_iota(
+            jnp.int32, (kv, g, page_size), 2)
+        for i in range(ppb):
+            valid = tok + i * page_size < seq_len
+            t = jnp.where(valid, s[..., i * page_size:(i + 1) * page_size],
+                          NEG)
+            o, m, l = _online_update(o, m, l, t, valid, vs[i], e_acc, m_acc)
+        oacc[...] = o
+        mx[...] = m
+        lx[...] = l
+
+    @pl.when(j == n_blk - 1)
     def _emit():
         if emit_carry:
             # raw carry out: the cross-shard merge (psum_carry) owns the
             # finalize — emitting (o, m, l) unfinalized keeps the merge an
             # exact exponent-shift combine
-            out_refs[0][0, 0] = oacc[...]
-            out_refs[1][0, 0] = mx[...]
-            out_refs[2][0, 0] = lx[...]
+            out_refs[0][0] = oacc[...]
+            out_refs[1][0] = mx[...]
+            out_refs[2][0] = lx[...]
         else:
-            out_refs[0][0, 0] = _finalize(oacc[...], lx[...])
+            out_refs[0][0] = _finalize(oacc[...], lx[...])
 
 
 def _decode_kernel_stats(pt_ref, sl_ref, kse_ref, vse_ref, q_ref, k_ref,
@@ -689,36 +782,29 @@ def _paged_decode(q4, k_pages, v_pages, k_se, v_se, page_table, seq_lens, *,
     b, kv, g, dh = q4.shape
     page_size = k_pages.shape[2]
     max_pages = page_table.shape[1]
-    grid = (b, kv, max_pages)
     kw = dict(packed=packed, e_kv=e_kv, m_kv=m_kv, e_acc=e_acc, m_acc=m_acc,
               page_size=page_size, scale=LOG2E / math.sqrt(dh))
-    # scalar-prefetch operands (SMEM): page table, lengths, page scale
-    # exponents — the index maps gather each sequence's pages through them
-    in_specs = [
-        pl.BlockSpec((1, 1, g, dh),
-                     lambda bb, hk, p, pt, sl, ks, vs: (bb, hk, 0, 0)),
-        pl.BlockSpec((1, 1, page_size, dh),
-                     lambda bb, hk, p, pt, sl, ks, vs: (pt[bb, p], hk, 0, 0)),
-        pl.BlockSpec((1, 1, page_size, dh),
-                     lambda bb, hk, p, pt, sl, ks, vs: (pt[bb, p], hk, 0, 0)),
-    ]
-    o_spec = pl.BlockSpec((1, 1, g, dh),
-                          lambda bb, hk, p, pt, sl, ks, vs: (bb, hk, 0, 0))
-    o_shape = jax.ShapeDtypeStruct((b, kv, g, dh), jnp.float32)
-    scratch = [
-        pltpu.VMEM((g, dh), jnp.float32),  # o carry
-        pltpu.VMEM((g, 1), jnp.float32),   # running max (exact)
-        pltpu.VMEM((g, 1), jnp.float32),   # l carry
-    ]
     if collect_stats:
+        # scalar-prefetch operands (SMEM): page table, lengths, page scale
+        # exponents — the index maps gather each sequence's pages through
+        # them, one page per grid step
+        grid = (b, kv, max_pages)
+        row = lambda bb, hk, p, pt, sl, ks, vs: (bb, hk, 0, 0)
+        page = lambda bb, hk, p, pt, sl, ks, vs: (pt[bb, p], hk, 0, 0)
+        in_specs = [pl.BlockSpec((1, 1, g, dh), row),
+                    pl.BlockSpec((1, 1, page_size, dh), page),
+                    pl.BlockSpec((1, 1, page_size, dh), page)]
         grid_spec = pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=4, grid=grid, in_specs=in_specs,
             out_specs=[
-                o_spec,
+                pl.BlockSpec((1, 1, g, dh), row),
                 pl.BlockSpec((1, N_STATS),
                              lambda bb, hk, p, pt, sl, ks, vs: (0, 0)),
             ],
-            scratch_shapes=scratch + [
+            scratch_shapes=[
+                pltpu.VMEM((g, dh), jnp.float32),  # o carry
+                pltpu.VMEM((g, 1), jnp.float32),   # running max (exact)
+                pltpu.VMEM((g, 1), jnp.float32),   # l carry
                 pltpu.VMEM((g, dh), jnp.float32),      # ideal o shadow
                 pltpu.VMEM((1, N_STATS), jnp.float32),  # stats row
             ],
@@ -726,35 +812,67 @@ def _paged_decode(q4, k_pages, v_pages, k_se, v_se, page_table, seq_lens, *,
         out, stats = pl.pallas_call(
             functools.partial(_decode_kernel_stats, **kw),
             grid_spec=grid_spec,
-            out_shape=[o_shape,
+            out_shape=[jax.ShapeDtypeStruct((b, kv, g, dh), jnp.float32),
                        jax.ShapeDtypeStruct((1, N_STATS), jnp.float32)],
             interpret=interpret,
         )(page_table, seq_lens, k_se, v_se, q4, k_pages, v_pages)
         return out, stats[0]
 
-    if return_carry:
-        c_spec = pl.BlockSpec((1, 1, g, 1),
-                              lambda bb, hk, p, pt, sl, ks, vs: (bb, hk, 0, 0))
-        c_shape = jax.ShapeDtypeStruct((b, kv, g, 1), jnp.float32)
-        grid_spec = pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=4, grid=grid, in_specs=in_specs,
-            out_specs=[o_spec, c_spec, c_spec], scratch_shapes=scratch)
-        return pl.pallas_call(
-            functools.partial(_decode_kernel, emit_carry=True, **kw),
-            grid_spec=grid_spec,
-            out_shape=[o_shape, c_shape, c_shape],
-            interpret=interpret,
-        )(page_table, seq_lens, k_se, v_se, q4, k_pages, v_pages)
+    ppb = _decode_block_pages(page_size, max_pages)
+    # the kernel's own copies slice the arena by page, which Mosaic allows
+    # only when a page row fills whole lanes; narrower heads take the pages
+    # as pipelined grid operands instead (same blocks, same folds)
+    manual = dh % LANES == 0
+    row = lambda bb, j, pt, sl, ks, vs: (bb, 0, 0, 0)
+    if manual:
+        page_specs = [pl.BlockSpec(memory_space=pl.ANY)] * 2
+        operands = [k_pages, v_pages]
+    else:
+        blk_tokens = ppb * page_size
 
+        def page_map(i):
+            def index(bb, j, pt, sl, ks, vs):
+                last = jnp.maximum(sl[bb] - 1, 0) // blk_tokens
+                return (pt[bb, jnp.minimum(j, last) * ppb + i], 0, 0, 0)
+            return index
+
+        page_specs = [pl.BlockSpec((1, kv, page_size, dh), page_map(i))
+                      for i in range(ppb)] * 2
+        operands = [k_pages] * ppb + [v_pages] * ppb
+    in_specs = [pl.BlockSpec((1, kv, g, dh), row)] + page_specs
+    o_spec = pl.BlockSpec((1, kv, g, dh), row)
+    o_shape = jax.ShapeDtypeStruct((b, kv, g, dh), jnp.float32)
+    if return_carry:
+        c_spec = pl.BlockSpec((1, kv, g, 1), row)
+        c_shape = jax.ShapeDtypeStruct((b, kv, g, 1), jnp.float32)
+        out_specs: list | pl.BlockSpec = [o_spec, c_spec, c_spec]
+        out_shape: list | jax.ShapeDtypeStruct = [o_shape, c_shape, c_shape]
+    else:
+        out_specs, out_shape = o_spec, o_shape
+    scratch = []
+    if manual:
+        buf = (2, ppb, kv, page_size, dh)  # double buffer of a block's pages
+        scratch = [pltpu.VMEM(buf, k_pages.dtype),
+                   pltpu.VMEM(buf, v_pages.dtype),
+                   pltpu.SemaphoreType.DMA((2, 2))]  # (k | v, slot)
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=4, grid=grid, in_specs=in_specs,
-        out_specs=o_spec, scratch_shapes=scratch)
+        num_scalar_prefetch=4, grid=(b, max_pages // ppb), in_specs=in_specs,
+        out_specs=out_specs,
+        scratch_shapes=scratch + [
+            pltpu.VMEM((kv, g, dh), jnp.float32),  # o carry
+            pltpu.VMEM((kv, g, 1), jnp.float32),   # running max (exact)
+            pltpu.VMEM((kv, g, 1), jnp.float32),   # l carry
+        ])
     return pl.pallas_call(
-        functools.partial(_decode_kernel, **kw),
+        functools.partial(_decode_kernel, ppb=ppb, manual=manual,
+                          emit_carry=return_carry, **kw),
         grid_spec=grid_spec,
-        out_shape=o_shape,
+        out_shape=out_shape,
+        # a row's blocks hand copies on to each other; rows are independent
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
-    )(page_table, seq_lens, k_se, v_se, q4, k_pages, v_pages)
+    )(page_table, seq_lens, k_se, v_se, q4, *operands)
 
 
 @register_kernel("paged_attn_decode")

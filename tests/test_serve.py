@@ -31,25 +31,32 @@ ACC = (6, 7)
 
 
 def _filled_arena(rng, *, kv=2, dh=16, n_pages=10, page_size=4,
-                  seq_tokens=(7, 3), fmt=FP8_152, scale=1.0):
+                  seq_tokens=(7, 3), fmt=FP8_152, scale=1.0, width=None):
     """One-layer arena with each sequence's K/V written via write_prompt;
-    returns (arena dict of layer-0 slices, page table rows, lens)."""
+    returns (arena dict of layer-0 slices, page table rows, lens).  The
+    table is as wide as the longest row, or ``width`` pages."""
     pc = KV.PagedKVConfig(n_layers=1, n_kv_heads=kv, head_dim=dh,
                           n_pages=n_pages, page_size=page_size, kv_fmt=fmt)
     ar = KV.init_arena(pc)
     ka, kse = ar["k"][0], ar["k_se"][0]
     va, vse = ar["v"][0], ar["v_se"][0]
-    rows, next_page = [], 1  # page 0 reserved
+    rows, next_page, ks, vs = [], 1, [], []  # page 0 reserved
     for n in seq_tokens:
         npg = -(-n // page_size)
-        pages = list(range(next_page, next_page + npg))
+        rows.append(list(range(next_page, next_page + npg)))
         next_page += npg
-        k = jnp.asarray(rng.standard_normal((n, kv, dh)).astype(np.float32)) * scale
-        v = jnp.asarray(rng.standard_normal((n, kv, dh)).astype(np.float32)) * scale
-        ka, kse, _ = KV.write_prompt(ka, kse, k, jnp.asarray(pages), fmt)
-        va, vse, _ = KV.write_prompt(va, vse, v, jnp.asarray(pages), fmt)
-        rows.append(pages)
-    width = max(len(r) for r in rows)
+        # each row padded to whole pages with zeros, as write_prompt pads
+        # its tail page: every row goes into the arena in one write
+        pad = ((0, npg * page_size - n), (0, 0), (0, 0))
+        ks.append(np.pad(rng.standard_normal((n, kv, dh)), pad) * scale)
+        vs.append(np.pad(rng.standard_normal((n, kv, dh)), pad) * scale)
+    pages = jnp.asarray([p for r in rows for p in r], jnp.int32)
+    if len(pages):
+        k, v = (jnp.asarray(np.concatenate(x).astype(np.float32))
+                for x in (ks, vs))
+        ka, kse, _ = KV.write_prompt(ka, kse, k, pages, fmt)
+        va, vse, _ = KV.write_prompt(va, vse, v, pages, fmt)
+    width = width or max(len(r) for r in rows)
     pt = np.zeros((len(rows), width), np.int32)
     for i, r in enumerate(rows):
         pt[i, :len(r)] = r
@@ -62,23 +69,52 @@ def _filled_arena(rng, *, kv=2, dh=16, n_pages=10, page_size=4,
 # --------------------------------------------------------------------------
 
 
-@pytest.mark.parametrize("seq_tokens", [
-    (7, 3),        # ragged page tails
-    (8, 4),        # decode exactly at page boundaries
-    (9, 1, 12),    # boundary + single-token + multi-page
+# 16-token pages in a 32-page table: the kernel walks two 16-page blocks a
+# row, so rows end mid-block, on block and page edges, or skip a block
+_BLOCKS = dict(page_size=16, width=32)
+
+
+@pytest.mark.parametrize("acc,seq_tokens,geom", [
+    *(pytest.param(acc, seq, {}, id=f"acc{i}-seq_tokens{j}")
+      for i, acc in enumerate([(8, 23), (6, 23), ACC, (6, 5)])
+      for j, seq in enumerate([
+          (7, 3),        # ragged page tails
+          (8, 4),        # decode exactly at page boundaries
+          (9, 1, 12),    # boundary + single-token + multi-page
+      ])),
+    pytest.param(ACC, (300, 40, 500), _BLOCKS, id="blocks-mid_block"),
+    pytest.param(ACC, (256, 512, 16), _BLOCKS, id="blocks-block_edge"),
+    pytest.param(ACC, (1, 400), _BLOCKS, id="blocks-one_token"),
+    pytest.param(ACC, (0, 333, 0), _BLOCKS, id="blocks-inactive_row"),
+    pytest.param(ACC, (100, 37), dict(page_size=16, width=8),
+                 id="narrower_than_a_block"),
+    pytest.param((6, 5), (512, 77, 257), dict(_BLOCKS, h=12),
+                 id="blocks-kv2_g6"),
+    pytest.param(ACC, (290, 0, 64), dict(_BLOCKS, return_carry=True),
+                 id="blocks-carry"),
+    # dh 128: the arena is copied by the kernel's own DMAs
+    pytest.param((6, 5), (450, 3), dict(_BLOCKS, dh=128, return_carry=True),
+                 id="blocks-dh128-carry"),
 ])
-@pytest.mark.parametrize("acc", [(8, 23), (6, 23), ACC, (6, 5)])
-def test_paged_decode_bitexact_vs_oracle(seq_tokens, acc):
+def test_paged_decode_bitexact_vs_oracle(acc, seq_tokens, geom):
+    geom = dict(geom)
+    h, carry = geom.pop("h", 4), geom.pop("return_carry", False)
+    dh = geom.get("dh", 16)
+    page_size = geom.get("page_size", 4)
+    n_pages = max(16, 1 + sum(-(-n // page_size) for n in seq_tokens))
     rng = np.random.RandomState(0)
-    arena, pt, lens = _filled_arena(rng, seq_tokens=seq_tokens, n_pages=16)
-    q = jnp.asarray(rng.standard_normal((len(seq_tokens), 4, 16)).astype(np.float32))
-    out = paged_attn_decode(q, arena["k"], arena["v"], arena["k_se"],
-                            arena["v_se"], pt, lens, kv_fmt=FP8_152, acc=acc)
-    ref = paged_attn_decode_reference(q, arena["k"], arena["v"],
-                                      arena["k_se"], arena["v_se"], pt, lens,
-                                      kv_fmt=FP8_152, acc=acc)
-    np.testing.assert_array_equal(np.asarray(out), np.asarray(ref))
-    assert np.all(np.isfinite(np.asarray(out)))
+    arena, pt, lens = _filled_arena(rng, seq_tokens=seq_tokens,
+                                    n_pages=n_pages, **geom)
+    q = jnp.asarray(rng.standard_normal((len(seq_tokens), h, dh))
+                    .astype(np.float32))
+    args = (q, arena["k"], arena["v"], arena["k_se"], arena["v_se"], pt, lens)
+    out = paged_attn_decode(*args, kv_fmt=FP8_152, acc=acc,
+                            return_carry=carry)
+    ref = paged_attn_decode_reference(*args, kv_fmt=FP8_152, acc=acc,
+                                      return_carry=carry)
+    for got, want in zip(out if carry else [out], ref if carry else [ref]):
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+        assert np.all(np.isfinite(np.asarray(got)))
 
 
 def test_paged_decode_packed_vs_f32_parity():

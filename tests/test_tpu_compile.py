@@ -142,15 +142,26 @@ def test_flash_prefill_paged_compiles(one_chip, arch):
         ((call.max_pages,), jnp.int32), i32, i32, i32)
 
 
-@pytest.mark.parametrize("arch", [SERVE_ARCH, TRAIN_ARCH])
-def test_paged_attn_decode_compiles(one_chip, arch):
+# the serve.qwen2-1.5b.decode64 cell: 64 rows at the 4,096-token bucket's
+# 256-page table against its 8,193-page arena
+CELL_ROWS, CELL_PAGES, CELL_WIDTH = 64, 8193, 256
+
+
+@pytest.mark.parametrize("case", [SERVE_ARCH, TRAIN_ARCH, "decode64",
+                                  "decode64_carry"])
+def test_paged_attn_decode_compiles(one_chip, case):
+    arch = TRAIN_ARCH if case == TRAIN_ARCH else SERVE_ARCH
     cfg, arena = _arena_shapes(arch)
     plan = plan_attention((N_PAGES - 1) * PAGE, PAGE)
     b = plan.buckets[-1]
+    rows, width = DECODE_ROWS, b.max_pages(PAGE)
+    if case.startswith("decode64"):
+        rows, width = CELL_ROWS, CELL_WIDTH
+        pages = ((CELL_PAGES, cfg.n_kv_heads, PAGE, cfg.head_dim), I8)
+        scales = ((CELL_PAGES,), jnp.int32)
+        arena = [pages, pages, scales, scales]
     _compile(lambda q, kp, vp, ks, vs, pt, sl: paged_attn_decode(
         q, kp, vp, ks, vs, pt, sl, kv_fmt=KV_FMT, acc=b.acc,
-        interpret=False), one_chip,
-        ((DECODE_ROWS, cfg.n_heads, cfg.head_dim), F32), *arena,
-        ((DECODE_ROWS, b.max_pages(PAGE)), jnp.int32),
-        ((DECODE_ROWS,), jnp.int32))
-
+        return_carry=case.endswith("_carry"), interpret=False), one_chip,
+        ((rows, cfg.n_heads, cfg.head_dim), F32), *arena,
+        ((rows, width), jnp.int32), ((rows,), jnp.int32))
