@@ -415,6 +415,33 @@ def _check_shardable(cfg: ModelConfig, dist: L.Dist) -> None:
             "nest inside the serve shard_map")
 
 
+def paged_collective_bytes(cfg: ModelConfig, dist: L.Dist, *, rows: int,
+                           scale_rows: int, logit_rows: int) -> dict:
+    """Bytes the collectives of one tensor-parallel paged call leave on each
+    shard (the size of each collective's result there), from shapes, by
+    kind: ``carry`` (the psum'd attention-carry merge: pmax of m, psum of
+    o and l over full heads), ``gather`` (the tiled all_gathers after
+    ``wo``, the gate/up product and ``w_down``), ``scale`` (the two pmaxes
+    of the K/V page amaxes) and ``logits`` (the head's vocab gather, or
+    the int8 wire's int32 psum and its scale pmax).  ``rows`` are the
+    query rows through the layers (the padded decode batch, or the padded
+    slab), ``scale_rows`` the amaxes each page-scale pmax carries (one a
+    decode row, one a slab page) and ``logit_rows`` the rows unembedded.
+    All zeros on one device."""
+    if dist.shard_axis is None:
+        return {"carry": 0, "gather": 0, "scale": 0, "logits": 0}
+    f32, act = 4, jnp.dtype(L.COMPUTE_DTYPE).itemsize
+    n = cfg.n_layers
+    carry = rows * cfg.n_heads * (cfg.head_dim + 2) * f32
+    gather = rows * (2 * cfg.d_model + cfg.d_ff) * act
+    if dist.logit_wire == "int8":
+        head = logit_rows * cfg.vocab_size * 4 + f32 if logit_rows else 0
+    else:
+        head = 0 if cfg.tie_embeddings else logit_rows * cfg.vocab_size * act
+    return {"carry": n * carry, "gather": n * gather,
+            "scale": n * 2 * scale_rows * f32, "logits": head}
+
+
 def init_paged_state(cfg: ModelConfig, *, n_pages: int, page_size: int,
                      kv_fmt=None) -> dict:
     """Deprecated: use ``models.api.paged_init_state`` (family-agnostic)."""

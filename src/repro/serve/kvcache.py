@@ -46,6 +46,7 @@ import numpy as np
 
 from repro.quant.formats import FPFormat
 from repro.kernels.common import quantize_block
+from repro.obs.trace import span
 from repro.quant.qtensor import pack_block, unpack_block
 
 __all__ = [
@@ -479,24 +480,32 @@ class ShardedPagePool(PagePool):
     drifted — a scheduler path that mutated one shard's accounting without
     the others (the classic TP desync bug) fails the next invariant sweep
     rather than corrupting a remote arena.
+
+    Each mirrored mutation and its lockstep check run inside a
+    ``serve.pool_lockstep`` span (``repro.obs.trace.span``, recorded in
+    ``tracer`` when one is given), so a profile puts their host time down
+    to the engine.
     """
 
-    def __init__(self, n_pages: int, page_size: int, *, n_shards: int):
+    def __init__(self, n_pages: int, page_size: int, *, n_shards: int,
+                 tracer=None):
         if n_shards < 1:
             raise ValueError(f"n_shards must be >= 1, got {n_shards}")
         super().__init__(n_pages, page_size)
         self.n_shards = n_shards
+        self.tracer = tracer
         self._replicas = [PagePool(n_pages, page_size)
                           for _ in range(n_shards)]
 
     def _mirror(self, op: str, sid: int, *args) -> None:
         want = self._pages.get(sid)
-        for i, rep in enumerate(self._replicas):
-            got = getattr(rep, op)(sid, *args)
-            if op != "release" and rep._pages.get(sid) != want:
-                raise AssertionError(
-                    f"shard {i} pool drifted on {op}(sid={sid}): "
-                    f"{got} vs primary {want}")
+        with span(self.tracer, "serve.pool_lockstep"):
+            for i, rep in enumerate(self._replicas):
+                got = getattr(rep, op)(sid, *args)
+                if op != "release" and rep._pages.get(sid) != want:
+                    raise AssertionError(
+                        f"shard {i} pool drifted on {op}(sid={sid}): "
+                        f"{got} vs primary {want}")
 
     def allocate(self, sid: int, n_tokens: int) -> list[int]:
         got = super().allocate(sid, n_tokens)

@@ -238,6 +238,7 @@ class ModelExecutor:
     """
 
     tracer = None  # set by the ``ServeEngine`` that carries a tracer
+    metrics = None  # likewise its metrics registry
 
     def __init__(self, model, params, pc: PagedKVConfig, *,
                  kv_fmt: FPFormat, dist: Dist = LOCAL, oracle: bool = False,
@@ -250,7 +251,7 @@ class ModelExecutor:
         self.dist = dist
         self.oracle = oracle
         self.max_batch = max_batch
-        self.kv = init_arena(pc)
+        self.kv = self._init_arena(pc)
         self.pm = get_paged_model(model.cfg)
         key = self._cache_key()
         try:
@@ -270,6 +271,16 @@ class ModelExecutor:
         relevant state (the sharded executor adds its mesh descriptor)."""
         return ("model-executor", self.cfg, self.kv_fmt, self.dist,
                 self.oracle, self.max_batch, self.pc, _device_topology())
+
+    def _init_arena(self, pc: PagedKVConfig) -> dict:
+        return init_arena(pc)
+
+    def _collectives(self, rows: int, scale_rows: int,
+                     logit_rows: int) -> dict:
+        """Stats of a call's ``executor.*`` span for its collectives: none
+        on one device (see ``ShardedModelExecutor``)."""
+        del rows, scale_rows, logit_rows
+        return {}
 
     # ------------------------------ jit fns --------------------------------
     def _jit(self, key, fn, **jit_kw):
@@ -339,12 +350,13 @@ class ModelExecutor:
         before = stats["compiles"]
         page_size = self.pc.page_size
         n_tok = len(req.tokens)
-        with span(self.tracer, "executor.prefill", tokens=n_tok):
-            width = req.slab_width or n_tok
+        width = req.slab_width or n_tok
+        n_slab = -(-width // page_size)
+        with span(self.tracer, "executor.prefill", tokens=n_tok,
+                  **self._collectives(width, n_slab, int(req.final))):
             toks = np.zeros((1, width), np.int32)
             toks[0, :n_tok] = req.tokens
             n_hist = len(req.hist_pages)
-            n_slab = -(-width // page_size)
             slab = np.zeros((n_slab,), np.int32)
             slab[:len(req.slab_pages)] = req.slab_pages
             row = np.zeros((req.bucket_pages or (n_hist + n_slab),),
@@ -363,7 +375,9 @@ class ModelExecutor:
     def decode(self, req: DecodeRequest) -> list[int]:
         """One batched decode token per row; returns the next tokens."""
         n = len(req.last_tokens)
-        with span(self.tracer, "executor.decode", rows=n):
+        mb = self.max_batch
+        with span(self.tracer, "executor.decode", rows=n,
+                  **self._collectives(mb, mb, mb)):
             logits = self.decode_logits(req)
             with span(self.tracer, "executor.decode.wait"):
                 out = np.asarray(jnp.argmax(logits[:n, 0], axis=-1))
@@ -651,6 +665,35 @@ class ShardedModelExecutor(ModelExecutor):
         return super()._cache_key() + (
             ("mesh", tuple(self.mesh.shape.items()), self.logit_wire),)
 
+    def _init_arena(self, pc: PagedKVConfig) -> dict:
+        # made where it lives, each shard its KV-head slice: the whole
+        # arena of a model that only fits sharded never exists on one chip
+        from repro.sharding.specs import named_shardings
+
+        return jax.jit(lambda: init_arena(pc), out_shardings=named_shardings(
+            self._kv_specs, self.mesh))()
+
+    def _collectives(self, rows: int, scale_rows: int,
+                     logit_rows: int) -> dict:
+        """The call's collective bytes on each shard, by kind
+        (``models.lm.paged_collective_bytes``), counted in the registry's
+        ``repro_serve_collective_bytes_total{kind}`` and returned as the
+        span's ``shards`` and ``collective_bytes`` stats."""
+        from repro.models.lm import paged_collective_bytes
+
+        by_kind = paged_collective_bytes(self.cfg, self.dist, rows=rows,
+                                         scale_rows=scale_rows,
+                                         logit_rows=logit_rows)
+        if self.metrics is not None:
+            c = self.metrics.counter(
+                "repro_serve_collective_bytes_total",
+                "bytes the tensor-parallel executor's collectives leave on "
+                "each shard, from shapes", labels=("kind",))
+            for kind, nbytes in by_kind.items():
+                c.inc(nbytes, kind=kind)
+        return {"shards": self.n_shards,
+                "collective_bytes": sum(by_kind.values())}
+
     def _decode_fn(self, acc: tuple[int, int]):
         import functools
 
@@ -760,15 +803,18 @@ class ServeEngine:
         else:
             self.pc = getattr(executor, "pc", None)
         self.executor = executor
-        if tracer is not None and isinstance(executor, ModelExecutor):
-            executor.tracer = tracer  # its executor.* spans join the ticks'
+        if isinstance(executor, ModelExecutor):
+            if tracer is not None:
+                executor.tracer = tracer  # its executor.* spans join ticks'
+            if metrics is not None:
+                executor.metrics = metrics
         # tensor-parallel executors advertise their shard count; the engine
         # then allocates through a ShardedPagePool (one logical allocator,
         # N mirrored per-shard pools with lockstep assertions) and the plan
         # certifies the cross-shard reduction stage
         self.tp_shards = int(getattr(executor, "n_shards", 1) or 1)
         self.pool = (ShardedPagePool(n_pages, page_size,
-                                     n_shards=self.tp_shards)
+                                     n_shards=self.tp_shards, tracer=tracer)
                      if self.tp_shards > 1 else PagePool(n_pages, page_size))
         self.store = SwapStore()
         self.plan = plan or plan_attention(
