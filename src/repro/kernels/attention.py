@@ -19,8 +19,11 @@ inference dividend.  Two kernels cover the serve path:
   query slab attends its page-aligned KV history with a carry-out call and
   folds its own causal slab with a carry-in call.
 * ``paged_attn_decode`` — single-token decode against the paged QTensor
-  KV-cache (``repro.serve.kvcache``): the page table and per-page scale
-  exponents ride in as scalar-prefetch operands, and each grid step walks
+  KV-cache (``repro.serve.kvcache``): the page table, per-page scale
+  exponents and the layer index ride in as scalar-prefetch operands, the
+  pages are read straight out of the whole ``(L, P, KV, page_size, dh)``
+  arena at ``[layer, page]`` (no layer's slab is sliced out of it for the
+  call), and each grid step walks
   a block of up to 256 tokens of one sequence (``_decode_block_pages``
   pages) for all its KV heads.  The block's int8 pages are DMA'd into a
   double-buffered VMEM scratch while the previous block computes (heads
@@ -594,13 +597,15 @@ def _decode_block_pages(page_size: int, max_pages: int) -> int:
     return max(d for d in range(1, cap + 1) if max_pages % d == 0)
 
 
-def _decode_kernel(pt_ref, sl_ref, kse_ref, vse_ref, q_ref, *refs, ppb,
-                   manual, packed, e_kv, m_kv, e_acc, m_acc, page_size,
+def _decode_kernel(pt_ref, sl_ref, kse_ref, vse_ref, ly_ref, q_ref, *refs,
+                   ppb, manual, packed, e_kv, m_kv, e_acc, m_acc, page_size,
                    scale, emit_carry=False):
     """Grid (B, max_pages // ppb): one step walks ``ppb`` pages of one row
     for every KV head, and blocks wholly past the row's length fetch and
-    compute nothing.  ``manual``: the arena stays in HBM and a live block's
-    pages are copied (one contiguous (KV, page_size, dh) DMA each) into
+    compute nothing.  ``ly_ref`` holds the layer whose pages are read out
+    of the whole arena.  ``manual``: the arena stays in HBM and a live
+    block's pages are copied (one contiguous (KV, page_size, dh) DMA each,
+    at ``[layer, page]``) into
     one slot of a double buffer while the block before it computes.
     Otherwise each of the block's pages is a grid operand of its own whose
     index map repeats the row's last live block past its length, so the
@@ -628,14 +633,16 @@ def _decode_kernel(pt_ref, sl_ref, kse_ref, vse_ref, q_ref, *refs, ppb,
         kbuf, vbuf, sem = refs[-6:-3]
         slot = jax.lax.rem(j, 2)
 
+        layer = ly_ref[0]
+
         def copies(blk, into):
             out = []
             for i in range(ppb):
                 pid = pt_ref[b, blk * ppb + i]
                 out.append(pltpu.make_async_copy(
-                    k_hbm.at[pid], kbuf.at[into, i], sem.at[0, into]))
+                    k_hbm.at[layer, pid], kbuf.at[into, i], sem.at[0, into]))
                 out.append(pltpu.make_async_copy(
-                    v_hbm.at[pid], vbuf.at[into, i], sem.at[1, into]))
+                    v_hbm.at[layer, pid], vbuf.at[into, i], sem.at[1, into]))
             return out
 
         # a row's first block starts its own copy; every live block starts
@@ -704,10 +711,10 @@ def _decode_kernel(pt_ref, sl_ref, kse_ref, vse_ref, q_ref, *refs, ppb,
             out_refs[0][0] = _finalize(oacc[...], lx[...])
 
 
-def _decode_kernel_stats(pt_ref, sl_ref, kse_ref, vse_ref, q_ref, k_ref,
-                         v_ref, o_ref, stats_ref, oacc, mx, lx, oi, stats_acc,
-                         *, packed, e_kv, m_kv, e_acc, m_acc, page_size,
-                         scale):
+def _decode_kernel_stats(pt_ref, sl_ref, kse_ref, vse_ref, ly_ref, q_ref,
+                         k_ref, v_ref, o_ref, stats_ref, oacc, mx, lx, oi,
+                         stats_acc, *, packed, e_kv, m_kv, e_acc, m_acc,
+                         page_size, scale):
     """Telemetry variant: the SAME online-softmax carries — identical
     values, identical order — plus a wide (f32) shadow ``o`` accumulation
     and the (1, N_STATS) swamping reduction over the output ensemble (the
@@ -775,31 +782,32 @@ def _decode_kernel_stats(pt_ref, sl_ref, kse_ref, vse_ref, q_ref, k_ref,
     static_argnames=("packed", "e_kv", "m_kv", "e_acc", "m_acc",
                      "collect_stats", "return_carry", "interpret"),
 )
-def _paged_decode(q4, k_pages, v_pages, k_se, v_se, page_table, seq_lens, *,
-                  packed, e_kv, m_kv, e_acc, m_acc, collect_stats,
+def _paged_decode(q4, k_pages, v_pages, k_se, v_se, page_table, seq_lens,
+                  layer, *, packed, e_kv, m_kv, e_acc, m_acc, collect_stats,
                   return_carry, interpret):
     _count_trace("paged_attn_decode")
     b, kv, g, dh = q4.shape
-    page_size = k_pages.shape[2]
+    page_size = k_pages.shape[3]
     max_pages = page_table.shape[1]
     kw = dict(packed=packed, e_kv=e_kv, m_kv=m_kv, e_acc=e_acc, m_acc=m_acc,
               page_size=page_size, scale=LOG2E / math.sqrt(dh))
     if collect_stats:
-        # scalar-prefetch operands (SMEM): page table, lengths, page scale
-        # exponents — the index maps gather each sequence's pages through
-        # them, one page per grid step
+        # scalar-prefetch operands (SMEM): page table, lengths, the layer's
+        # page scale exponents, the layer — the index maps gather each
+        # sequence's pages through them, one page per grid step
         grid = (b, kv, max_pages)
-        row = lambda bb, hk, p, pt, sl, ks, vs: (bb, hk, 0, 0)
-        page = lambda bb, hk, p, pt, sl, ks, vs: (pt[bb, p], hk, 0, 0)
+        row = lambda bb, hk, p, pt, sl, ks, vs, ly: (bb, hk, 0, 0)
+        page = lambda bb, hk, p, pt, sl, ks, vs, ly: (ly[0], pt[bb, p], hk,
+                                                      0, 0)
         in_specs = [pl.BlockSpec((1, 1, g, dh), row),
-                    pl.BlockSpec((1, 1, page_size, dh), page),
-                    pl.BlockSpec((1, 1, page_size, dh), page)]
+                    pl.BlockSpec((None, 1, 1, page_size, dh), page),
+                    pl.BlockSpec((None, 1, 1, page_size, dh), page)]
         grid_spec = pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=4, grid=grid, in_specs=in_specs,
+            num_scalar_prefetch=5, grid=grid, in_specs=in_specs,
             out_specs=[
                 pl.BlockSpec((1, 1, g, dh), row),
                 pl.BlockSpec((1, N_STATS),
-                             lambda bb, hk, p, pt, sl, ks, vs: (0, 0)),
+                             lambda bb, hk, p, pt, sl, ks, vs, ly: (0, 0)),
             ],
             scratch_shapes=[
                 pltpu.VMEM((g, dh), jnp.float32),  # o carry
@@ -815,7 +823,7 @@ def _paged_decode(q4, k_pages, v_pages, k_se, v_se, page_table, seq_lens, *,
             out_shape=[jax.ShapeDtypeStruct((b, kv, g, dh), jnp.float32),
                        jax.ShapeDtypeStruct((1, N_STATS), jnp.float32)],
             interpret=interpret,
-        )(page_table, seq_lens, k_se, v_se, q4, k_pages, v_pages)
+        )(page_table, seq_lens, k_se, v_se, layer, q4, k_pages, v_pages)
         return out, stats[0]
 
     ppb = _decode_block_pages(page_size, max_pages)
@@ -823,7 +831,7 @@ def _paged_decode(q4, k_pages, v_pages, k_se, v_se, page_table, seq_lens, *,
     # only when a page row fills whole lanes; narrower heads take the pages
     # as pipelined grid operands instead (same blocks, same folds)
     manual = dh % LANES == 0
-    row = lambda bb, j, pt, sl, ks, vs: (bb, 0, 0, 0)
+    row = lambda bb, j, pt, sl, ks, vs, ly: (bb, 0, 0, 0)
     if manual:
         page_specs = [pl.BlockSpec(memory_space=pl.ANY)] * 2
         operands = [k_pages, v_pages]
@@ -831,12 +839,13 @@ def _paged_decode(q4, k_pages, v_pages, k_se, v_se, page_table, seq_lens, *,
         blk_tokens = ppb * page_size
 
         def page_map(i):
-            def index(bb, j, pt, sl, ks, vs):
+            def index(bb, j, pt, sl, ks, vs, ly):
                 last = jnp.maximum(sl[bb] - 1, 0) // blk_tokens
-                return (pt[bb, jnp.minimum(j, last) * ppb + i], 0, 0, 0)
+                return (ly[0], pt[bb, jnp.minimum(j, last) * ppb + i], 0, 0,
+                        0)
             return index
 
-        page_specs = [pl.BlockSpec((1, kv, page_size, dh), page_map(i))
+        page_specs = [pl.BlockSpec((None, 1, kv, page_size, dh), page_map(i))
                       for i in range(ppb)] * 2
         operands = [k_pages] * ppb + [v_pages] * ppb
     in_specs = [pl.BlockSpec((1, kv, g, dh), row)] + page_specs
@@ -856,7 +865,7 @@ def _paged_decode(q4, k_pages, v_pages, k_se, v_se, page_table, seq_lens, *,
                    pltpu.VMEM(buf, v_pages.dtype),
                    pltpu.SemaphoreType.DMA((2, 2))]  # (k | v, slot)
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=4, grid=(b, max_pages // ppb), in_specs=in_specs,
+        num_scalar_prefetch=5, grid=(b, max_pages // ppb), in_specs=in_specs,
         out_specs=out_specs,
         scratch_shapes=scratch + [
             pltpu.VMEM((kv, g, dh), jnp.float32),  # o carry
@@ -872,7 +881,26 @@ def _paged_decode(q4, k_pages, v_pages, k_se, v_se, page_table, seq_lens, *,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
-    )(page_table, seq_lens, k_se, v_se, q4, *operands)
+    )(page_table, seq_lens, k_se, v_se, layer, q4, *operands)
+
+
+def _arena_layer(k_pages, v_pages, k_se, v_se, layer):
+    """The kernels' view of the arena: the whole ``(L, P, KV, page_size,
+    dh)`` pages, the layer's ``(P,)`` scale exponents and the layer index as
+    a ``(1,)`` int32 scalar-prefetch operand.  With ``layer=None`` the pages
+    are one layer's ``(P, KV, page_size, dh)`` and the scales ``(P,)``: that
+    is the one-layer arena at layer 0.  Only the layer's small scale row is
+    sliced here; its pages are read in place, at ``[layer, page]``."""
+    if layer is None:
+        if k_pages.shape != v_pages.shape or k_pages.ndim != 4:
+            raise ValueError(f"bad pages {k_pages.shape} / {v_pages.shape}")
+        return (k_pages[None], v_pages[None], jnp.asarray(k_se, jnp.int32),
+                jnp.asarray(v_se, jnp.int32), jnp.zeros((1,), jnp.int32))
+    if k_pages.shape != v_pages.shape or k_pages.ndim != 5:
+        raise ValueError(f"bad arena {k_pages.shape} / {v_pages.shape}")
+    layer = jnp.asarray(layer, jnp.int32)
+    return (k_pages, v_pages, jnp.asarray(k_se, jnp.int32)[layer],
+            jnp.asarray(v_se, jnp.int32)[layer], layer.reshape(1))
 
 
 @register_kernel("paged_attn_decode")
@@ -885,6 +913,7 @@ def paged_attn_decode(
     page_table: jnp.ndarray,
     seq_lens: jnp.ndarray,
     *,
+    layer=None,
     kv_fmt=None,
     acc: tuple[int, int] = _WIDE,
     collect_stats: bool = False,
@@ -894,11 +923,14 @@ def paged_attn_decode(
     """One decode token of attention per sequence against the paged cache.
 
     * ``q`` (B, H, dh) f32 — this step's query rows.
-    * ``k_pages``/``v_pages`` (P, KV, page_size, dh) — the arena: int8
+    * ``k_pages``/``v_pages`` (L, P, KV, page_size, dh) — the whole arena,
+      read at ``layer`` (a traced int32 scalar) in place; or, with
+      ``layer=None``, one layer's (P, KV, page_size, dh).  Int8
       ``(1, e, m)`` codes (``kv_fmt`` required; unpacked in VMEM) or f32
       carriers (parity/oracle mode, ``kv_fmt`` ignored for decoding).
-    * ``k_se``/``v_se`` (P,) int32 — per-page power-of-two scale exponents
-      (ignored in f32 mode: the carrier already includes the scale).
+    * ``k_se``/``v_se`` (L, P), or (P,) with ``layer=None``, int32 —
+      per-page power-of-two scale exponents (ignored in f32 mode: the
+      carrier already includes the scale).
     * ``page_table`` (B, max_pages) int32 — page ids per sequence, padded
       with 0 (page 0 is the reserved null page, see ``serve.kvcache``).
     * ``seq_lens`` (B,) int32 — valid tokens per sequence (0 = inactive
@@ -918,10 +950,10 @@ def paged_attn_decode(
         raise ValueError("collect_stats and return_carry are exclusive")
     if q.ndim != 3:
         raise ValueError(f"q must be (B, H, dh), got {q.shape}")
-    if k_pages.shape != v_pages.shape or k_pages.ndim != 4:
-        raise ValueError(f"bad pages {k_pages.shape} / {v_pages.shape}")
+    k_pages, v_pages, k_se, v_se, layer = _arena_layer(
+        k_pages, v_pages, k_se, v_se, layer)
     b, h, dh = q.shape
-    kv = k_pages.shape[1]
+    kv = k_pages.shape[2]
     if h % kv != 0:
         raise ValueError(f"H={h} not a multiple of KV={kv}")
     packed = k_pages.dtype == jnp.int8
@@ -934,10 +966,9 @@ def paged_attn_decode(
     q4 = q.astype(jnp.float32).reshape(b, kv, h // kv, dh)
     e_acc, m_acc = acc
     out = _paged_decode(
-        q4, k_pages, v_pages,
-        jnp.asarray(k_se, jnp.int32), jnp.asarray(v_se, jnp.int32),
+        q4, k_pages, v_pages, k_se, v_se,
         jnp.asarray(page_table, jnp.int32), jnp.asarray(seq_lens, jnp.int32),
-        packed=packed, e_kv=int(e_kv), m_kv=int(m_kv),
+        layer, packed=packed, e_kv=int(e_kv), m_kv=int(m_kv),
         e_acc=int(e_acc), m_acc=int(m_acc),
         collect_stats=collect_stats, return_carry=return_carry,
         interpret=resolve_interpret(interpret))
@@ -952,12 +983,16 @@ def paged_attn_decode(
 
 
 def paged_attn_decode_reference(q, k_pages, v_pages, k_se, v_se, page_table,
-                                seq_lens, *, kv_fmt=None, acc=_WIDE,
-                                return_carry=False):
+                                seq_lens, *, layer=None, kv_fmt=None,
+                                acc=_WIDE, return_carry=False):
     """Unfused jnp oracle for ``paged_attn_decode``: gathers pages through
     the page table with plain indexing, dequantizes with the per-page
     scales, and walks the pages in the same order with the same carry
-    rounding.  Bit-exact against the kernel."""
+    rounding.  Bit-exact against the kernel.  ``layer`` as in the kernel:
+    the oracle takes that layer of the whole arena with plain indexing."""
+    if layer is not None:
+        k_pages, v_pages = k_pages[layer], v_pages[layer]
+        k_se, v_se = k_se[layer], v_se[layer]
     b, h, dh = q.shape
     kv = k_pages.shape[1]
     g = h // kv
@@ -1052,14 +1087,16 @@ def finalize_carry(o, l):
 # --------------------------------------------------------------------------
 
 
-def _prefill_paged_kernel(pr_ref, gm_ref, kse_ref, vse_ref, *refs,
+def _prefill_paged_kernel(pr_ref, gm_ref, kse_ref, vse_ref, ly_ref, *refs,
                           block_q: int, page_size: int, packed: bool,
                           e_kv: int, m_kv: int, e_acc: int, m_acc: int,
                           scale: float, has_carry: bool, emit_carry: bool):
     """Grid (H, q_blocks, max_pages).  The page row and the slab geometry
     (``gm_ref`` = [q_offset, q_len, kv_len, start_page], SMEM) are traced
     scalar-prefetch operands, so every slab of every prompt in the bucket
-    reuses this one compiled body; pages past the live count, before the
+    reuses this one compiled body; the page blocks come out of the whole
+    arena at ``[layer, page]`` (``ly_ref``, read by the index maps); pages
+    past the live count, before the
     carry's resume point, or wholly in the causal future are provable
     carry no-ops and are predicated away."""
     n_in = 6 if has_carry else 3
@@ -1123,30 +1160,31 @@ def _prefill_paged_kernel(pr_ref, gm_ref, kse_ref, vse_ref, *refs,
                      "emit_carry", "interpret"),
 )
 def _flash_prefill_paged(q, k_pages, v_pages, k_se, v_se, page_row, geom,
-                         carry_o, carry_m, carry_l, *, packed, e_kv, m_kv,
-                         e_acc, m_acc, block_q, emit_carry, interpret):
+                         layer, carry_o, carry_m, carry_l, *, packed, e_kv,
+                         m_kv, e_acc, m_acc, block_q, emit_carry, interpret):
     _count_trace("flash_prefill_paged")
     t, h, dh = q.shape
-    kv = k_pages.shape[1]
+    kv = k_pages.shape[2]
     g = h // kv
-    page_size = k_pages.shape[2]
+    page_size = k_pages.shape[3]
     max_pages = page_row.shape[0]
     has_carry = carry_o is not None
     sq = -(-t // block_q) * block_q
     qt = jnp.pad(q.astype(jnp.float32).transpose(1, 0, 2),
                  ((0, 0), (0, sq - t), (0, 0)))
     grid = (h, sq // block_q, max_pages)
-    # GQA rides the index map: query head hh reads KV head hh // g straight
-    # from the arena — no repeated HBM copy (the dense kernel's jnp.repeat)
+    # GQA rides the index map: query head hh reads KV head hh // g of the
+    # layer straight from the arena — no repeated HBM copy (the dense
+    # kernel's jnp.repeat)
     in_specs = [
         pl.BlockSpec((1, block_q, dh),
-                     lambda hh, qi, p, pr, gm, ks, vs: (hh, qi, 0)),
-        pl.BlockSpec((1, 1, page_size, dh),
-                     lambda hh, qi, p, pr, gm, ks, vs, g=g:
-                     (pr[p], hh // g, 0, 0)),
-        pl.BlockSpec((1, 1, page_size, dh),
-                     lambda hh, qi, p, pr, gm, ks, vs, g=g:
-                     (pr[p], hh // g, 0, 0)),
+                     lambda hh, qi, p, pr, gm, ks, vs, ly: (hh, qi, 0)),
+        pl.BlockSpec((None, 1, 1, page_size, dh),
+                     lambda hh, qi, p, pr, gm, ks, vs, ly, g=g:
+                     (ly[0], pr[p], hh // g, 0, 0)),
+        pl.BlockSpec((None, 1, 1, page_size, dh),
+                     lambda hh, qi, p, pr, gm, ks, vs, ly, g=g:
+                     (ly[0], pr[p], hh // g, 0, 0)),
     ]
     operands = [qt, k_pages, v_pages]
     if has_carry:
@@ -1159,25 +1197,25 @@ def _flash_prefill_paged(q, k_pages, v_pages, k_se, v_se, page_row, geom,
         operands += [co, cm, cl]
         in_specs += [
             pl.BlockSpec((1, block_q, dh),
-                         lambda hh, qi, p, pr, gm, ks, vs: (hh, qi, 0)),
+                         lambda hh, qi, p, pr, gm, ks, vs, ly: (hh, qi, 0)),
             pl.BlockSpec((1, block_q, 1),
-                         lambda hh, qi, p, pr, gm, ks, vs: (hh, qi, 0)),
+                         lambda hh, qi, p, pr, gm, ks, vs, ly: (hh, qi, 0)),
             pl.BlockSpec((1, block_q, 1),
-                         lambda hh, qi, p, pr, gm, ks, vs: (hh, qi, 0)),
+                         lambda hh, qi, p, pr, gm, ks, vs, ly: (hh, qi, 0)),
         ]
     o_spec = pl.BlockSpec((1, block_q, dh),
-                          lambda hh, qi, p, pr, gm, ks, vs: (hh, qi, 0))
+                          lambda hh, qi, p, pr, gm, ks, vs, ly: (hh, qi, 0))
     o_shape = jax.ShapeDtypeStruct((h, sq, dh), jnp.float32)
     if emit_carry:
-        s_spec = pl.BlockSpec((1, block_q, 1),
-                              lambda hh, qi, p, pr, gm, ks, vs: (hh, qi, 0))
+        s_spec = pl.BlockSpec(
+            (1, block_q, 1), lambda hh, qi, p, pr, gm, ks, vs, ly: (hh, qi, 0))
         s_shape = jax.ShapeDtypeStruct((h, sq, 1), jnp.float32)
         out_specs: list | pl.BlockSpec = [o_spec, s_spec, s_spec]
         out_shape: list | jax.ShapeDtypeStruct = [o_shape, s_shape, s_shape]
     else:
         out_specs, out_shape = o_spec, o_shape
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=4, grid=grid, in_specs=in_specs,
+        num_scalar_prefetch=5, grid=grid, in_specs=in_specs,
         out_specs=out_specs,
         scratch_shapes=[
             pltpu.VMEM((block_q, dh), jnp.float32),  # o carry
@@ -1193,7 +1231,7 @@ def _flash_prefill_paged(q, k_pages, v_pages, k_se, v_se, page_row, geom,
         grid_spec=grid_spec,
         out_shape=out_shape,
         interpret=interpret,
-    )(page_row, geom, k_se, v_se, *operands)
+    )(page_row, geom, k_se, v_se, layer, *operands)
     if emit_carry:
         o, m, l = out
         return (o.transpose(1, 0, 2)[:t], m[..., 0].T[:t], l[..., 0].T[:t])
@@ -1219,6 +1257,7 @@ def flash_prefill_paged(
     start_page=0,
     return_carry: bool = False,
     call: AttnCall | None = None,
+    layer=None,
     interpret: bool | None = None,
 ) -> jnp.ndarray | tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
     """Bucketed causal prefill straight off the paged KV arena.
@@ -1230,11 +1269,13 @@ def flash_prefill_paged(
 
     * ``q`` (T, H, dh) — the query slab, padded to the bucket's slab width;
       rows ``>= q_len`` are padding (their output is exactly 0).
-    * ``k_pages``/``v_pages`` (P, KV, page_size, dh) + ``k_se``/``v_se``
-      (P,) int32 — one layer's arena AFTER the slab's
-      ``kvcache.write_prompt``: history and fresh slab are walked in one
-      pass, int8 pages unpacked in VMEM exactly like ``paged_attn_decode``
-      (f32 carriers pass through; ``kv_fmt`` ignored then).
+    * ``k_pages``/``v_pages`` (L, P, KV, page_size, dh) + ``k_se``/``v_se``
+      (L, P) int32 — the whole arena AFTER the slab's
+      ``kvcache.write_prompt``, read at ``layer`` (a traced int32 scalar)
+      in place; or, with ``layer=None``, one layer's (P, KV, page_size,
+      dh) + (P,).  History and fresh slab are walked in one pass, int8
+      pages unpacked in VMEM exactly like ``paged_attn_decode`` (f32
+      carriers pass through; ``kv_fmt`` ignored then).
     * ``page_row`` (max_pages,) int32 — this sequence's pages in token
       order, padded with 0 (the reserved null page); pages at positions
       ``>= ceil(kv_len / page_size)`` are never read.
@@ -1265,10 +1306,10 @@ def flash_prefill_paged(
                 f"{call.max_pages}")
     if q.ndim != 3:
         raise ValueError(f"q must be (T, H, dh), got {q.shape}")
-    if k_pages.shape != v_pages.shape or k_pages.ndim != 4:
-        raise ValueError(f"bad pages {k_pages.shape} / {v_pages.shape}")
+    k_pages, v_pages, k_se, v_se, layer = _arena_layer(
+        k_pages, v_pages, k_se, v_se, layer)
     t, h, dh = q.shape
-    kv = k_pages.shape[1]
+    kv = k_pages.shape[2]
     if h % kv != 0:
         raise ValueError(f"H={h} not a multiple of KV={kv}")
     packed = k_pages.dtype == jnp.int8
@@ -1290,9 +1331,9 @@ def flash_prefill_paged(
                       jnp.asarray(kv_len, jnp.int32),
                       jnp.asarray(start_page, jnp.int32)])
     return _flash_prefill_paged(
-        q, k_pages, v_pages,
-        jnp.asarray(k_se, jnp.int32), jnp.asarray(v_se, jnp.int32),
-        jnp.asarray(page_row, jnp.int32), geom, carry_o, carry_m, carry_l,
+        q, k_pages, v_pages, k_se, v_se,
+        jnp.asarray(page_row, jnp.int32), geom, layer, carry_o, carry_m,
+        carry_l,
         packed=packed, e_kv=int(e_kv), m_kv=int(m_kv),
         e_acc=int(e_acc), m_acc=int(m_acc), block_q=int(block_q),
         emit_carry=bool(return_carry),
@@ -1303,13 +1344,16 @@ def flash_prefill_paged_reference(q, k_pages, v_pages, k_se, v_se, page_row,
                                   q_offset, q_len, kv_len, *, kv_fmt=None,
                                   acc=_WIDE, carry=None, start_page=0,
                                   return_carry=False,
-                                  call: AttnCall | None = None):
+                                  call: AttnCall | None = None, layer=None):
     """Unfused jnp oracle for ``flash_prefill_paged``: gathers each page
     through the page row, dequantizes with the per-page scales, and walks
     ALL ``max_pages`` positions in order — pages the kernel predicates away
     are run fully masked here, which is a provable carry no-op (alpha = 1,
     addends exactly 0, the running max pinned at NEG), so oracle == kernel
-    bit-for-bit."""
+    bit-for-bit.  ``layer`` as in the kernel."""
+    if layer is not None:
+        k_pages, v_pages = k_pages[layer], v_pages[layer]
+        k_se, v_se = k_se[layer], v_se[layer]
     if call is not None:
         acc = call.acc
         kv_fmt = call.kv_fmt

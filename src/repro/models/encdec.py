@@ -194,20 +194,24 @@ def paged_decode(params, tokens, kv_state, xk, xv, page_table,
     x = L._constrain(x, dist, P(dist.data_axes, None, None))
 
     def body(carry, inp):
-        lp, kvl, xkl, xvl = inp
-        h, nkv = L.attn_decode_paged(
-            lp["attn"], L.rms_norm(carry, lp["ln1"], cfg.norm_eps), kvl,
+        (x, kv), (lp, xkl, xvl, layer) = carry, inp
+        h, kv = L.attn_decode_paged(
+            lp["attn"], L.rms_norm(x, lp["ln1"], cfg.norm_eps), kv,
             page_table, positions, seq_lens, cfg, dist,
-            kv_fmt=kv_fmt, acc=acc, oracle=oracle)
-        carry = carry + h
-        z = L.rms_norm(carry, lp["ln_x"], cfg.norm_eps)
+            layer=layer, kv_fmt=kv_fmt, acc=acc, oracle=oracle)
+        x = x + h
+        z = L.rms_norm(x, lp["ln_x"], cfg.norm_eps)
         q = L._q_proj(lp["xattn"], z, cfg, positions[:, None])
         o = L._gqa_attend(q, xkl, xvl, None, cfg, dist)
-        carry = carry + L.dense(o, lp["xattn"]["wo"], cfg.quant.attn_out)
-        z = L.rms_norm(carry, lp["ln2"], cfg.norm_eps)
-        return carry + L.mlp_apply(lp["mlp"], z, cfg), nkv
+        x = x + L.dense(o, lp["xattn"]["wo"], cfg.quant.attn_out)
+        z = L.rms_norm(x, lp["ln2"], cfg.norm_eps)
+        return (x + L.mlp_apply(lp["mlp"], z, cfg), kv), None
 
-    x, new_kv = scan_util.scan(body, x, (params["decoder"], kv_state, xk, xv))
+    # the arena rides the carry whole, written and read at each layer's
+    # index in place (as ``lm._paged_stack``)
+    layers = jnp.arange(kv_state["k"].shape[0], dtype=jnp.int32)
+    (x, new_kv), _ = scan_util.scan(body, (x, kv_state),
+                                    (params["decoder"], xk, xv, layers))
     logits = _unembed(params, x, cfg, dist)
     return logits, new_kv
 

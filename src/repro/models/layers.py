@@ -401,14 +401,17 @@ def attn_decode_paged(
     cfg: ModelConfig,
     dist: Dist,
     *,
+    layer,
     kv_fmt,
     acc: tuple[int, int],
     oracle: bool = False,
 ) -> tuple[jnp.ndarray, dict[str, jnp.ndarray]]:
-    """One-token decode against a layer's paged-KV arena slice.
+    """One-token decode of layer ``layer`` against the paged-KV arena.
 
-    ``x`` (B, 1, D); ``kv`` = {"k", "v", "k_se", "v_se"} per-layer slices
-    (``repro.serve.kvcache`` layout); ``positions`` (B,) — each sequence's
+    ``x`` (B, 1, D); ``kv`` = {"k", "v", "k_se", "v_se"}, the whole arena
+    (``repro.serve.kvcache`` layout), which the token's K/V are scattered
+    into at ``[layer, page]`` and the kernel reads at ``layer`` in place;
+    ``layer`` a traced int32 scalar; ``positions`` (B,) — each sequence's
     own write position (continuous batching: they differ); ``seq_lens``
     (B,) — attended tokens incl. this one, 0 for padded rows (their writes
     land in the reserved null page).  ``acc`` is the planner's carry
@@ -425,29 +428,29 @@ def attn_decode_paged(
     pos2 = positions[:, None]
     q = _q_proj(p, x, cfg, pos2)  # (B, 1, H, dh)
     k1, v1 = _kv_proj(p, x, cfg, pos2)
-    page_size = kv["k"].shape[2]
+    page_size = kv["k"].shape[-2]
     page_id = jnp.take_along_axis(
         page_table, (positions // page_size)[:, None], axis=1)[:, 0]
     slot = positions % page_size
     ax = dist.shard_axis
     kk, kse = KV.append_token(kv["k"], kv["k_se"],
                               k1[:, 0].astype(jnp.float32), page_id, slot,
-                              kv_fmt, pmax_axis=ax)
+                              kv_fmt, pmax_axis=ax, layer=layer)
     vv, vse = KV.append_token(kv["v"], kv["v_se"],
                               v1[:, 0].astype(jnp.float32), page_id, slot,
-                              kv_fmt, pmax_axis=ax)
+                              kv_fmt, pmax_axis=ax, layer=layer)
     attend = paged_attn_decode_reference if oracle else paged_attn_decode
     if ax is None:
         o = attend(q[:, 0].astype(jnp.float32), kk, vv, kse, vse, page_table,
-                   seq_lens, kv_fmt=kv_fmt, acc=acc)
+                   seq_lens, layer=layer, kv_fmt=kv_fmt, acc=acc)
     else:
         # head-sharded: each local head walks its FULL-context online
         # softmax exactly as the single-device kernel (same pages, same
         # order, same carry rounding), then the cross-shard gather is a
         # psum'd carry merge with neutral non-owner elements (exact)
         o_l, m_l, l_l = attend(q[:, 0].astype(jnp.float32), kk, vv, kse, vse,
-                               page_table, seq_lens, kv_fmt=kv_fmt, acc=acc,
-                               return_carry=True)
+                               page_table, seq_lens, layer=layer,
+                               kv_fmt=kv_fmt, acc=acc, return_carry=True)
         o = _merge_sharded_carry(o_l, m_l, l_l, dist)
     o = o.reshape(b, 1, -1).astype(COMPUTE_DTYPE)
     new_kv = {"k": kk, "v": vv, "k_se": kse, "v_se": vse}
@@ -465,13 +468,16 @@ def attn_verify_paged(
     cfg: ModelConfig,
     dist: Dist,
     *,
+    layer,
     kv_fmt,
     acc: tuple[int, int],
     oracle: bool = False,
 ) -> tuple[jnp.ndarray, dict[str, jnp.ndarray]]:
     """Speculative-decode verify: score ``S = k + 1`` tokens per sequence
-    against a layer's paged arena in ONE batched kernel call, bitwise
-    identical to ``S`` sequential ``attn_decode_paged`` steps.
+    of layer ``layer`` against the whole paged arena (written and read in
+    place at ``layer``, as ``attn_decode_paged``) in ONE batched kernel
+    call, bitwise identical to ``S`` sequential ``attn_decode_paged``
+    steps.
 
     ``x`` (B, S, D) — the last committed token followed by the k draft
     proposals; ``positions`` (B,) the FIRST write position per row;
@@ -497,7 +503,7 @@ def attn_verify_paged(
     pos2 = positions[:, None] + jnp.arange(s, dtype=jnp.int32)[None, :]
     q = _q_proj(p, x, cfg, pos2)  # (B, S, H, dh)
     k1, v1 = _kv_proj(p, x, cfg, pos2)
-    page_size = kv["k"].shape[2]
+    page_size = kv["k"].shape[-2]
     ax = dist.shard_axis
     kk, kse, vv, vse = kv["k"], kv["k_se"], kv["v"], kv["v_se"]
     for j in range(s):
@@ -506,9 +512,11 @@ def attn_verify_paged(
             page_table, (pos_j // page_size)[:, None], axis=1)[:, 0]
         slot = pos_j % page_size
         kk, kse = KV.append_token(kk, kse, k1[:, j].astype(jnp.float32),
-                                  page_id, slot, kv_fmt, pmax_axis=ax)
+                                  page_id, slot, kv_fmt, pmax_axis=ax,
+                                  layer=layer)
         vv, vse = KV.append_token(vv, vse, v1[:, j].astype(jnp.float32),
-                                  page_id, slot, kv_fmt, pmax_axis=ax)
+                                  page_id, slot, kv_fmt, pmax_axis=ax,
+                                  layer=layer)
     # flatten: row (i, j) attends sequence i's pages at length seq_lens+j
     # (padded rows stay 0 → the kernel emits exact zeros, nothing read)
     q_flat = q.reshape(b * s, *q.shape[2:]).astype(jnp.float32)
@@ -519,11 +527,12 @@ def attn_verify_paged(
         0).reshape(b * s)
     attend = paged_attn_decode_reference if oracle else paged_attn_decode
     if ax is None:
-        o = attend(q_flat, kk, vv, kse, vse, pt_flat, sl_flat,
+        o = attend(q_flat, kk, vv, kse, vse, pt_flat, sl_flat, layer=layer,
                    kv_fmt=kv_fmt, acc=acc)
     else:
         o_l, m_l, l_l = attend(q_flat, kk, vv, kse, vse, pt_flat, sl_flat,
-                               kv_fmt=kv_fmt, acc=acc, return_carry=True)
+                               layer=layer, kv_fmt=kv_fmt, acc=acc,
+                               return_carry=True)
         o = _merge_sharded_carry(o_l, m_l, l_l, dist)
     o = o.reshape(b, s, -1).astype(COMPUTE_DTYPE)
     new_kv = {"k": kk, "v": vv, "k_se": kse, "v_se": vse}
@@ -646,14 +655,17 @@ def attn_prefill_bucketed(
     cfg: ModelConfig,
     dist: Dist,
     *,
+    layer,
     kv_fmt,
     acc: tuple[int, int],
     block_q: int | None = None,
     call=None,
 ) -> tuple[jnp.ndarray, dict[str, jnp.ndarray]]:
-    """One bucket-shaped prefill slab of ONE sequence through a layer —
-    the single-compile replacement for the history-gather + two-call
-    ``attn_prefill_chunk_paged`` walk.
+    """One bucket-shaped prefill slab of ONE sequence through layer
+    ``layer`` — the single-compile replacement for the history-gather +
+    two-call ``attn_prefill_chunk_paged`` walk.  ``kv`` is the whole arena:
+    the slab's pages are written at ``[layer, page]`` and the kernel reads
+    the layer in place.
 
     ``x`` (1, T, D) is the slab padded to the bucket's fixed slab width;
     ``q_offset``/``q_len`` are TRACED int32 scalars (absolute start, live
@@ -678,7 +690,7 @@ def attn_prefill_bucketed(
     from repro.serve import kvcache as KV
 
     t = x.shape[1]
-    page_size = kv["k"].shape[2]
+    page_size = kv["k"].shape[-2]
     q_offset = jnp.asarray(q_offset, jnp.int32)
     q_len = jnp.asarray(q_len, jnp.int32)
     positions = (q_offset + jnp.arange(t, dtype=jnp.int32))[None]
@@ -689,9 +701,9 @@ def attn_prefill_bucketed(
     vf = jnp.where(live, v[0].astype(jnp.float32), 0.0)
     ax = dist.shard_axis
     kk, kse, _ = KV.write_prompt(kv["k"], kv["k_se"], kf, slab_page_ids,
-                                 kv_fmt, pmax_axis=ax)
+                                 kv_fmt, pmax_axis=ax, layer=layer)
     vv, vse, _ = KV.write_prompt(kv["v"], kv["v_se"], vf, slab_page_ids,
-                                 kv_fmt, pmax_axis=ax)
+                                 kv_fmt, pmax_axis=ax, layer=layer)
     h_here = q.shape[2]  # local heads under tensor-parallel shard_map
     if call is None and block_q is None:
         block_q = attn_blocks_for(t, h_here, cfg.head_dim, page_size,
@@ -699,12 +711,12 @@ def attn_prefill_bucketed(
                                   max_pages=int(page_row.shape[0]))
     if call is not None and ax is not None:
         import dataclasses as _dc
-        call = _dc.replace(call, h=h_here, kv_heads=kk.shape[1])
+        call = _dc.replace(call, h=h_here, kv_heads=kk.shape[-3])
     if ax is None:
         o = flash_prefill_paged(q[0].astype(jnp.float32), kk, vv, kse, vse,
                                 page_row, q_offset, q_len, q_offset + q_len,
                                 kv_fmt=kv_fmt, acc=acc, block_q=block_q or 128,
-                                call=call)
+                                call=call, layer=layer)
     else:
         # same discipline as attn_decode_paged: full-context local-head
         # walk, neutral-element psum'd carry merge (exact)
@@ -712,7 +724,7 @@ def attn_prefill_bucketed(
             q[0].astype(jnp.float32), kk, vv, kse, vse,
             page_row, q_offset, q_len, q_offset + q_len,
             kv_fmt=kv_fmt, acc=acc, block_q=block_q or 128,
-            call=call, return_carry=True)
+            call=call, return_carry=True, layer=layer)
         o = _merge_sharded_carry(o_l, m_l, l_l, dist)
     o = o.reshape(1, t, -1).astype(COMPUTE_DTYPE)
     new_kv = {"k": kk, "v": vv, "k_se": kse, "v_se": vse}

@@ -452,6 +452,34 @@ def init_paged_state(cfg: ModelConfig, *, n_pages: int, page_size: int,
                             kv_fmt=kv_fmt)
 
 
+def _paged_stack(params: Params, x: jnp.ndarray, kv_state: dict,
+                 cfg: ModelConfig, dist: L.Dist, attend):
+    """The layer stack of the paged entries.  The arena rides the layer
+    scan's carry whole, and the scan hands each layer its index:
+    ``attend(p_attn, h, kv, layer)`` writes the layer's new K/V into the
+    arena at ``[layer, page]`` and its kernel reads that layer in place, so
+    no layer's slab is sliced out of the arena or stacked back into a new
+    one.  Under a jit that donates the arena, a call updates it in place.
+    Returns the final hidden states and the arena."""
+
+    def body(carry, inp):
+        (x, kv), (lp, layer) = carry, inp
+        h, kv = attend(lp["attn"], L.rms_norm(x, lp["ln1"], cfg.norm_eps),
+                       kv, layer)
+        x = x + h
+        z = L.rms_norm(x, lp["ln2"], cfg.norm_eps)
+        if cfg.moe is not None and "moe" in lp:
+            f, _ = L.moe_apply(lp["moe"], z, cfg, dist)
+        else:
+            f = L.mlp_apply(lp["mlp"], z, cfg, dist)
+        return (x + f, kv), None
+
+    layers = jnp.arange(kv_state["k"].shape[0], dtype=jnp.int32)
+    (x, kv_state), _ = scan_util.scan(body, (x, kv_state),
+                                      (params["layers"], layers))
+    return x, kv_state
+
+
 def paged_decode(
     params: Params,
     tokens: jnp.ndarray,   # (B, 1) int32
@@ -477,21 +505,12 @@ def paged_decode(
     x = params["embed"][tokens].astype(L.COMPUTE_DTYPE)
     x = L._constrain(x, dist, P(dist.data_axes, None, None))
 
-    def body(carry, inp):
-        lp, kvl = inp
-        h, nkv = L.attn_decode_paged(
-            lp["attn"], L.rms_norm(carry, lp["ln1"], cfg.norm_eps), kvl,
-            page_table, positions, seq_lens, cfg, dist,
-            kv_fmt=kv_fmt, acc=acc, oracle=oracle)
-        carry = carry + h
-        z = L.rms_norm(carry, lp["ln2"], cfg.norm_eps)
-        if cfg.moe is not None and "moe" in lp:
-            f, _ = L.moe_apply(lp["moe"], z, cfg, dist)
-        else:
-            f = L.mlp_apply(lp["mlp"], z, cfg, dist)
-        return carry + f, nkv
+    def attend(p, h, kv, layer):
+        return L.attn_decode_paged(
+            p, h, kv, page_table, positions, seq_lens, cfg, dist,
+            layer=layer, kv_fmt=kv_fmt, acc=acc, oracle=oracle)
 
-    x, new_kv = scan_util.scan(body, x, (params["layers"], kv_state))
+    x, new_kv = _paged_stack(params, x, kv_state, cfg, dist, attend)
     logits = _unembed(params, x, cfg, dist)
     return logits, new_kv
 
@@ -524,21 +543,12 @@ def paged_verify(
     x = params["embed"][tokens].astype(L.COMPUTE_DTYPE)
     x = L._constrain(x, dist, P(dist.data_axes, None, None))
 
-    def body(carry, inp):
-        lp, kvl = inp
-        h, nkv = L.attn_verify_paged(
-            lp["attn"], L.rms_norm(carry, lp["ln1"], cfg.norm_eps), kvl,
-            page_table, positions, seq_lens, cfg, dist,
-            kv_fmt=kv_fmt, acc=acc, oracle=oracle)
-        carry = carry + h
-        z = L.rms_norm(carry, lp["ln2"], cfg.norm_eps)
-        if cfg.moe is not None and "moe" in lp:
-            f, _ = L.moe_apply(lp["moe"], z, cfg, dist)
-        else:
-            f = L.mlp_apply(lp["mlp"], z, cfg, dist)
-        return carry + f, nkv
+    def attend(p, h, kv, layer):
+        return L.attn_verify_paged(
+            p, h, kv, page_table, positions, seq_lens, cfg, dist,
+            layer=layer, kv_fmt=kv_fmt, acc=acc, oracle=oracle)
 
-    x, new_kv = scan_util.scan(body, x, (params["layers"], kv_state))
+    x, new_kv = _paged_stack(params, x, kv_state, cfg, dist, attend)
     logits = _unembed(params, x, cfg, dist)
     return logits, new_kv
 
@@ -585,21 +595,12 @@ def paged_prefill(
     x = params["embed"][tokens].astype(L.COMPUTE_DTYPE)
     x = L._constrain(x, dist, P(dist.data_axes, None, None))
 
-    def body(carry, inp):
-        lp, kvl = inp
-        h, nkv = L.attn_prefill_bucketed(
-            lp["attn"], L.rms_norm(carry, lp["ln1"], cfg.norm_eps), kvl,
-            page_row, slab_page_ids, q_offset, q_len, cfg, dist,
-            kv_fmt=kv_fmt, acc=acc, block_q=block_q, call=call)
-        carry = carry + h
-        z = L.rms_norm(carry, lp["ln2"], cfg.norm_eps)
-        if cfg.moe is not None and "moe" in lp:
-            f, _ = L.moe_apply(lp["moe"], z, cfg, dist)
-        else:
-            f = L.mlp_apply(lp["mlp"], z, cfg, dist)
-        return carry + f, nkv
+    def attend(p, h, kv, layer):
+        return L.attn_prefill_bucketed(
+            p, h, kv, page_row, slab_page_ids, q_offset, q_len, cfg, dist,
+            layer=layer, kv_fmt=kv_fmt, acc=acc, block_q=block_q, call=call)
 
-    x, new_kv = scan_util.scan(body, x, (params["layers"], kv_state))
+    x, new_kv = _paged_stack(params, x, kv_state, cfg, dist, attend)
     if not want_logits:
         return None, new_kv
     last = jax.lax.dynamic_slice_in_dim(

@@ -19,8 +19,9 @@ different lengths.  This module fixes both:
   sequences grow and reclaims them on completion, so the HBM bill tracks
   the tokens actually cached, not ``batch * max_t``.
 
-Layout (one arena per model; the layer axis leads so the per-layer scan
-in ``models.lm.paged_decode`` can carry arena slices as scan xs)::
+Layout (one arena per model; the layer scan of ``models.lm``'s paged
+entries carries it whole and writes layer ``l``'s tokens at ``[l, page]``
+in place, so no layer's slab is ever sliced out or stacked back)::
 
     k / v   : (L, P, KV, page_size, dh)  int8 codes
     k_se/v_se: (L, P)                     int32 scale exponents
@@ -102,12 +103,15 @@ class PagedKVConfig:
 
 
 def init_arena(pc: PagedKVConfig) -> dict[str, jnp.ndarray]:
-    """Zero-initialized arena pytree (int8 code 0 decodes to +0.0)."""
+    """Zero-initialized arena pytree (int8 code 0 decodes to +0.0).  Each
+    leaf is a buffer of its own: the executor donates the arena leaf by
+    leaf, and one buffer cannot be donated twice."""
     shape = (pc.n_layers, pc.n_pages, pc.n_kv_heads, pc.page_size,
              pc.head_dim)
-    z = jnp.zeros(shape, jnp.int8)
-    se = jnp.zeros((pc.n_layers, pc.n_pages), jnp.int32)
-    return {"k": z, "v": z, "k_se": se, "v_se": se}
+    se = (pc.n_layers, pc.n_pages)
+    return {"k": jnp.zeros(shape, jnp.int8), "v": jnp.zeros(shape, jnp.int8),
+            "k_se": jnp.zeros(se, jnp.int32),
+            "v_se": jnp.zeros(se, jnp.int32)}
 
 
 def _scale_exp(amax: jnp.ndarray) -> jnp.ndarray:
@@ -128,14 +132,18 @@ def _decode(codes: jnp.ndarray, se: jnp.ndarray, fmt: FPFormat) -> jnp.ndarray:
     return unpack_block(codes, fmt.e, fmt.m) * jnp.exp2(se.astype(jnp.float32))
 
 
-def append_token(arena_l: jnp.ndarray, se_l: jnp.ndarray, x: jnp.ndarray,
+def append_token(arena: jnp.ndarray, se: jnp.ndarray, x: jnp.ndarray,
                  page_id: jnp.ndarray, slot: jnp.ndarray,
                  fmt: FPFormat,
-                 pmax_axis: str | None = None) -> tuple[jnp.ndarray,
-                                                        jnp.ndarray]:
-    """Write one decode token per sequence into a layer's arena slice.
+                 pmax_axis: str | None = None, *,
+                 layer=None) -> tuple[jnp.ndarray, jnp.ndarray]:
+    """Write one decode token per sequence of layer ``layer`` into the
+    arena, in place: one scatter of the codes at ``[layer, page_id, :,
+    slot]`` into the whole ``arena`` (L, P, KV, page_size, dh) int8 and one
+    of the scale exponents at ``[layer, page_id]`` into ``se`` (L, P)
+    int32; ``layer`` is a traced int32 scalar.  With ``layer=None`` the
+    arena is one layer's (P, KV, page_size, dh) and ``se`` (P,).
 
-    ``arena_l`` (P, KV, page_size, dh) int8, ``se_l`` (P,) int32,
     ``x`` (B, KV, dh) f32 values, ``page_id``/``slot`` (B,) int32.  A write
     at ``slot == 0`` is the page's first and fixes its scale exponent.
     Padded batch rows must carry ``page_id == 0`` (the null page).
@@ -146,26 +154,35 @@ def append_token(arena_l: jnp.ndarray, se_l: jnp.ndarray, x: jnp.ndarray,
     (global, all-heads) exponent the single-device write would, so the
     shard-local codes are a bitwise slice of the unsharded arena.
     """
+    at = () if layer is None else (layer,)
     amax = jnp.max(jnp.abs(x), axis=(1, 2))  # (B,)
     if pmax_axis is not None:
         amax = jax.lax.pmax(amax, pmax_axis)
-    se = jnp.where(slot == 0, _scale_exp(amax), se_l[page_id])
-    se_l = se_l.at[page_id].set(se)
-    codes = _encode(x, se[:, None, None], fmt)  # (B, KV, dh)
-    arena_l = arena_l.at[page_id, :, slot].set(codes)
-    return arena_l, se_l
+    tok_se = jnp.where(slot == 0, _scale_exp(amax), se[at + (page_id,)])
+    se = se.at[at + (page_id,)].set(tok_se)
+    codes = _encode(x, tok_se[:, None, None], fmt)  # (B, KV, dh)
+    # one dh-wide row per (row, head): a scatter whose window is the minor
+    # dimension alone writes the arena in its own layout, where a (KV, dh)
+    # window would have XLA relayout (copy) the whole arena around it
+    heads = jnp.arange(x.shape[1], dtype=jnp.int32)[None, :]
+    arena = arena.at[at + (page_id[:, None], heads, slot[:, None])].set(codes)
+    return arena, se
 
 
-def write_prompt(arena_l: jnp.ndarray, se_l: jnp.ndarray, x: jnp.ndarray,
+def write_prompt(arena: jnp.ndarray, se: jnp.ndarray, x: jnp.ndarray,
                  page_ids: jnp.ndarray, fmt: FPFormat,
-                 pmax_axis: str | None = None,
+                 pmax_axis: str | None = None, *, layer=None,
                  ) -> tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
-    """Write one sequence's prompt K (or V) into a layer's arena slice.
+    """Write one sequence's prompt K (or V) of layer ``layer`` into the
+    arena, in place: one scatter of whole pages at ``[layer, page_ids]``
+    into ``arena`` (L, P, KV, page_size, dh) and one of their scale
+    exponents into ``se`` (L, P), as ``append_token``; with ``layer=None``
+    the arena is one layer's (P, KV, page_size, dh) and ``se`` (P,).
 
     ``x`` (S, KV, dh) f32; ``page_ids`` (n_pages,) int32 with
     ``n_pages * page_size >= S`` (the tail page is zero-padded; code 0
     decodes to 0.0 and padded tokens are masked out of attention anyway).
-    Returns ``(arena_l, se_l, dequant)`` where ``dequant`` (S, KV, dh) is
+    Returns ``(arena, se, dequant)`` where ``dequant`` (S, KV, dh) is
     the exact values the cache now holds — prefill attends to THESE, so
     later paged decode sees the same history prefill saw.
 
@@ -175,20 +192,21 @@ def write_prompt(arena_l: jnp.ndarray, se_l: jnp.ndarray, x: jnp.ndarray,
     """
     s, kv, dh = x.shape
     npg = page_ids.shape[0]
-    page_size = arena_l.shape[2]
+    page_size = arena.shape[-2]
+    at = () if layer is None else (layer,)
     xp = jnp.pad(x.astype(jnp.float32),
                  ((0, npg * page_size - s), (0, 0), (0, 0)))
     blocks = xp.reshape(npg, page_size, kv, dh).transpose(0, 2, 1, 3)
     amax = jnp.max(jnp.abs(blocks), axis=(1, 2, 3))  # (npg,)
     if pmax_axis is not None:
         amax = jax.lax.pmax(amax, pmax_axis)
-    se = _scale_exp(amax)
-    codes = _encode(blocks, se[:, None, None, None], fmt)
-    arena_l = arena_l.at[page_ids].set(codes)
-    se_l = se_l.at[page_ids].set(se)
-    deq = _decode(codes, se[:, None, None, None], fmt)
+    page_se = _scale_exp(amax)
+    codes = _encode(blocks, page_se[:, None, None, None], fmt)
+    arena = arena.at[at + (page_ids,)].set(codes)
+    se = se.at[at + (page_ids,)].set(page_se)
+    deq = _decode(codes, page_se[:, None, None, None], fmt)
     deq = deq.transpose(0, 2, 1, 3).reshape(npg * page_size, kv, dh)[:s]
-    return arena_l, se_l, deq
+    return arena, se, deq
 
 
 def gather_pages(arena_l: jnp.ndarray, se_l: jnp.ndarray,

@@ -169,12 +169,15 @@ def measure_decode_vrr(kv_state, page_row: np.ndarray,
 
     q = jax.random.normal(key, (1, cfg.n_heads, cfg.head_dim), jnp.float32)
     _, raw = paged_attn_decode(
-        q, kv_state["k"][0], kv_state["v"][0],
-        kv_state["k_se"][0], kv_state["v_se"][0],
+        q, kv_state["k"], kv_state["v"], kv_state["k_se"], kv_state["v_se"],
         jnp.asarray(page_row[None]), jnp.asarray([seq_len], jnp.int32),
-        kv_fmt=kv_fmt, acc=acc, collect_stats=True)
+        layer=0, kv_fmt=kv_fmt, acc=acc, collect_stats=True)
     return EnsembleStats.from_raw(np.asarray(raw))
 
+
+# The arena's position among each program's operands: donated, so the
+# arena a program returns is the buffer it was given, updated in place.
+_ARENA = {"decode": 2, "prefill": 2, "verify": 2, "rollback": 0}
 
 # One compile cache per serve PROCESS, not per engine: tearing an engine
 # down and constructing another with the same configuration (the bench's
@@ -235,6 +238,12 @@ class ModelExecutor:
     jitted program is named ``serve_<kind>`` (``serve_decode``,
     ``serve_prefill``, ``serve_prefill_final``, ``serve_verify``,
     ``serve_rollback``), so a profile tells their device times apart.
+
+    Every program that advances the arena takes it donated (``_ARENA``
+    names its operand) and the caller rebinds ``self.kv`` to the arena it
+    returns, so a call updates the arena in place: no program holds the
+    arena it was given beside the one it returns.  Nothing may keep a
+    reference to ``self.kv`` across such a call.
     """
 
     tracer = None  # set by the ``ServeEngine`` that carries a tracer
@@ -289,7 +298,8 @@ class ModelExecutor:
         it only to trace), so ``stats["compiles"]`` is the compile count —
         including shape-driven retraces the key did not anticipate.  The
         program is named after the key's kind (``jit_serve_decode`` in the
-        profile); a prefill that returns logits is ``serve_prefill_final``."""
+        profile); a prefill that returns logits is ``serve_prefill_final``.
+        The arena operand of every kind is donated (``_ARENA``)."""
         fns = self._cache["fns"]
         hit = fns.get(key)
         if hit is None:
@@ -302,7 +312,8 @@ class ModelExecutor:
             final = key[0] == "prefill" and key[2]
             counted.__name__ = counted.__qualname__ = (
                 f"serve_{key[0]}" + ("_final" if final else ""))
-            hit = fns[key] = jax.jit(counted, **jit_kw)
+            hit = fns[key] = jax.jit(counted, donate_argnums=_ARENA[key[0]],
+                                     **jit_kw)
         return hit
 
     def _decode_fn(self, acc: tuple[int, int]):
@@ -489,15 +500,18 @@ class ModelExecutor:
         slab — final and, for multi-slab prompts, non-final — are CALLED
         on dummy operands with the exact shapes/dtypes the engine will
         use, because only a real call populates jit's dispatch cache (AOT
-        lowering does not).  Outputs are discarded, so the arena is
-        untouched.  After this, steady-state serving performs zero traces;
+        lowering does not).  Each call donates the arena, so the arena it
+        returns is kept: the padded calls write only the reserved null page
+        0 (zero page rows and slab pages, length-0 rows), which no mask
+        ever reads, and leave every other page's bytes as they were.  After
+        this, steady-state serving performs zero traces;
         ``compile_stats()["warm_compiles"]`` records what warmup paid."""
         stats = self._cache["stats"]
         before = stats["compiles"]
         page_size = self.pc.page_size
         for i, b in enumerate(plan.buckets):
             w = b.max_pages(page_size)
-            self._decode_fn(b.acc)(
+            _, self.kv = self._decode_fn(b.acc)(
                 self.params, jnp.zeros((self.max_batch, 1), jnp.int32),
                 self.kv, jnp.zeros((self.max_batch, w), jnp.int32),
                 jnp.zeros((self.max_batch,), jnp.int32),
@@ -511,7 +525,7 @@ class ModelExecutor:
                                      and b.max_ctx > prefill_chunk else []))
             n_slab = -(-slab_w // page_size)
             for final in finals:
-                self._prefill_fn(b.acc, final, call)(
+                _, self.kv = self._prefill_fn(b.acc, final, call)(
                     self.params, jnp.zeros((1, slab_w), jnp.int32),
                     self.kv, jnp.zeros((w,), jnp.int32),
                     jnp.zeros((n_slab,), jnp.int32),
@@ -528,7 +542,8 @@ class ModelExecutor:
         traces (the CI gate extends to spec on).  ``include_verify=False``
         warms only the rollback scrub — the DRAFT lane rolls back but is
         never verified, so its executor skips the per-bucket verify
-        compiles."""
+        compiles.  As in ``warmup``, the padded calls donate the arena and
+        it is kept: they touch only the null page 0."""
         stats = self._cache["stats"]
         before = stats["compiles"]
         page_size = self.pc.page_size
@@ -536,12 +551,12 @@ class ModelExecutor:
         self.rollback_pad = -(-s_v // page_size) + 1
         for b in plan.buckets if include_verify else ():
             w = b.max_pages(page_size)
-            self._verify_fn(b.acc, s_v)(
+            _, self.kv = self._verify_fn(b.acc, s_v)(
                 self.params, jnp.zeros((self.max_batch, s_v), jnp.int32),
                 self.kv, jnp.zeros((self.max_batch, w), jnp.int32),
                 jnp.zeros((self.max_batch,), jnp.int32),
                 jnp.zeros((self.max_batch,), jnp.int32))
-        self._jit(("rollback", self.rollback_pad), truncate_pages)(
+        self.kv = self._jit(("rollback", self.rollback_pad), truncate_pages)(
             self.kv, jnp.zeros((self.rollback_pad,), jnp.int32),
             jnp.int32(0), jnp.int32(0))
         delta = stats["compiles"] - before

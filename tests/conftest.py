@@ -43,3 +43,30 @@ def run_child(code: str, *, devices: int = 8, timeout: int = 600) -> str:
     )
     assert out.returncode == 0, f"child failed:\nSTDOUT:\n{out.stdout}\nSTDERR:\n{out.stderr}"
     return out.stdout
+
+
+def layer_sliced_arena(setattr) -> None:
+    """Make every paged attention layer run over its own layer's slab, as
+    a layer scan that carries arena slices as ``xs`` and stacks them back
+    as ``ys`` does: slab ``l`` is indexed out of the arena, the layer
+    writes and attends it as a one-layer arena (``layer=None``), and the
+    slab is written back at ``l``.  The serving program instead writes and
+    reads the whole arena at ``[l, page]`` in place; the tests hold the two
+    to the same tokens, logits and arena bytes.  ``setattr`` is pytest's
+    ``monkeypatch.setattr`` (undone after the test) or the builtin in a
+    child process.  The process compile cache is swapped for an empty one,
+    so executors built afterwards trace the patched layers."""
+    from repro.models import layers
+    from repro.serve import scheduler
+
+    def sliced(attend):
+        def run(p, x, kv, *args, layer, **kw):
+            slab = {k: a[layer] for k, a in kv.items()}
+            y, slab = attend(p, x, slab, *args, layer=None, **kw)
+            return y, {k: kv[k].at[layer].set(slab[k]) for k in kv}
+        return run
+
+    for name in ("attn_decode_paged", "attn_verify_paged",
+                 "attn_prefill_bucketed"):
+        setattr(layers, name, sliced(getattr(layers, name)))
+    setattr(scheduler, "_PROCESS_CACHE", {})

@@ -6,6 +6,8 @@ isolation; hypothesis property tests over arrival/completion orders)."""
 
 from __future__ import annotations
 
+import re
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -21,11 +23,12 @@ from repro.kernels.attention import (
     paged_attn_decode_reference,
 )
 from repro.models import encdec, lm
-from repro.models.api import get_model
+from repro.models.api import DecodeRequest, get_model
 from repro.quant.formats import FP8_152, FPFormat
 from repro.serve import kvcache as KV
 from repro.serve.plan import decode_m_acc, min_e_acc, plan_attention
 from repro.serve.scheduler import ServeEngine, measure_decode_vrr
+from tests.conftest import layer_sliced_arena
 
 ACC = (6, 7)
 
@@ -526,6 +529,72 @@ def test_encdec_decode_step_paged():
 
 
 # --------------------------------------------------------------------------
+# the arena updated in place: bitwise what per-layer slabs gave
+# --------------------------------------------------------------------------
+
+
+def _paged_entry(path, cfg, params, acc):
+    """One paged entry's call as ``fn(kv) -> (logits, kv)`` on the arena
+    ``_layered_arena`` fills: sequence 0 holds 8 tokens on pages 1-2,
+    sequence 1 holds 2 on page 3.  Decode and verify write at positions
+    8 and 2 (page 5's first slot, which fixes its scale; mid-page 3, and
+    on into page 6 under verify); prefill runs a 3-token slab at offset 8
+    on page 5, as a prompt's last slab (logits) or as one before it."""
+    kw = dict(kv_fmt=FP8_152, acc=acc)
+    pt = jnp.asarray([[1, 2, 5], [3, 6, 0]], jnp.int32)
+    pos = jnp.asarray([8, 2], jnp.int32)
+    if path in ("decode", "decode_oracle"):
+        toks = jnp.asarray([[5], [11]], jnp.int32)
+        return lambda kv: lm.paged_decode(
+            params, toks, kv, pt, pos, pos + 1, cfg,
+            oracle=path == "decode_oracle", **kw)
+    if path == "verify":
+        toks = jnp.asarray([[5, 7, 9], [11, 13, 2]], jnp.int32)
+        return lambda kv: lm.paged_verify(params, toks, kv, pt, pos,
+                                          pos + 1, cfg, **kw)
+    toks = jnp.asarray([[5, 7, 9, 0]], jnp.int32)
+    row = jnp.asarray([1, 2, 5, 0], jnp.int32)
+    return lambda kv: lm.paged_prefill(
+        params, toks, kv, row, jnp.asarray([5], jnp.int32), 8, 3, cfg,
+        want_logits=path == "prefill_final", **kw)
+
+
+def _layered_arena(cfg, params, acc):
+    rng = np.random.RandomState(21)
+    kv = lm.init_paged_state(cfg, n_pages=8, page_size=4)
+    for pages, n in (([1, 2], 8), ([3], 2)):
+        toks = jnp.asarray([rng.randint(0, cfg.vocab_size, n)], jnp.int32)
+        ids = jnp.asarray(pages, jnp.int32)
+        _, kv = lm.paged_prefill(params, toks, kv, ids, ids, 0, n, cfg,
+                                 kv_fmt=FP8_152, acc=acc)
+    return kv
+
+
+@pytest.mark.parametrize("path", ["decode", "decode_oracle", "verify",
+                                  "prefill_final", "prefill_slab"])
+def test_paged_entry_in_place_matches_layer_slabs(smoke_model, monkeypatch,
+                                                  path):
+    """Each paged entry writes and reads the whole arena at each layer's
+    index: its logits and every arena byte are those of the same entry
+    run over per-layer slabs (``conftest.layer_sliced_arena``)."""
+    model, params = smoke_model
+    cfg = model.cfg
+    assert cfg.n_layers > 1, "one layer cannot tell the layers apart"
+    _, bucket = plan_attention(28, 4).bucket_for(12)
+    kv0 = _layered_arena(cfg, params, bucket.acc)
+    logits, kv = jax.jit(_paged_entry(path, cfg, params, bucket.acc))(kv0)
+    layer_sliced_arena(monkeypatch.setattr)
+    want, kv_want = jax.jit(_paged_entry(path, cfg, params, bucket.acc))(kv0)
+    assert (logits is None) == (path == "prefill_slab") == (want is None)
+    if want is not None:
+        np.testing.assert_array_equal(np.asarray(logits), np.asarray(want))
+    for key in kv0:
+        np.testing.assert_array_equal(np.asarray(kv[key]),
+                                      np.asarray(kv_want[key]))
+    assert not np.array_equal(np.asarray(kv["k"]), np.asarray(kv0["k"]))
+
+
+# --------------------------------------------------------------------------
 # serve-time VRR monitor
 # --------------------------------------------------------------------------
 
@@ -704,6 +773,112 @@ def test_engine_forced_preempt_midstream_is_exact(smoke_model):
     out = eng.run()
     assert out[rid] == baseline
     assert eng.restores == 1
+
+
+@pytest.mark.parametrize("scenario", ["chunked_prefill", "swap"])
+def test_engine_in_place_arena_matches_layer_slabs(smoke_model, monkeypatch,
+                                                   scenario):
+    """The engine over the in-place arena, with chunked prefill (final and
+    earlier slabs) or with preemption, swap-out and swap-in forced by a
+    tight pool, gives the token streams, arena bytes and next decode
+    logits that the same engine gives over per-layer slabs."""
+    model, params = smoke_model
+    rng = np.random.RandomState(23)
+    lens = (9, 5, 3) if scenario == "chunked_prefill" else (8, 8, 8)
+    prompts = [list(rng.randint(0, model.cfg.vocab_size, n)) for n in lens]
+    n_pages = 24 if scenario == "chunked_prefill" else 7
+
+    def run():
+        eng = _engine(model, params, n_pages=n_pages,
+                      prefill_chunk_tokens=4)
+        rids = [eng.submit(p, 6) for p in prompts]
+        out = eng.run()
+        arena = {k: np.asarray(a) for k, a in eng.kv.items()}
+        _, bucket = eng.plan.bucket_for(12)
+        logits = eng.executor.decode_logits(DecodeRequest(
+            rids=(0,), last_tokens=(5,), page_table=((1, 2, 3),),
+            positions=(10,), seq_lens=(11,), acc=bucket.acc))
+        return [tuple(out[r]) for r in rids], arena, np.asarray(logits), eng
+
+    streams, arena, logits, eng = run()
+    if scenario == "swap":
+        assert eng.preemptions > 0 and eng.restores > 0
+    else:
+        assert eng.prefill_slabs > len(prompts)
+    layer_sliced_arena(monkeypatch.setattr)
+    want_streams, want_arena, want_logits, _ = run()
+    assert streams == want_streams
+    for key in arena:
+        np.testing.assert_array_equal(arena[key], want_arena[key])
+    np.testing.assert_array_equal(logits, want_logits)
+
+
+def _aliased_params(compiled) -> set[int]:
+    """Parameter numbers of a compiled program that its outputs alias."""
+    head = compiled.as_text().splitlines()[0]
+    alias = re.search(r"input_output_alias=\{(.*?)\}, entry", head)
+    return set() if alias is None else {
+        int(n) for n in re.findall(r"\}: \((\d+),", alias.group(1))}
+
+
+@pytest.mark.parametrize("kind", ["decode", "prefill", "verify", "rollback"])
+def test_executor_programs_alias_the_arena(smoke_model, kind):
+    """Every program that advances the arena is compiled with the arena
+    donated: its outputs alias exactly the arena's four input buffers."""
+    model, params = smoke_model
+    eng = _engine(model, params, prefill_chunk_tokens=4)
+    ex, b = eng.executor, eng.plan.buckets[0]
+    w, mb, z = b.max_pages(eng.page_size), ex.max_batch, jnp.zeros
+    i32 = jnp.int32
+    first = len(jax.tree.leaves(ex.params)) + 1  # params, tokens, arena
+    if kind == "decode":
+        low = ex._decode_fn(b.acc).lower(
+            ex.params, z((mb, 1), i32), ex.kv, z((mb, w), i32),
+            z((mb,), i32), z((mb,), i32))
+    elif kind == "prefill":
+        call = eng.plan.kernel_call(0, h=eng.cfg.n_heads,
+                                    dh=eng.cfg.head_dim, kv_fmt=eng.kv_fmt,
+                                    slab_tokens=4)
+        low = ex._prefill_fn(b.acc, True, call).lower(
+            ex.params, z((1, 4), i32), ex.kv, z((w,), i32), z((1,), i32),
+            i32(0), i32(4))
+    elif kind == "verify":
+        low = ex._verify_fn(b.acc, 3).lower(
+            ex.params, z((mb, 3), i32), ex.kv, z((mb, w), i32),
+            z((mb,), i32), z((mb,), i32))
+    else:
+        first = 0
+        low = ex._jit(("rollback", 2), KV.truncate_pages).lower(
+            ex.kv, z((2,), i32), i32(0), i32(0))
+    compiled = low.compile()
+    assert _aliased_params(compiled) == set(range(first, first + 4))
+    assert compiled.memory_analysis().alias_size_in_bytes == sum(
+        a.nbytes for a in ex.kv.values())
+
+
+def test_warmup_keeps_every_live_page(smoke_model):
+    """Warm-up calls donate the arena and keep what they return: every
+    page but the null page 0 is byte-identical afterwards, the arena
+    they were given is gone, and the engine serves on without touching
+    a deleted buffer."""
+    model, params = smoke_model
+    rng = np.random.RandomState(24)
+    eng = _engine(model, params, prefill_chunk_tokens=4)
+    eng.submit(list(rng.randint(0, model.cfg.vocab_size, 9)), 3)
+    eng.run()
+    # read through copies: a host view of a CPU buffer would pin it, and
+    # a pinned buffer is copied rather than donated
+    before = {k: np.asarray(jnp.copy(a)) for k, a in eng.kv.items()}
+    assert before["k"][:, 1:].any() and before["k_se"][:, 1:].any()
+    given = eng.executor.kv
+    eng.executor.warmup(eng.plan, prefill_chunk=4)
+    eng.executor.warmup_verify(eng.plan, k=2)
+    assert all(a.is_deleted() for a in given.values())
+    for key, a in eng.kv.items():
+        np.testing.assert_array_equal(np.asarray(a)[:, 1:],
+                                      before[key][:, 1:])
+    rid = eng.submit(list(rng.randint(0, model.cfg.vocab_size, 6)), 4)
+    assert len(eng.run()[rid]) == 4
 
 
 def test_monitor_rebucket_keyed_by_grown_context(smoke_model):

@@ -281,6 +281,78 @@ print("OK")
 
 
 @pytest.mark.slow
+@pytest.mark.parametrize("policy", ["exact", "predicted"])
+def test_sharded_in_place_arena_matches_layer_slabs(policy):
+    """Under four shards (each a KV-head slice of the arena, page scales
+    pmax'd across them): chunked prefill, decode, a forced preemption with
+    swap-out and swap-in, then verify and a page-exact rollback, over the
+    in-place arena, give the tokens, decode logits and arena bytes that
+    the same executor gives over per-layer slabs; with exact GEMMs and
+    with the paper's quantized ones (``--policy predicted``)."""
+    quantize = "" if policy == "exact" else (
+        "from repro.core.policy import AccumulationPolicy, plan_for_model\n"
+        "cfg = plan_for_model(cfg, seq_len=32, global_batch=3, policy="
+        "AccumulationPolicy(mode='predicted'))\n")
+    run_child(
+        _SHARD_CFG + quantize + """
+import jax, numpy as np
+from repro.models.api import DecodeRequest, VerifyRequest, get_model
+from repro.quant.formats import FPFormat
+from repro.serve.kvcache import PagedKVConfig
+from repro.serve.plan import plan_attention
+from repro.serve.scheduler import ServeEngine, ShardedModelExecutor
+from tests.conftest import layer_sliced_arena
+
+model = get_model(cfg)
+params = model.init_params(jax.random.PRNGKey(0))
+kv_fmt = FPFormat(e=5, m=2)
+N_PAGES, PAGE = 24, 4
+pc = PagedKVConfig.for_model(cfg, n_pages=N_PAGES, page_size=PAGE, kv_fmt=kv_fmt)
+prompts = [list(np.random.RandomState(s).randint(1, cfg.vocab_size, n))
+           for s, n in ((1, 5), (2, 9), (3, 3))]
+plan = plan_attention((N_PAGES - 1) * PAGE, PAGE, prefill_chunk_tokens=4,
+                      tp_shards=4)
+_, b = plan.bucket_for(14)
+
+def drive():
+    ex = ShardedModelExecutor(model, params, pc, kv_fmt=kv_fmt, n_shards=4,
+                              max_batch=3)
+    eng = ServeEngine(model, params, n_pages=N_PAGES, page_size=PAGE,
+                      max_batch=3, executor=ex, plan=plan,
+                      prefill_chunk_tokens=4)
+    rids = [eng.submit(p, 5) for p in prompts]
+    for _ in range(4):
+        eng.step()
+    eng.preempt(max(eng.active))
+    out = eng.run()
+    assert eng.preemptions >= 1 and eng.restores >= 1
+    logits = np.asarray(ex.decode_logits(DecodeRequest(
+        rids=(0,), last_tokens=(5,), page_table=((1, 2, 3, 4),),
+        positions=(10,), seq_lens=(11,), acc=b.acc)))
+    # three candidates at 11..13 (page 3's last slot, page 4's first two),
+    # then a rejection back to 12 tokens releases page 4
+    ver = ex.verify(VerifyRequest(
+        rids=(0,), tokens=((5, 7, 9),), page_table=((1, 2, 3, 4),),
+        positions=(11,), seq_lens=(12,), acc=b.acc))
+    ex.rollback(0, [1, 2, 3, 4], 12, 14)
+    arena = {k: np.asarray(a) for k, a in ex.kv.items()}
+    return [out[r] for r in rids], logits, ver, arena
+
+streams, logits, ver, arena = drive()
+layer_sliced_arena(setattr)
+w_streams, w_logits, w_ver, w_arena = drive()
+assert streams == w_streams, (streams, w_streams)
+assert np.array_equal(logits, w_logits), "decode logits diverged"
+assert ver == w_ver, (ver, w_ver)
+for k in ("k", "v", "k_se", "v_se"):
+    assert np.array_equal(arena[k], w_arena[k]), f"arena {k} diverged"
+print("OK")
+""",
+        devices=4,
+    )
+
+
+@pytest.mark.slow
 def test_sharded_oracle_parity_with_single_device():
     """The jnp-oracle (reference-kernel) serve path under the same 4-shard
     mesh: token streams and arenas bitwise equal to single-device

@@ -34,6 +34,7 @@ from repro.serve import truncate_pages
 from repro.serve.plan import plan_attention, plan_verify
 from repro.serve.scheduler import ServeEngine
 from repro.serve.spec import SpecDecodeEngine
+from tests.conftest import layer_sliced_arena
 
 
 @pytest.fixture(scope="module")
@@ -189,6 +190,35 @@ def test_spec_engine_stream_matches_plain_greedy(smoke_pair):
     assert eng.draft_pool.free_pages == eng.draft_pool.n_pages - 1
     # an unrelated draft model accepts sometimes, not always
     assert 0.0 <= eng.acceptance_rate() < 1.0
+
+
+def test_spec_engine_in_place_arena_matches_layer_slabs(smoke_pair,
+                                                       monkeypatch):
+    """Verify and rollback over the in-place arena: the spec engine's
+    streams and both lanes' arena bytes are those it gives over per-layer
+    slabs (``conftest.layer_sliced_arena``), with rejections, so rollbacks
+    really scrub pages."""
+    model, params, dmodel, dparams = smoke_pair
+    rng = np.random.RandomState(14)
+    prompts = [list(rng.randint(0, model.cfg.vocab_size, n))
+               for n in (5, 9, 3)]
+
+    def run():
+        eng = SpecDecodeEngine(model, params, spec_k=2, draft_model=dmodel,
+                               draft_params=dparams, **_ENG_KW)
+        streams = _run(eng, prompts, 5)
+        arenas = [{k: np.asarray(a) for k, a in ex.kv.items()}
+                  for ex in (eng.executor, eng.draft_executor)]
+        return streams, arenas, eng
+
+    streams, arenas, eng = run()
+    assert eng.spec_rollback_tokens > 0
+    layer_sliced_arena(monkeypatch.setattr)
+    want_streams, want_arenas, _ = run()
+    assert streams == want_streams
+    for arena, want in zip(arenas, want_arenas):
+        for key in arena:
+            np.testing.assert_array_equal(arena[key], want[key])
 
 
 def test_spec_engine_all_accept_when_draft_is_target(smoke_pair):

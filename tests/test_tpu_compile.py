@@ -165,3 +165,35 @@ def test_paged_attn_decode_compiles(one_chip, case):
         return_carry=case.endswith("_carry"), interpret=False), one_chip,
         ((rows, cfg.n_heads, cfg.head_dim), F32), *arena,
         ((rows, width), jnp.int32), ((rows,), jnp.int32))
+
+
+@pytest.mark.parametrize("case", ["decode", "decode_carry", "prefill"])
+def test_kernels_read_the_layer_of_the_whole_arena(one_chip, case):
+    """The serving programs hand the kernels the whole (L, P, KV, page,
+    dh) arena of the decode64 cell and the layer as a traced scalar; the
+    decode kernel's page DMAs and the prefill kernel's page blocks then
+    index ``[layer, page]``."""
+    cfg = get_config(SERVE_ARCH)
+    pages = ((cfg.n_layers, CELL_PAGES, cfg.n_kv_heads, PAGE, cfg.head_dim),
+             I8)
+    scales = ((cfg.n_layers, CELL_PAGES), jnp.int32)
+    arena = [pages, pages, scales, scales]
+    i32 = ((), jnp.int32)
+    plan = plan_attention((CELL_PAGES - 1) * PAGE, PAGE,
+                          prefill_chunk_tokens=SLAB)
+    if case == "prefill":
+        last = len(plan.buckets) - 1
+        call = plan.kernel_call(last, h=cfg.n_heads, dh=cfg.head_dim,
+                                kv_fmt=KV_FMT, slab_tokens=SLAB)
+        _compile(lambda q, kp, vp, ks, vs, ly, row, q0, ql, kl:
+                 flash_prefill_paged(q, kp, vp, ks, vs, row, q0, ql, kl,
+                                     call=call, layer=ly, interpret=False),
+                 one_chip, ((SLAB, cfg.n_heads, cfg.head_dim), F32), *arena,
+                 i32, ((call.max_pages,), jnp.int32), i32, i32, i32)
+        return
+    acc = plan.buckets[-1].acc
+    _compile(lambda q, kp, vp, ks, vs, ly, pt, sl: paged_attn_decode(
+        q, kp, vp, ks, vs, pt, sl, layer=ly, kv_fmt=KV_FMT, acc=acc,
+        return_carry=case == "decode_carry", interpret=False), one_chip,
+        ((CELL_ROWS, cfg.n_heads, cfg.head_dim), F32), *arena, i32,
+        ((CELL_ROWS, CELL_WIDTH), jnp.int32), ((CELL_ROWS,), jnp.int32))
