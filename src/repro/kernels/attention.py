@@ -3,60 +3,53 @@
 Attention over a growing KV-cache is the longest accumulation in the serving
 system — the softmax-weighted value sum reduces over every cached token — so
 it is where the paper's variance-retention analysis pays the largest
-inference dividend.  Two kernels cover the serve path:
+inference dividend.  Two kernels cover the serve path, both reading int8
+``(1, e, m)`` pages straight out of the whole ``(L, P, KV, page_size, dh)``
+arena (``repro.serve.kvcache``) at ``[layer, page]`` and unpacking them in
+VMEM (``repro.quant.qtensor`` layout, times each page's power-of-two scale;
+f32 carrier pages pass through, the parity mode) — no dequantized copy of
+the cache ever exists in HBM:
 
-* ``flash_prefill`` — causal online-softmax attention over a prompt
-  (one sequence), KV visited in ``chunk``-length blocks.  It is
-  **resumable**: ``carry=(o, m, l)`` feeds a previous call's online-softmax
-  state back in and ``return_carry=True`` hands the raw state out instead of
-  the finalized output, while ``q_offset``/``kv_offset`` place the query and
-  KV slabs on the absolute token axis.  Because the running max lives on the
-  integer base-2 lattice and the o/l carries are already rounded to the
-  accumulator format after every block, the carry round-trips through HBM
-  exactly — splitting the KV walk at any block boundary and resuming is
-  bit-identical to the one-shot walk.  Chunked prefill
-  (``repro.serve.scheduler``) leans on this: each ``prefill_chunk_tokens``
-  query slab attends its page-aligned KV history with a carry-out call and
-  folds its own causal slab with a carry-in call.
-* ``paged_attn_decode`` — single-token decode against the paged QTensor
-  KV-cache (``repro.serve.kvcache``): the page table, per-page scale
-  exponents and the layer index ride in as scalar-prefetch operands, the
-  pages are read straight out of the whole ``(L, P, KV, page_size, dh)``
-  arena at ``[layer, page]`` (no layer's slab is sliced out of it for the
-  call), and each grid step walks
-  a block of up to 256 tokens of one sequence (``_decode_block_pages``
-  pages) for all its KV heads.  The block's int8 pages are DMA'd into a
-  double-buffered VMEM scratch while the previous block computes (heads
-  narrower than the 128 lanes take them as pipelined grid operands;
-  blocks past the sequence's length fetch nothing), unpacked in VMEM
-  (``repro.quant.qtensor`` layout, times each page's power-of-two scale)
-  and scored in one contraction; the online softmax then folds the block
-  page by page, its carry rounded once per page as before — no
-  dequantized copy of the cache ever exists in HBM.
-* ``flash_prefill_paged`` — causal prefill rebuilt on the decode kernel's
-  scalar-prefetch pattern: the page row, per-page scale exponents and the
-  absolute-axis geometry (``q_offset``/``q_len``/``kv_len``/``start_page``)
-  are all TRACED operands, the page row is padded to the bucket width and
+* ``paged_attn_decode`` — single-token decode: the page table, per-page
+  scale exponents and the layer index ride in as scalar-prefetch operands,
+  and each grid step walks a block of up to 256 tokens of one sequence
+  (``_decode_block_pages`` pages) for all its KV heads.  The block's pages
+  are DMA'd into a double-buffered VMEM scratch while the previous block
+  computes (heads narrower than the 128 lanes take them as pipelined grid
+  operands; blocks past the sequence's length fetch nothing) and scored in
+  one contraction; the online softmax then folds the block page by page,
+  its carry rounded once per page.
+* ``flash_prefill_paged`` — causal prefill on the same scalar-prefetch
+  pattern: the page row, per-page scale exponents and the absolute-axis
+  geometry (``q_offset``/``q_len``/``kv_len``/``start_page``) are all
+  TRACED operands, the page row is padded to the bucket width and
   ``pl.when`` masks past the live page count — so ONE compiled kernel per
   attention bucket (``repro.serve.plan``) serves every slab of every prompt
   in the bucket, aligned or ragged, history and fresh slab walked in a
-  single pass over the post-write arena.  Bit-identical to the dense
-  ``flash_prefill`` walk at the same ``chunk == page_size`` cadence.
+  single pass over the post-write arena.  It is **resumable**: ``carry=(o,
+  m, l)`` feeds a previous call's online-softmax state over pages ``[0,
+  start_page)`` back in and ``return_carry=True`` hands the raw state out
+  instead of the finalized output.  Because the running max lives on the
+  integer base-2 lattice and the o/l carries are already rounded to the
+  accumulator format after every page, the carry round-trips through HBM
+  exactly — splitting the walk at any page boundary and resuming is
+  bit-identical to the one-shot walk.
 
 Accumulation discipline (the same chunked low-precision carry as
-``fused.py``): within one KV block the score and weighted-value contractions
-run in ideal f32 (intra-chunk); across blocks the THREE online-softmax
-carries — the output accumulator ``o`` and the denominator ``l`` — are
-rounded to the planner's ``(1, e_acc, m_acc)`` accumulator format after
-every block update (``repro.serve.plan`` sizes the format per context-length
-bucket with the paper's §4.4 knee test; the running max ``m`` is exact — it
-is order statistics, not an accumulation).  The per-block update, shared
-verbatim by the kernels and the unfused references, is ``_online_update``.
+``fused.py``): within one KV page the score and weighted-value contractions
+run in ideal f32 (intra-chunk); across pages the online-softmax carries —
+the output accumulator ``o`` and the denominator ``l`` — are rounded to the
+planner's ``(1, e_acc, m_acc)`` accumulator format after every page update
+(``repro.serve.plan`` sizes the format per context-length bucket with the
+paper's §4.4 knee test; the running max ``m`` is exact — it is order
+statistics, not an accumulation).  The per-page update, shared verbatim by
+the kernels and the unfused references, is ``_online_update``.
 
 Bit-exactness contract: ``*_reference`` are unfused jnp oracles that walk
-the same blocks in the same order with the same carry rounding —
+the same pages in the same order with the same carry rounding —
 ``tests/test_serve.py`` pins kernel == reference exactly (ragged page
-tails, decode at page boundaries, packed-vs-f32 KV parity included).
+tails, decode at page boundaries, prefill resumed at every page boundary,
+packed-vs-f32 KV parity included).
 
 ``paged_attn_decode(collect_stats=True)`` is the serve-time telemetry
 variant: alongside the quantized carries it runs a wide (f32) shadow ``o``
@@ -82,19 +75,14 @@ from repro.kernels.autotune import AttnCall, fmt_tuple, register_kernel
 from repro.kernels.common import (
     LANES,
     N_STATS,
-    ROUNDINGS,
     quantize_block,
-    quantize_block_sr,
     resolve_interpret,
-    sr_random_bits,
     stats_delta_row,
     stats_update,
 )
 from repro.quant.qtensor import unpack_block
 
 __all__ = [
-    "flash_prefill",
-    "flash_prefill_reference",
     "flash_prefill_paged",
     "flash_prefill_paged_reference",
     "paged_attn_decode",
@@ -184,47 +172,7 @@ def _pv(p: jnp.ndarray, v: jnp.ndarray) -> jnp.ndarray:
         preferred_element_type=jnp.float32)
 
 
-# the l carry draws its dither from a salted seed stream so it never shares
-# bits with the o carry of the same (row, block) — correlated dither between
-# numerator and denominator would bias the finalized ratio
-_L_SALT = 0x6A09E667
-
-
-def _sr_attn_bits(seed, step, *, abs_row0, head0, block_q: int, dh: int,
-                  h: int, shape3=None):
-    """Dither bits for one KV-block carry update of the online softmax.
-
-    Pure function of (seed, absolute KV-block index ``step``, absolute
-    query row, head, feature) — invariant to q blocking, grid schedule and
-    chunked-prefill resumption (a resumed walk re-derives the SAME bits the
-    one-shot walk used at that block, so resume == one-shot stays bitwise).
-    Returns ``(rbits_o, rbits_l)`` shaped like the o / l carries: the
-    kernel calls it per (head, q-tile) with scalars ``head0``/``abs_row0``;
-    the reference passes ``shape3=(h, s, dh)`` to draw the whole slab's
-    bits in one shot from identical coordinates."""
-    seed = jnp.asarray(seed).astype(jnp.uint32)
-    step = jnp.asarray(step).astype(jnp.uint32)
-    row0 = jnp.asarray(abs_row0).astype(jnp.uint32)
-    if shape3 is None:
-        head = jnp.asarray(head0).astype(jnp.uint32)
-        ro = (jax.lax.broadcasted_iota(jnp.uint32, (block_q, dh), 0) + row0)
-        co = (jax.lax.broadcasted_iota(jnp.uint32, (block_q, dh), 1)
-              + head * jnp.uint32(dh))
-        rl = (jax.lax.broadcasted_iota(jnp.uint32, (block_q, 1), 0) + row0)
-        cl = jnp.broadcast_to(head, (block_q, 1))
-    else:
-        ro = jax.lax.broadcasted_iota(jnp.uint32, shape3, 1) + row0
-        co = (jax.lax.broadcasted_iota(jnp.uint32, shape3, 0)
-              * jnp.uint32(dh)
-              + jax.lax.broadcasted_iota(jnp.uint32, shape3, 2))
-        rl, cl = ro[..., :1], co[..., :1] // jnp.uint32(dh)
-    rbits_o = sr_random_bits(seed, step, ro, co, h * dh)
-    rbits_l = sr_random_bits(seed ^ jnp.uint32(_L_SALT), step, rl, cl, h)
-    return rbits_o, rbits_l
-
-
-def _online_update(o, m, l, t, valid, v, e_acc: int, m_acc: int,
-                   rounding: str = "rne", rbits=None):
+def _online_update(o, m, l, t, valid, v, e_acc: int, m_acc: int):
     """One KV-block step of the online softmax with the chunked
     low-precision carry discipline.
 
@@ -237,12 +185,8 @@ def _online_update(o, m, l, t, valid, v, e_acc: int, m_acc: int,
     inter-chunk stage of the paper's Corollary 1 — while everything within
     the block is ideal f32.  A fully-masked block is a carry no-op: alpha =
     2^0 = 1, the addends are exactly zero, and the carry is a representable
-    point of the accumulator format, so quantize(c + 0) == c — under BOTH
-    roundings (a representable point is a fixed point of the SR dither
-    too, so predicating a provably-masked block away stays bit-identical
-    to running it).  ``rounding="sr"`` replaces the carry's
-    round-to-nearest with stochastic rounding driven by ``rbits``, a
-    ``(rbits_o, rbits_l)`` pair from ``_sr_attn_bits``.  Returns
+    point of the accumulator format, so quantize(c + 0) == c — predicating
+    a provably-masked block away is bit-identical to running it.  Returns
     (o', m', l')."""
     m_new = jnp.maximum(m, jnp.ceil(jnp.max(t, axis=-1, keepdims=True)))
     alpha = jnp.exp2(m - m_new)
@@ -251,311 +195,14 @@ def _online_update(o, m, l, t, valid, v, e_acc: int, m_acc: int,
     p = jnp.where(valid, jnp.exp2(t - m_new), 0.0)
     l_raw = l * alpha + jnp.sum(p, axis=-1, keepdims=True)
     o_raw = o * alpha + _pv(p, v)
-    if rounding == "sr":
-        rbits_o, rbits_l = rbits
-        l_new = quantize_block_sr(l_raw, e_acc, m_acc, rbits_l)
-        o_new = quantize_block_sr(o_raw, e_acc, m_acc, rbits_o)
-    else:
-        l_new = quantize_block(l_raw, e_acc, m_acc)
-        o_new = quantize_block(o_raw, e_acc, m_acc)
+    l_new = quantize_block(l_raw, e_acc, m_acc)
+    o_new = quantize_block(o_raw, e_acc, m_acc)
     return o_new, m_new, l_new
 
 
 def _finalize(o, l):
     """out = o / l; 0 where nothing was attended (l == 0)."""
     return jnp.where(l > 0.0, o / jnp.where(l > 0.0, l, 1.0), 0.0)
-
-
-# --------------------------------------------------------------------------
-# prefill
-# --------------------------------------------------------------------------
-
-
-def _prefill_kernel(*refs, sk_true: int, block_q: int, chunk: int,
-                    e_acc: int, m_acc: int, scale: float, q_offset: int,
-                    kv_offset: int, has_carry: bool, emit_carry: bool,
-                    rounding: str, sr_seed: int, h_total: int):
-    n_in = 6 if has_carry else 3
-    q_ref, k_ref, v_ref = refs[:3]
-    out_refs = refs[n_in:n_in + (3 if emit_carry else 1)]
-    oacc, mx, lx = refs[n_in + (3 if emit_carry else 1):]
-    # program_id must be bound at kernel top level (interpret mode only
-    # substitutes it there, not inside pl.when branch jaxprs)
-    hq, qi, kk = pl.program_id(0), pl.program_id(1), pl.program_id(2)
-
-    @pl.when(kk == 0)
-    def _init():
-        if has_carry:
-            co_ref, cm_ref, cl_ref = refs[3:6]
-            oacc[...] = co_ref[0]
-            mx[...] = cm_ref[0]
-            lx[...] = cl_ref[0]
-        else:
-            oacc[...] = jnp.zeros_like(oacc)
-            mx[...] = jnp.full_like(mx, NEG)
-            lx[...] = jnp.zeros_like(lx)
-
-    # blocks strictly in the causal future (or wholly past the KV slab's
-    # end) are provably carry no-ops — every score masked, alpha = 1,
-    # addends exactly 0 — so their MXU/VPU work is predicated away outright.
-    # Causality is on ABSOLUTE positions: query row i sits at q_offset + i,
-    # KV column j at kv_offset + j (one-shot calls have both offsets 0).
-    @pl.when((kv_offset + kk * chunk
-              <= q_offset + qi * block_q + block_q - 1)
-             & (kk * chunk < sk_true))
-    def _update():
-        q = q_ref[0]  # (block_q, dh)
-        k = k_ref[0]  # (chunk, dh)
-        v = v_ref[0]
-        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32) * scale
-        rows = (q_offset + qi * block_q
-                + jax.lax.broadcasted_iota(jnp.int32, s.shape, 0))
-        cols_l = kk * chunk + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-        valid = (kv_offset + cols_l <= rows) & (cols_l < sk_true)
-        s = jnp.where(valid, s, NEG)
-        rbits = None
-        if rounding == "sr":
-            # dither keyed on the ABSOLUTE kv-block index and absolute
-            # (row, head, feature) — a resumed walk draws the same bits
-            rbits = _sr_attn_bits(
-                jnp.uint32(sr_seed), kv_offset // chunk + kk,
-                abs_row0=q_offset + qi * block_q, head0=hq,
-                block_q=block_q, dh=v.shape[-1], h=h_total)
-        o_new, m_new, l_new = _online_update(
-            oacc[...], mx[...], lx[...], s, valid, v, e_acc, m_acc,
-            rounding=rounding, rbits=rbits)
-        oacc[...] = o_new
-        mx[...] = m_new
-        lx[...] = l_new
-
-    @pl.when(kk == pl.num_programs(2) - 1)
-    def _emit():
-        if emit_carry:
-            out_refs[0][0] = oacc[...]
-            out_refs[1][0] = mx[...]
-            out_refs[2][0] = lx[...]
-        else:
-            out_refs[0][0] = _finalize(oacc[...], lx[...])
-
-
-@functools.partial(
-    jax.jit,
-    static_argnames=("e_acc", "m_acc", "chunk", "block_q", "q_offset",
-                     "kv_offset", "emit_carry", "interpret", "rounding",
-                     "sr_seed"),
-)
-def _flash_prefill(q, k, v, carry_o, carry_m, carry_l, *, e_acc, m_acc,
-                   chunk, block_q, q_offset, kv_offset, emit_carry,
-                   interpret, rounding="rne", sr_seed=0):
-    _count_trace("flash_prefill")
-    s, h, dh = q.shape
-    sk_true = k.shape[0]
-    kv = k.shape[1]
-    g = h // kv
-    has_carry = carry_o is not None
-    # GQA: repeat K/V to the full head count (prefill-transient HBM; the
-    # decode kernel instead shares one KV page across its g query rows)
-    kh = jnp.repeat(k, g, axis=1) if g > 1 else k
-    vh = jnp.repeat(v, g, axis=1) if g > 1 else v
-    sq = -(-s // block_q) * block_q
-    sk = -(-sk_true // chunk) * chunk
-    qt = jnp.pad(q.astype(jnp.float32).transpose(1, 0, 2),
-                 ((0, 0), (0, sq - s), (0, 0)))
-    kt = jnp.pad(kh.astype(jnp.float32).transpose(1, 0, 2),
-                 ((0, 0), (0, sk - sk_true), (0, 0)))
-    vt = jnp.pad(vh.astype(jnp.float32).transpose(1, 0, 2),
-                 ((0, 0), (0, sk - sk_true), (0, 0)))
-    grid = (h, sq // block_q, sk // chunk)
-    in_specs = [
-        pl.BlockSpec((1, block_q, dh), lambda hh, qi, kk: (hh, qi, 0)),
-        pl.BlockSpec((1, chunk, dh), lambda hh, qi, kk: (hh, kk, 0)),
-        pl.BlockSpec((1, chunk, dh), lambda hh, qi, kk: (hh, kk, 0)),
-    ]
-    operands = [qt, kt, vt]
-    if has_carry:
-        # carry rows ride in the kernel layout; padded rows get the same
-        # neutral state the cold init uses (they are sliced off anyway)
-        co = jnp.pad(carry_o.astype(jnp.float32).transpose(1, 0, 2),
-                     ((0, 0), (0, sq - s), (0, 0)))
-        cm = jnp.pad(carry_m.astype(jnp.float32).T[..., None],
-                     ((0, 0), (0, sq - s), (0, 0)), constant_values=NEG)
-        cl = jnp.pad(carry_l.astype(jnp.float32).T[..., None],
-                     ((0, 0), (0, sq - s), (0, 0)))
-        operands += [co, cm, cl]
-        in_specs += [
-            pl.BlockSpec((1, block_q, dh), lambda hh, qi, kk: (hh, qi, 0)),
-            pl.BlockSpec((1, block_q, 1), lambda hh, qi, kk: (hh, qi, 0)),
-            pl.BlockSpec((1, block_q, 1), lambda hh, qi, kk: (hh, qi, 0)),
-        ]
-    o_spec = pl.BlockSpec((1, block_q, dh), lambda hh, qi, kk: (hh, qi, 0))
-    o_shape = jax.ShapeDtypeStruct((h, sq, dh), jnp.float32)
-    if emit_carry:
-        s_spec = pl.BlockSpec((1, block_q, 1), lambda hh, qi, kk: (hh, qi, 0))
-        s_shape = jax.ShapeDtypeStruct((h, sq, 1), jnp.float32)
-        out_specs: list | pl.BlockSpec = [o_spec, s_spec, s_spec]
-        out_shape: list | jax.ShapeDtypeStruct = [o_shape, s_shape, s_shape]
-    else:
-        out_specs, out_shape = o_spec, o_shape
-    out = pl.pallas_call(
-        functools.partial(_prefill_kernel, sk_true=sk_true, block_q=block_q,
-                          chunk=chunk, e_acc=e_acc, m_acc=m_acc,
-                          scale=LOG2E / math.sqrt(dh), q_offset=q_offset,
-                          kv_offset=kv_offset, has_carry=has_carry,
-                          emit_carry=emit_carry, rounding=rounding,
-                          sr_seed=sr_seed, h_total=h),
-        grid=grid,
-        in_specs=in_specs,
-        out_specs=out_specs,
-        out_shape=out_shape,
-        scratch_shapes=[
-            pltpu.VMEM((block_q, dh), jnp.float32),  # o carry
-            pltpu.VMEM((block_q, 1), jnp.float32),   # running max (exact)
-            pltpu.VMEM((block_q, 1), jnp.float32),   # l carry
-        ],
-        interpret=interpret,
-    )(*operands)
-    if emit_carry:
-        o, m, l = out
-        return (o.transpose(1, 0, 2)[:s], m[..., 0].T[:s], l[..., 0].T[:s])
-    return out.transpose(1, 0, 2)[:s]
-
-
-@register_kernel("flash_prefill")
-def flash_prefill(
-    q: jnp.ndarray,
-    k: jnp.ndarray,
-    v: jnp.ndarray,
-    *,
-    acc: tuple[int, int] = _WIDE,
-    chunk: int = 128,
-    block_q: int = 128,
-    q_offset: int = 0,
-    kv_offset: int = 0,
-    carry: tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray] | None = None,
-    return_carry: bool = False,
-    call: AttnCall | None = None,
-    interpret: bool | None = None,
-    rounding: str = "rne",
-    sr_seed: int = 0,
-) -> jnp.ndarray | tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
-    """Causal flash attention for one sequence's prefill (resumable).
-
-    * ``q`` (S, H, dh) — query rows at absolute positions ``q_offset + i``;
-      ``k``/``v`` (Sk, KV, dh) — KV rows at absolute positions
-      ``kv_offset + j`` (GQA handled by head repetition).  Values should
-      already carry the KV-cache quantization
-      (``repro.serve.kvcache.write_prompt`` returns the dequantized view)
-      so that later paged decode attends to exactly what prefill attended.
-    * ``acc`` — the (e_acc, m_acc) carry format from the serve planner.
-    * ``chunk`` is the KV block length n1 — numerics (the carry rounding
-      cadence; the serve path pins it to the KV page size so prefill and
-      decode share one accumulation geometry).  ``block_q`` is
-      schedule-only: any choice is bit-identical (each query row's block
-      sequence over KV is fixed), tuned via ``autotune_flash_prefill``.
-    * ``carry`` — a previous call's ``(o, m, l)`` state (shapes (S, H, dh),
-      (S, H), (S, H)) covering KV ``[0, kv_offset)``; ``return_carry=True``
-      returns the raw state after this call's KV instead of the finalized
-      output.  Resuming at a ``chunk`` multiple is bit-identical to the
-      one-shot walk: the o/l carries are representable accumulator-format
-      points and the running max is on the integer lattice, so the HBM
-      round-trip is exact.  Offsets are static (one trace per slab
-      geometry — the serve engine's slab sizes are fixed per plan).
-    * ``call`` — an ``AttnCall`` spec supplying acc/chunk/block_q/offsets
-      in one struct (the same one the autotuner and the serve compile
-      cache key on); explicit kwargs are ignored when it is given.
-    * ``rounding="sr"`` — stochastic rounding of the o/l carries, seeded by
-      ``sr_seed``: deterministic given the seed, block_q/schedule-invariant
-      and resume==one-shot bitwise (the dither is keyed on the ABSOLUTE
-      kv-block index and absolute row/head/feature coordinates, so a
-      resumed walk re-derives the one-shot walk's bits).  Default "rne" is
-      bit-identical to the seed-less kernel.
-    """
-    if rounding not in ROUNDINGS:
-        raise ValueError(f"rounding must be one of {ROUNDINGS}")
-    if call is not None:
-        acc = call.acc
-        chunk = call.chunk
-        block_q = call.resolve_block_q()
-        q_offset = call.q_offset
-        kv_offset = call.kv_offset
-        return_carry = bool(return_carry or call.return_carry)
-    if q.ndim != 3 or k.ndim != 3 or v.ndim != 3 or k.shape != v.shape:
-        raise ValueError(f"bad shapes q{q.shape} k{k.shape} v{v.shape}")
-    if q.shape[1] % k.shape[1] != 0:
-        raise ValueError(f"H={q.shape[1]} not a multiple of KV={k.shape[1]}")
-    if kv_offset % chunk != 0:
-        raise ValueError(
-            f"kv_offset {kv_offset} must be a multiple of chunk {chunk}: a "
-            "mid-block resumption would insert an extra carry-rounding "
-            "event and break bit-exactness vs the one-shot walk")
-    carry_o = carry_m = carry_l = None
-    if carry is not None:
-        carry_o, carry_m, carry_l = carry
-        s, h, dh = q.shape
-        if carry_o.shape != (s, h, dh) or carry_m.shape != (s, h) \
-                or carry_l.shape != (s, h):
-            raise ValueError(
-                f"carry shapes {carry_o.shape}/{carry_m.shape}/"
-                f"{carry_l.shape} do not match q {q.shape}")
-    e_acc, m_acc = acc
-    return _flash_prefill(q, k, v, carry_o, carry_m, carry_l,
-                          e_acc=int(e_acc), m_acc=int(m_acc),
-                          chunk=int(chunk), block_q=int(block_q),
-                          q_offset=int(q_offset), kv_offset=int(kv_offset),
-                          emit_carry=bool(return_carry),
-                          interpret=resolve_interpret(interpret),
-                          rounding=rounding, sr_seed=int(sr_seed))
-
-
-def flash_prefill_reference(q, k, v, *, acc=_WIDE, chunk=128, q_offset=0,
-                            kv_offset=0, carry=None, return_carry=False,
-                            rounding="rne", sr_seed=0):
-    """Unfused jnp oracle for ``flash_prefill``: same chunk walk, same carry
-    rounding, no q blocking (per-row results are block_q-invariant).
-    Mirrors the kernel's resumable-carry contract exactly — including the
-    SR dither coordinates, so kernel and reference agree bitwise in both
-    rounding modes."""
-    s, h, dh = q.shape
-    sk_true = k.shape[0]
-    g = h // k.shape[1]
-    kh = jnp.repeat(k, g, axis=1).astype(jnp.float32).transpose(1, 0, 2)
-    vh = jnp.repeat(v, g, axis=1).astype(jnp.float32).transpose(1, 0, 2)
-    qt = q.astype(jnp.float32).transpose(1, 0, 2)  # (h, s, dh)
-    sk = -(-sk_true // chunk) * chunk
-    kh = jnp.pad(kh, ((0, 0), (0, sk - sk_true), (0, 0)))
-    vh = jnp.pad(vh, ((0, 0), (0, sk - sk_true), (0, 0)))
-    e_acc, m_acc = acc
-    if carry is None:
-        o = jnp.zeros((h, s, dh), jnp.float32)
-        m = jnp.full((h, s, 1), NEG, jnp.float32)
-        l = jnp.zeros((h, s, 1), jnp.float32)
-    else:
-        co, cm, cl = carry
-        o = co.astype(jnp.float32).transpose(1, 0, 2)
-        m = cm.astype(jnp.float32).T[..., None]
-        l = cl.astype(jnp.float32).T[..., None]
-    rows = q_offset + jnp.arange(s)[None, :, None]
-    scale = LOG2E / math.sqrt(dh)
-    for kk in range(sk // chunk):
-        kb = kh[:, kk * chunk:(kk + 1) * chunk]
-        vb = vh[:, kk * chunk:(kk + 1) * chunk]
-        sc = _pv(qt, kb.transpose(0, 2, 1)) * scale  # (h, s, chunk)
-        cols_l = kk * chunk + jnp.arange(chunk)[None, None, :]
-        valid = (kv_offset + cols_l <= rows) & (cols_l < sk_true)
-        sc = jnp.where(valid, sc, NEG)
-        rbits = None
-        if rounding == "sr":
-            rbits = _sr_attn_bits(jnp.uint32(sr_seed),
-                                  kv_offset // chunk + kk,
-                                  abs_row0=q_offset, head0=0,
-                                  block_q=s, dh=dh, h=h,
-                                  shape3=(h, s, dh))
-        o, m, l = _online_update(o, m, l, sc, valid, vb, e_acc, m_acc,
-                                 rounding=rounding, rbits=rbits)
-    if return_carry:
-        return (o.transpose(1, 0, 2), m[..., 0].T, l[..., 0].T)
-    return _finalize(o, l).transpose(1, 0, 2)
 
 
 # --------------------------------------------------------------------------
@@ -1174,8 +821,7 @@ def _flash_prefill_paged(q, k_pages, v_pages, k_se, v_se, page_row, geom,
                  ((0, 0), (0, sq - t), (0, 0)))
     grid = (h, sq // block_q, max_pages)
     # GQA rides the index map: query head hh reads KV head hh // g of the
-    # layer straight from the arena — no repeated HBM copy (the dense
-    # kernel's jnp.repeat)
+    # layer straight from the arena — no repeated HBM copy
     in_specs = [
         pl.BlockSpec((1, block_q, dh),
                      lambda hh, qi, p, pr, gm, ks, vs, ly: (hh, qi, 0)),
@@ -1284,12 +930,12 @@ def flash_prefill_paged(
       (history + slab).  Causality is on absolute positions, so a slab at
       any ``q_offset`` reuses the same executable.
     * ``carry``/``start_page``/``return_carry`` — resumable online-softmax
-      state exactly as in ``flash_prefill``: ``carry`` covers KV pages
+      state (shapes (T, H, dh), (T, H), (T, H)): ``carry`` covers KV pages
       ``[0, start_page)`` and the walk resumes there; the carry
       round-trips exactly (accumulator-format points + integer-lattice
       max), so split-anywhere equals one-shot bit-for-bit.
     * ``acc``/``block_q``/``call`` — carry format and the schedule-only q
-      tile; ``call`` (an ``AttnCall`` with ``max_pages > 0``) supplies
+      tile; ``call`` (the bucket's ``AttnCall``) supplies
       acc/block_q/kv_fmt from the one struct the serve compile cache and
       autotuner share.
 
@@ -1300,7 +946,7 @@ def flash_prefill_paged(
         block_q = call.resolve_block_q()
         kv_fmt = call.kv_fmt
         return_carry = bool(return_carry or call.return_carry)
-        if call.max_pages and page_row.shape[0] != call.max_pages:
+        if page_row.shape[0] != call.max_pages:
             raise ValueError(
                 f"page_row width {page_row.shape[0]} != bucket max_pages "
                 f"{call.max_pages}")

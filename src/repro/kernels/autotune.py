@@ -311,10 +311,8 @@ def attn_vmem_bytes(block_q: int, chunk: int, dh: int,
                     *, kv_bytes: int = 4) -> int:
     """VMEM working set of one flash-attention grid step: q/out tiles, the
     K/V block, the o-carry scratch and the (block_q, 1) max/l rows.
-    ``kv_bytes`` prices the K/V block carrier — 4 for ``flash_prefill``
-    (its tiles are f32: prefill consumes the dequantized view so it
-    attends to exactly what the pages hold), 1 for the decode kernel's
-    in-VMEM-unpacked int8 pages."""
+    ``kv_bytes`` prices the K/V block carrier — 1 for int8 pages unpacked
+    in VMEM, 4 for f32 carrier pages."""
     return (4 * block_q * dh            # q tile
             + 2 * kv_bytes * chunk * dh  # k + v block
             + 4 * block_q * dh           # out tile
@@ -345,60 +343,47 @@ class AttnCall:
     executor's compile cache and the planner cannot drift apart — the old
     arrangement kept three hand-maintained tuples in sync.
 
-    ``max_pages > 0`` selects the bucketed paged kernel
+    It describes a call of the bucketed paged prefill kernel
     (``flash_prefill_paged``): geometry scalars ride in as traced
-    scalar-prefetch operands and the page row is padded to ``max_pages``,
-    so every slab of every prompt in the bucket shares one compiled
-    kernel.  ``max_pages == 0`` describes the dense ``flash_prefill``
-    call, where ``q_offset``/``kv_offset`` are jit-static.
+    scalar-prefetch operands and the page row is padded to ``max_pages``
+    (required, > 0), so every slab of every prompt in the bucket shares one
+    compiled kernel.
     """
 
     s: int                    # query tokens per call (slab width, padded)
     h: int                    # query heads
     dh: int                   # head dim
     chunk: int                # carry rounding cadence (== KV page size)
+    max_pages: int            # padded page-row width of the bucket
     e_acc: int = 8
     m_acc: int = 23
     kv_fmt: Any = None        # packed KV representation format, or None
     kv_heads: int = 0         # KV heads; 0 means h (no GQA)
-    max_pages: int = 0        # padded page-row width; 0 = dense kernel
     block_q: int = 0          # explicit override; 0 = consult the table
-    q_offset: int = 0         # dense kernel only (static); paged: traced
-    kv_offset: int = 0        # dense kernel only (static); paged: traced
-    has_carry: bool = False
     return_carry: bool = False
     dtype: str = "f32"
 
     def __post_init__(self):
+        if self.max_pages <= 0:
+            raise ValueError(f"max_pages must be > 0, got {self.max_pages}")
         object.__setattr__(self, "kv_fmt", fmt_tuple(self.kv_fmt))
 
     @property
     def acc(self) -> tuple[int, int]:
         return (self.e_acc, self.m_acc)
 
-    @property
-    def paged(self) -> bool:
-        return self.max_pages > 0
-
     def table_key(self, vmem: int | None = None) -> str:
-        """Tuning-table key.  Paged calls append ``:p{max_pages}`` so the
-        dense entries written by earlier releases keep resolving."""
+        """Tuning-table key: the attention key plus ``:p{max_pages}``."""
         key = _attn_key(self.s, self.h, self.dh, self.chunk, self.e_acc,
                         self.m_acc, self.kv_fmt, dtype=self.dtype, vmem=vmem)
-        return f"{key}:p{self.max_pages}" if self.paged else key
+        return f"{key}:p{self.max_pages}"
 
     def resolve_block_q(self, vmem: int | None = None) -> int:
         """block_q is the only schedule-only knob: explicit override, else
-        the tuned entry (paged key first, dense key as fallback — the tile
-        working set is the same), else the safe default 128."""
+        the tuned entry, else the safe default 128."""
         if self.block_q:
             return int(self.block_q)
-        table = get_table()
-        e = table.get_key(self.table_key(vmem=vmem))
-        if e is None and self.paged:
-            e = table.get_key(_attn_key(self.s, self.h, self.dh, self.chunk,
-                                        self.e_acc, self.m_acc, self.kv_fmt,
-                                        dtype=self.dtype, vmem=vmem))
+        e = get_table().get_key(self.table_key(vmem=vmem))
         return int(e["block_q"]) if e is not None else 128
 
     def static_signature(self) -> tuple:
@@ -406,8 +391,7 @@ class AttnCall:
         with equal signatures hit the same XLA executable."""
         return (self.s, self.h, self.dh, self.chunk, self.e_acc, self.m_acc,
                 self.kv_fmt, self.kv_heads, self.max_pages,
-                self.resolve_block_q(), self.q_offset, self.kv_offset,
-                self.has_carry, self.return_carry, self.dtype)
+                self.resolve_block_q(), self.return_carry, self.dtype)
 
 
 class TuningTable:
@@ -534,16 +518,15 @@ def pair_blocks_for(t: int, k: int, n: int, *, bwd_chunk: int = 0,
     return (bt, bk, bn)
 
 
-def attn_blocks_for(s: int, h: int, dh: int, chunk: int, *, e_acc: int = 8,
-                    m_acc: int = 23, kv_fmt=None, dtype: str = "f32",
-                    vmem: int | None = None, max_pages: int = 0) -> int:
-    """Trace-time consult for the prefill kernels' block_q (the only
+def attn_blocks_for(s: int, h: int, dh: int, chunk: int, *, max_pages: int,
+                    e_acc: int = 8, m_acc: int = 23, kv_fmt=None,
+                    dtype: str = "f32", vmem: int | None = None) -> int:
+    """Trace-time consult for the prefill kernel's block_q (the only
     schedule-only knob: ``chunk`` is the carry rounding cadence — numerics,
     pinned to the KV page size by the serve path — and the decode kernel's
-    grid is fixed by the page geometry outright).  ``max_pages > 0``
-    consults the paged-kernel key, falling back to the dense one."""
-    return AttnCall(s, h, dh, chunk, e_acc=e_acc, m_acc=m_acc, kv_fmt=kv_fmt,
-                    max_pages=max_pages, dtype=dtype).resolve_block_q(vmem)
+    grid is fixed by the page geometry outright)."""
+    return AttnCall(s, h, dh, chunk, max_pages, e_acc=e_acc, m_acc=m_acc,
+                    kv_fmt=kv_fmt, dtype=dtype).resolve_block_q(vmem)
 
 
 # --------------------------------------------------------------------------
@@ -733,15 +716,8 @@ def autotune_bwd_pair(
 
 
 def autotune_flash_prefill(
-    s: int = 0,
-    h: int = 0,
-    dh: int = 0,
+    call: AttnCall,
     *,
-    chunk: int = 0,
-    e_acc: int = 8,
-    m_acc: int = 23,
-    kv_fmt: Any = None,
-    call: "AttnCall | None" = None,
     vmem: int | None = None,
     reps: int = 2,
     seed: int = 0,
@@ -749,19 +725,15 @@ def autotune_flash_prefill(
     persist: bool = True,
     verbose: bool = False,
 ) -> dict:
-    """Tune the prefill kernel's block_q for one (prompt, heads, head_dim)
-    geometry (``chunk`` is the carry cadence — numerics, never swept) and
-    record the winner under an ``attn:`` key in the shared tuning table.
-
-    Pass ``call=AttnCall(...)`` to tune from the same spec the executor
-    compiles against; a paged call (``max_pages > 0``) times the bucketed
-    ``flash_prefill_paged`` over a dummy page arena and records under the
-    paged ``:p{max_pages}`` key."""
+    """Tune the prefill kernel's block_q for one bucket's ``AttnCall`` (the
+    same spec the executor compiles against; ``chunk`` is the carry cadence
+    — numerics, never swept): time ``flash_prefill_paged`` over a dummy
+    page arena and record the winner under the call's ``attn:…:p{max_pages}``
+    key in the shared tuning table."""
     import jax.numpy as jnp
 
-    if call is None:
-        call = AttnCall(s, h, dh, chunk, e_acc=e_acc, m_acc=m_acc,
-                        kv_fmt=kv_fmt)
+    from repro.kernels.attention import flash_prefill_paged  # late: cycle
+
     s, h, dh, chunk = call.s, call.h, call.dh, call.chunk
     budget = vmem if vmem is not None else vmem_budget()
     key_str = call.table_key(vmem=budget)
@@ -770,7 +742,7 @@ def autotune_flash_prefill(
     if cached is not None and cached.get("reps", 0) >= reps:
         return cached
 
-    kv_bytes = 1 if (call.paged and call.kv_fmt is not None) else 4
+    kv_bytes = 1 if call.kv_fmt is not None else 4
     sp = max(-(-s // 128) * 128, 128)
     cands = [bq for bq in _TILE_EDGES
              if bq <= sp and attn_vmem_bytes(bq, chunk, dh,
@@ -780,50 +752,30 @@ def autotune_flash_prefill(
     rk = jax.random.PRNGKey(seed)
     kq, kk, kv_ = jax.random.split(rk, 3)
     q = jax.random.normal(kq, (s, h, dh), jnp.float32)
-    if call.paged:
-        from repro.kernels.attention import flash_prefill_paged  # late
-
-        kvh = call.kv_heads or h
-        page = chunk
-        n_pg = call.max_pages
-        if call.kv_fmt is not None:
-            kp = jax.random.randint(kk, (n_pg, kvh, page, dh), -63, 64,
-                                    jnp.int8)
-            vp = jax.random.randint(kv_, (n_pg, kvh, page, dh), -63, 64,
-                                    jnp.int8)
-        else:
-            kp = jax.random.normal(kk, (n_pg, kvh, page, dh), jnp.float32)
-            vp = jax.random.normal(kv_, (n_pg, kvh, page, dh), jnp.float32)
-        se = jnp.zeros((n_pg,), jnp.int32)
-        row = jnp.arange(n_pg, dtype=jnp.int32)
-        kv_len = jnp.int32(min(s, n_pg * page))
-
-        def make_run(bq):
-            def run(q, kp, vp):
-                c = dataclasses.replace(call, block_q=bq)
-                return flash_prefill_paged(
-                    q, kp, vp, se, se, row, jnp.int32(0), jnp.int32(s),
-                    kv_len, call=c)
-            return run
-
-        operands = (q, kp, vp)
+    kvh = call.kv_heads or h
+    n_pg = call.max_pages
+    if call.kv_fmt is not None:
+        kp = jax.random.randint(kk, (n_pg, kvh, chunk, dh), -63, 64, jnp.int8)
+        vp = jax.random.randint(kv_, (n_pg, kvh, chunk, dh), -63, 64,
+                                jnp.int8)
     else:
-        from repro.kernels.attention import flash_prefill  # late: cycle
+        kp = jax.random.normal(kk, (n_pg, kvh, chunk, dh), jnp.float32)
+        vp = jax.random.normal(kv_, (n_pg, kvh, chunk, dh), jnp.float32)
+    se = jnp.zeros((n_pg,), jnp.int32)
+    row = jnp.arange(n_pg, dtype=jnp.int32)
+    kv_len = jnp.int32(min(s, n_pg * chunk))
 
-        k = jax.random.normal(kk, (s, h, dh), jnp.float32)
-        v = jax.random.normal(kv_, (s, h, dh), jnp.float32)
-
-        def make_run(bq):
-            def run(q, k, v):
-                return flash_prefill(q, k, v, acc=call.acc, chunk=chunk,
-                                     block_q=bq)
-            return run
-
-        operands = (q, k, v)
+    def make_run(bq):
+        def run(q, kp, vp):
+            c = dataclasses.replace(call, block_q=bq)
+            return flash_prefill_paged(
+                q, kp, vp, se, se, row, jnp.int32(0), jnp.int32(s),
+                kv_len, call=c)
+        return run
 
     best: tuple[float, int] | None = None
     for bq in cands:
-        us = time_kernel(make_run(bq), *operands, reps=reps)
+        us = time_kernel(make_run(bq), q, kp, vp, reps=reps)
         if verbose:
             print(f"  autotune attn {s}x{h}x{dh} c{chunk}: bq={bq} -> {us:.0f}us")
         if best is None or us < best[0]:

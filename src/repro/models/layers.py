@@ -540,110 +540,6 @@ def attn_verify_paged(
     return (y if ax is None else _gather_cols(y, dist)), new_kv
 
 
-def attn_prefill_paged(
-    p: Params,
-    x: jnp.ndarray,
-    kv: dict[str, jnp.ndarray],
-    page_ids: jnp.ndarray,
-    positions: jnp.ndarray,
-    cfg: ModelConfig,
-    dist: Dist,
-    *,
-    kv_fmt,
-    acc: tuple[int, int],
-    block_q: int | None = None,
-) -> tuple[jnp.ndarray, dict[str, jnp.ndarray]]:
-    """Causal prefill of ONE sequence through a layer, writing its K/V into
-    the paged arena and attending (flash kernel, page-size chunked carry)
-    to the exact quantized values the cache now holds — decode later sees
-    the same history prefill saw.  ``x`` (1, S, D); ``page_ids``
-    (n_pages,)."""
-    from repro.kernels.attention import flash_prefill
-    from repro.kernels.autotune import attn_blocks_for
-    from repro.serve import kvcache as KV
-
-    s = x.shape[1]
-    q = _q_proj(p, x, cfg, positions)  # (1, S, H, dh)
-    k, v = _kv_proj(p, x, cfg, positions)
-    kk, kse, kdq = KV.write_prompt(kv["k"], kv["k_se"],
-                                   k[0].astype(jnp.float32), page_ids, kv_fmt)
-    vv, vse, vdq = KV.write_prompt(kv["v"], kv["v_se"],
-                                   v[0].astype(jnp.float32), page_ids, kv_fmt)
-    page_size = kv["k"].shape[2]
-    if block_q is None:
-        block_q = attn_blocks_for(s, cfg.n_heads, cfg.head_dim, page_size,
-                                  e_acc=acc[0], m_acc=acc[1], kv_fmt=kv_fmt)
-    o = flash_prefill(q[0].astype(jnp.float32), kdq, vdq, acc=acc,
-                      chunk=page_size, block_q=block_q)
-    o = o.reshape(1, s, -1).astype(COMPUTE_DTYPE)
-    new_kv = {"k": kk, "v": vv, "k_se": kse, "v_se": vse}
-    return dense(o, p["wo"], cfg.quant.attn_out), new_kv
-
-
-def attn_prefill_chunk_paged(
-    p: Params,
-    x: jnp.ndarray,
-    kv: dict[str, jnp.ndarray],
-    hist_page_ids: jnp.ndarray,
-    slab_page_ids: jnp.ndarray,
-    t0: int,
-    cfg: ModelConfig,
-    dist: Dist,
-    *,
-    kv_fmt,
-    acc: tuple[int, int],
-    block_q: int | None = None,
-) -> tuple[jnp.ndarray, dict[str, jnp.ndarray]]:
-    """One chunked-prefill slab of ONE sequence through a layer.
-
-    ``x`` (1, T, D) is the slab's hidden states (absolute token positions
-    ``t0 + i``; ``t0`` must be page-aligned so slab pages are whole pages
-    and the carry hand-off lands on a block edge).  The slab's K/V are
-    quantized into ``slab_page_ids`` exactly as a one-shot
-    ``attn_prefill_paged`` would have (same per-page scale grouping), then
-    the slab's queries attend their page history via the resumable flash
-    kernel: a carry-out pass over the dequantized ``hist_page_ids`` view
-    (all of it causally visible — the slab starts at ``t0``), resumed by a
-    carry-in causal pass over the slab's own KV.  Per query row this walks
-    the same page-size blocks in the same order with the same carry
-    rounding as a one-shot prefill of the whole prompt — bit-identical
-    outputs, arena and (eventually) decode stream."""
-    from repro.kernels.attention import flash_prefill
-    from repro.kernels.autotune import attn_blocks_for
-    from repro.serve import kvcache as KV
-
-    s = x.shape[1]
-    page_size = kv["k"].shape[2]
-    if t0 % page_size != 0:
-        raise ValueError(f"slab offset {t0} not page-aligned ({page_size})")
-    positions = (t0 + jnp.arange(s, dtype=jnp.int32))[None]
-    q = _q_proj(p, x, cfg, positions)  # (1, T, H, dh)
-    k, v = _kv_proj(p, x, cfg, positions)
-    kk, kse, kdq = KV.write_prompt(kv["k"], kv["k_se"],
-                                   k[0].astype(jnp.float32), slab_page_ids,
-                                   kv_fmt)
-    vv, vse, vdq = KV.write_prompt(kv["v"], kv["v_se"],
-                                   v[0].astype(jnp.float32), slab_page_ids,
-                                   kv_fmt)
-    if block_q is None:
-        block_q = attn_blocks_for(s, cfg.n_heads, cfg.head_dim, page_size,
-                                  e_acc=acc[0], m_acc=acc[1], kv_fmt=kv_fmt)
-    qf = q[0].astype(jnp.float32)
-    carry = None
-    if t0 > 0:
-        kh = KV.gather_pages(kk, kse, hist_page_ids, kv_fmt)  # (t0, KV, dh)
-        vh = KV.gather_pages(vv, vse, hist_page_ids, kv_fmt)
-        carry = flash_prefill(qf, kh[:t0], vh[:t0], acc=acc,
-                              chunk=page_size, block_q=block_q,
-                              q_offset=t0, return_carry=True)
-    o = flash_prefill(qf, kdq, vdq, acc=acc, chunk=page_size,
-                      block_q=block_q, q_offset=t0, kv_offset=t0,
-                      carry=carry)
-    o = o.reshape(1, s, -1).astype(COMPUTE_DTYPE)
-    new_kv = {"k": kk, "v": vv, "k_se": kse, "v_se": vse}
-    return dense(o, p["wo"], cfg.quant.attn_out), new_kv
-
-
 def attn_prefill_bucketed(
     p: Params,
     x: jnp.ndarray,
@@ -662,10 +558,10 @@ def attn_prefill_bucketed(
     call=None,
 ) -> tuple[jnp.ndarray, dict[str, jnp.ndarray]]:
     """One bucket-shaped prefill slab of ONE sequence through layer
-    ``layer`` — the single-compile replacement for the history-gather +
-    two-call ``attn_prefill_chunk_paged`` walk.  ``kv`` is the whole arena:
-    the slab's pages are written at ``[layer, page]`` and the kernel reads
-    the layer in place.
+    ``layer``: the slab's K/V are written into the paged arena and its
+    queries attend history and slab in one ``flash_prefill_paged`` call.
+    ``kv`` is the whole arena: the slab's pages are written at ``[layer,
+    page]`` and the kernel reads the layer in place.
 
     ``x`` (1, T, D) is the slab padded to the bucket's fixed slab width;
     ``q_offset``/``q_len`` are TRACED int32 scalars (absolute start, live
@@ -700,10 +596,10 @@ def attn_prefill_bucketed(
     kf = jnp.where(live, k[0].astype(jnp.float32), 0.0)
     vf = jnp.where(live, v[0].astype(jnp.float32), 0.0)
     ax = dist.shard_axis
-    kk, kse, _ = KV.write_prompt(kv["k"], kv["k_se"], kf, slab_page_ids,
-                                 kv_fmt, pmax_axis=ax, layer=layer)
-    vv, vse, _ = KV.write_prompt(kv["v"], kv["v_se"], vf, slab_page_ids,
-                                 kv_fmt, pmax_axis=ax, layer=layer)
+    kk, kse = KV.write_prompt(kv["k"], kv["k_se"], kf, slab_page_ids,
+                              kv_fmt, pmax_axis=ax, layer=layer)
+    vv, vse = KV.write_prompt(kv["v"], kv["v_se"], vf, slab_page_ids,
+                              kv_fmt, pmax_axis=ax, layer=layer)
     h_here = q.shape[2]  # local heads under tensor-parallel shard_map
     if call is None and block_q is None:
         block_q = attn_blocks_for(t, h_here, cfg.head_dim, page_size,

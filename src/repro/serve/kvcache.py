@@ -58,7 +58,6 @@ __all__ = [
     "init_arena",
     "append_token",
     "write_prompt",
-    "gather_pages",
     "dequantize_pages",
     "swap_out_pages",
     "swap_in_pages",
@@ -172,7 +171,7 @@ def append_token(arena: jnp.ndarray, se: jnp.ndarray, x: jnp.ndarray,
 def write_prompt(arena: jnp.ndarray, se: jnp.ndarray, x: jnp.ndarray,
                  page_ids: jnp.ndarray, fmt: FPFormat,
                  pmax_axis: str | None = None, *, layer=None,
-                 ) -> tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
+                 ) -> tuple[jnp.ndarray, jnp.ndarray]:
     """Write one sequence's prompt K (or V) of layer ``layer`` into the
     arena, in place: one scatter of whole pages at ``[layer, page_ids]``
     into ``arena`` (L, P, KV, page_size, dh) and one of their scale
@@ -182,9 +181,8 @@ def write_prompt(arena: jnp.ndarray, se: jnp.ndarray, x: jnp.ndarray,
     ``x`` (S, KV, dh) f32; ``page_ids`` (n_pages,) int32 with
     ``n_pages * page_size >= S`` (the tail page is zero-padded; code 0
     decodes to 0.0 and padded tokens are masked out of attention anyway).
-    Returns ``(arena, se, dequant)`` where ``dequant`` (S, KV, dh) is
-    the exact values the cache now holds — prefill attends to THESE, so
-    later paged decode sees the same history prefill saw.
+    Returns ``(arena, se)``.  Prefill and decode both attend to the pages
+    themselves, so decode sees exactly the history prefill saw.
 
     ``pmax_axis``: see ``append_token`` — the per-page amax is shared over
     the mesh axis so KV-head-sharded writes fix the same scale exponents
@@ -204,23 +202,7 @@ def write_prompt(arena: jnp.ndarray, se: jnp.ndarray, x: jnp.ndarray,
     codes = _encode(blocks, page_se[:, None, None, None], fmt)
     arena = arena.at[at + (page_ids,)].set(codes)
     se = se.at[at + (page_ids,)].set(page_se)
-    deq = _decode(codes, page_se[:, None, None, None], fmt)
-    deq = deq.transpose(0, 2, 1, 3).reshape(npg * page_size, kv, dh)[:s]
-    return arena, se, deq
-
-
-def gather_pages(arena_l: jnp.ndarray, se_l: jnp.ndarray,
-                 page_ids: jnp.ndarray, fmt: FPFormat) -> jnp.ndarray:
-    """Dequantized token-major view of one sequence's pages in a layer:
-    (len(page_ids) * page_size, KV, dh) f32 — exactly the values
-    ``write_prompt`` returned when the pages were written (same codes, same
-    per-page scale exponents).  Chunked prefill attends its history through
-    this view, so a resumed slab sees bit-identically what a one-shot
-    prefill over the whole prompt would have seen."""
-    codes = arena_l[page_ids]  # (n, KV, page_size, dh)
-    deq = _decode(codes, se_l[page_ids][:, None, None, None], fmt)
-    n, kv, page_size, dh = deq.shape
-    return deq.transpose(0, 2, 1, 3).reshape(n * page_size, kv, dh)
+    return arena, se
 
 
 def dequantize_pages(arena_l: jnp.ndarray, se_l: jnp.ndarray,

@@ -9,9 +9,9 @@ levers that keep utilization up under bursty, mixed-length arrivals:
 * **chunked prefill** — a prompt is prefilled in ``prefill_chunk_tokens``
   slabs (page-aligned), ONE slab per engine step, interleaved with the
   batched decode of every running sequence — a long prompt no longer
-  blocks the decode batch for a full step per prompt.  Each slab runs the
-  resumable-carry ``flash_prefill`` (history carry-out pass over the
-  sequence's pages, causal carry-in pass over the slab), which is
+  blocks the decode batch for a full step per prompt.  Each slab writes
+  its K/V into the sequence's pages, then one ``flash_prefill_paged`` call
+  walks history and slab off the arena in page order, which is
   bit-identical to the one-shot prefill at every split point — the
   numerics are scheduling-invariant by construction.
 * **optimistic admission + preemption/swap** — admission asks only for the

@@ -244,3 +244,29 @@ def test_autotune_bwd_pair_roundtrip(tmp_table):
         64, 256, 64, bwd_chunk=64, grad_chunk=64, bwd_acc=(6, 5),
         grad_acc=(6, 8), repr_fmt=(5, 2), packed=True, reps=1)
     assert again == entry
+
+
+def test_attn_call_needs_a_page_row():
+    # an AttnCall describes the paged prefill kernel only: a bucket's
+    # padded page-row width is required and positive
+    with pytest.raises(ValueError, match="max_pages"):
+        autotune.AttnCall(8, 4, 16, 4, 0)
+    call = autotune.AttnCall(8, 4, 16, 4, 3, e_acc=6, m_acc=7,
+                             kv_fmt=(5, 2))
+    assert call.table_key().startswith("attn:8x4x16:c4:acc6.7:r5.2:")
+    assert call.table_key().endswith(":p3")
+
+
+def test_autotune_flash_prefill_roundtrip(tmp_table):
+    # the prefill tuner times the paged kernel and records under the
+    # call's :p{max_pages} key, which the trace-time consult then reads
+    call = autotune.AttnCall(8, 4, 16, 4, 3, e_acc=6, m_acc=7,
+                             kv_fmt=(5, 2), kv_heads=2)
+    entry = autotune.autotune_flash_prefill(call, reps=1)
+    assert tmp_table.get_key(call.table_key()) == entry
+    assert autotune.attn_blocks_for(
+        8, 4, 16, 4, max_pages=3, e_acc=6, m_acc=7, kv_fmt=(5, 2)
+    ) == entry["block_q"]
+    # another bucket width is another key: untuned default
+    assert autotune.attn_blocks_for(
+        8, 4, 16, 4, max_pages=4, e_acc=6, m_acc=7, kv_fmt=(5, 2)) == 128

@@ -10,7 +10,8 @@ slab of every prompt in a bucket.  Pinned here at three levels:
 
 * kernel — 20+ randomized (t0, q_len) slab geometries, aligned and
   ragged, through ``flash_prefill_paged`` cost exactly ONE trace and
-  each matches the dense one-shot kernel bit-for-bit;
+  each matches the one-shot walk of its jnp oracle
+  (``flash_prefill_paged_reference``) bit-for-bit;
 * engine — a warmed ``ServeEngine`` serves randomized traffic including
   ragged tails and post-preemption restores with ZERO steady-state
   compiles (the serve bench gates the same number in CI);
@@ -31,8 +32,8 @@ import pytest
 from repro.configs import get_smoke_config
 from repro.kernels.attention import (
     counting_traces,
-    flash_prefill,
     flash_prefill_paged,
+    flash_prefill_paged_reference,
 )
 from repro.models.api import get_model
 from repro.serve import plan as P
@@ -56,8 +57,8 @@ def smoke_model():
 def test_one_trace_serves_all_slab_geometries():
     """20+ randomized (t0, q_len) geometries — page-aligned offsets,
     ragged tails, single-row slabs — through ONE (bucket-width, slab-width)
-    signature: exactly one trace, every output bit-equal to the dense
-    one-shot kernel over the same prefix."""
+    signature: exactly one trace, every output bit-equal to the oracle's
+    one-shot walk over the same prefix."""
     chunk, W, T = 4, 6, 8          # page size, bucket page width, slab width
     h, kv, dh = 4, 2, 8
     max_ctx = W * chunk
@@ -84,8 +85,8 @@ def test_one_trace_serves_all_slab_geometries():
             out = flash_prefill_paged(
                 qs, kp, vp, se, se, row, jnp.int32(t0), jnp.int32(q_len),
                 jnp.int32(kv_len), kv_fmt=None, acc=ACC, block_q=T)
-            one = flash_prefill(q[:kv_len], k[:kv_len], v[:kv_len], acc=ACC,
-                                chunk=chunk, block_q=T)
+            one = flash_prefill_paged_reference(
+                q[:kv_len], kp, vp, se, se, row, 0, kv_len, kv_len, acc=ACC)
             np.testing.assert_array_equal(np.asarray(out[:q_len]),
                                           np.asarray(one[t0:]))
             assert np.all(np.asarray(out[q_len:]) == 0.0), (t0, q_len)

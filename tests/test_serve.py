@@ -15,10 +15,10 @@ import pytest
 
 from repro.configs import get_smoke_config
 from repro.core.vrr import CUTOFF_LOG_V
-from repro.kernels.common import N_STATS
+from repro.kernels.common import N_STATS, quantize_block
 from repro.kernels.attention import (
-    flash_prefill,
-    flash_prefill_reference,
+    flash_prefill_paged,
+    flash_prefill_paged_reference,
     paged_attn_decode,
     paged_attn_decode_reference,
 )
@@ -57,8 +57,8 @@ def _filled_arena(rng, *, kv=2, dh=16, n_pages=10, page_size=4,
     if len(pages):
         k, v = (jnp.asarray(np.concatenate(x).astype(np.float32))
                 for x in (ks, vs))
-        ka, kse, _ = KV.write_prompt(ka, kse, k, pages, fmt)
-        va, vse, _ = KV.write_prompt(va, vse, v, pages, fmt)
+        ka, kse = KV.write_prompt(ka, kse, k, pages, fmt)
+        va, vse = KV.write_prompt(va, vse, v, pages, fmt)
     width = width or max(len(r) for r in rows)
     pt = np.zeros((len(rows), width), np.int32)
     for i, r in enumerate(rows):
@@ -159,30 +159,74 @@ def test_paged_decode_inactive_row_and_stats_neutrality():
 # prefill kernel
 # --------------------------------------------------------------------------
 
+# the prefill tests' page size (== the carry cadence) and heads: 4 query
+# heads over 2 KV heads, so GQA is always on
+_PS, _H, _KV, _DH = 4, 4, 2, 16
 
+
+def _prefill_qkv(seed, s):
+    rng = np.random.RandomState(seed)
+    q, k, v = (jnp.asarray(rng.standard_normal((s, n, _DH))
+                           .astype(np.float32)) for n in (_H, _KV, _KV))
+    return q, k, v
+
+
+def _prefill_arena(k, v, row, packed, n_pages=12):
+    """One-layer arena holding ``k``/``v`` (S, KV, dh) on the pages of
+    ``row`` in token order: int8 (1,5,2) pages written by ``write_prompt``,
+    or f32 carrier pages (scale exponents 0).  Returns the kernel's arena
+    operands and its ``kv_fmt``."""
+    pages = jnp.asarray(row, jnp.int32)
+    se = jnp.zeros((n_pages,), jnp.int32)
+    if packed:
+        zero = jnp.zeros((n_pages, _KV, _PS, _DH), jnp.int8)
+        kp, kse = KV.write_prompt(zero, se, k, pages, FP8_152)
+        vp, vse = KV.write_prompt(zero, se, v, pages, FP8_152)
+        return (kp, vp, kse, vse), FP8_152
+    s = k.shape[0]
+
+    def blocks(x):
+        x = jnp.pad(x, ((0, len(row) * _PS - s), (0, 0), (0, 0)))
+        return x.reshape(len(row), _PS, _KV, _DH).transpose(0, 2, 1, 3)
+
+    zero = jnp.zeros((n_pages, _KV, _PS, _DH), jnp.float32)
+    return (zero.at[pages].set(blocks(k)), zero.at[pages].set(blocks(v)),
+            se, se), None
+
+
+def _prefill_row(s, width=None):
+    """Non-contiguous pages in token order, padded with the null page."""
+    n = -(-s // _PS)
+    row = [11 - 2 * i for i in range(n)]
+    return row, jnp.asarray(row + [0] * ((width or n + 1) - n), jnp.int32)
+
+
+@pytest.mark.parametrize("packed", [False, True], ids=["f32", "int8"])
 @pytest.mark.parametrize("s", [5, 8, 13])
 @pytest.mark.parametrize("acc", [(8, 23), ACC])
-def test_flash_prefill_bitexact_and_blockq_invariant(s, acc):
-    rng = np.random.RandomState(3)
-    q = jnp.asarray(rng.standard_normal((s, 4, 16)).astype(np.float32))
-    k = jnp.asarray(rng.standard_normal((s, 2, 16)).astype(np.float32))
-    v = jnp.asarray(rng.standard_normal((s, 2, 16)).astype(np.float32))
-    ref = flash_prefill_reference(q, k, v, acc=acc, chunk=4)
+def test_flash_prefill_bitexact_and_blockq_invariant(s, acc, packed):
+    """The served prefill kernel equals its jnp oracle bit for bit, at
+    every ``block_q``, over f32 and int8-packed pages."""
+    q, k, v = _prefill_qkv(3, s)
+    pages, row = _prefill_row(s)
+    arena, fmt = _prefill_arena(k, v, pages, packed)
+    args = (q, *arena, row, 0, s, s)
+    ref = flash_prefill_paged_reference(*args, kv_fmt=fmt, acc=acc)
     for bq in (4, 8):
-        out = flash_prefill(q, k, v, acc=acc, chunk=4, block_q=bq)
+        out = flash_prefill_paged(*args, kv_fmt=fmt, acc=acc, block_q=bq)
         np.testing.assert_array_equal(np.asarray(out), np.asarray(ref))
 
 
 def test_flash_prefill_matches_plain_softmax_when_wide():
-    rng = np.random.RandomState(4)
-    s, h, kv, dh = 11, 4, 2, 16
-    q = jnp.asarray(rng.standard_normal((s, h, dh)).astype(np.float32))
-    k = jnp.asarray(rng.standard_normal((s, kv, dh)).astype(np.float32))
-    v = jnp.asarray(rng.standard_normal((s, kv, dh)).astype(np.float32))
-    out = flash_prefill(q, k, v, acc=(8, 23), chunk=4, block_q=8)
-    kh = jnp.repeat(k, h // kv, axis=1)
-    vh = jnp.repeat(v, h // kv, axis=1)
-    sc = jnp.einsum("shd,thd->hst", q, kh) / np.sqrt(dh)
+    s = 11
+    q, k, v = _prefill_qkv(4, s)
+    pages, row = _prefill_row(s)
+    arena, _ = _prefill_arena(k, v, pages, packed=False)
+    out = flash_prefill_paged(q, *arena, row, 0, s, s, acc=(8, 23),
+                              block_q=8)
+    kh = jnp.repeat(k, _H // _KV, axis=1)
+    vh = jnp.repeat(v, _H // _KV, axis=1)
+    sc = jnp.einsum("shd,thd->hst", q, kh) / np.sqrt(_DH)
     sc = jnp.where(jnp.tril(jnp.ones((s, s), bool))[None], sc, -jnp.inf)
     ref = jnp.einsum("hst,thd->shd", jax.nn.softmax(sc, axis=-1), vh)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
@@ -202,64 +246,86 @@ def test_flash_prefill_resumable_carry_bitexact(s, acc):
     unfused oracle — the carry is exact through HBM because o/l are
     representable accumulator-format points and the running max is on the
     integer lattice."""
-    chunk = 4
-    rng = np.random.RandomState(11)
-    q = jnp.asarray(rng.standard_normal((s, 4, 16)).astype(np.float32))
-    k = jnp.asarray(rng.standard_normal((s, 2, 16)).astype(np.float32))
-    v = jnp.asarray(rng.standard_normal((s, 2, 16)).astype(np.float32))
-    one = flash_prefill(q, k, v, acc=acc, chunk=chunk, block_q=8)
-    ref = flash_prefill_reference(q, k, v, acc=acc, chunk=chunk)
+    q, k, v = _prefill_qkv(11, s)
+    pages, row = _prefill_row(s)
+    arena, fmt = _prefill_arena(k, v, pages, packed=True)
+    kw = dict(kv_fmt=fmt, acc=acc)
+    one = flash_prefill_paged(q, *arena, row, 0, s, s, block_q=8, **kw)
+    ref = flash_prefill_paged_reference(q, *arena, row, 0, s, s, **kw)
     np.testing.assert_array_equal(np.asarray(one), np.asarray(ref))
-    for split in range(chunk, s, chunk):
-        c = flash_prefill(q, k[:split], v[:split], acc=acc, chunk=chunk,
-                          block_q=8, return_carry=True)
-        out = flash_prefill(q, k[split:], v[split:], acc=acc, chunk=chunk,
-                            block_q=8, kv_offset=split, carry=c)
-        np.testing.assert_array_equal(np.asarray(out), np.asarray(one))
-        cr = flash_prefill_reference(q, k[:split], v[:split], acc=acc,
-                                     chunk=chunk, return_carry=True)
-        outr = flash_prefill_reference(q, k[split:], v[split:], acc=acc,
-                                       chunk=chunk, kv_offset=split,
-                                       carry=cr)
-        np.testing.assert_array_equal(np.asarray(outr), np.asarray(one))
+    for split in range(1, len(pages)):
+        for walk, bq in ((flash_prefill_paged, dict(block_q=8)),
+                         (flash_prefill_paged_reference, {})):
+            c = walk(q, *arena, row, 0, s, split * _PS, return_carry=True,
+                     **bq, **kw)
+            out = walk(q, *arena, row, 0, s, s, carry=c, start_page=split,
+                       **bq, **kw)
+            np.testing.assert_array_equal(np.asarray(out), np.asarray(one),
+                                          err_msg=f"{walk.__name__} {split}")
 
 
 @pytest.mark.parametrize("s,c_slab", [(13, 4), (13, 8), (16, 8), (9, 12)])
 def test_flash_prefill_qslab_scheme_bitexact(s, c_slab):
-    """The engine's chunked-prefill decomposition — per query slab, a
-    carry-out pass over the history then a causal carry-in pass over the
-    slab's own KV — concatenates to exactly the one-shot output for every
-    slab size, including ragged final slabs."""
-    chunk = 4
-    rng = np.random.RandomState(12)
-    q = jnp.asarray(rng.standard_normal((s, 4, 16)).astype(np.float32))
-    k = jnp.asarray(rng.standard_normal((s, 2, 16)).astype(np.float32))
-    v = jnp.asarray(rng.standard_normal((s, 2, 16)).astype(np.float32))
-    one = flash_prefill(q, k, v, acc=ACC, chunk=chunk, block_q=8)
+    """The engine's chunked-prefill discipline — per query slab, write the
+    slab's K/V into its int8 pages, then one kernel call over the
+    post-write arena with traced ``q_offset``/``q_len`` and the slab padded
+    to a fixed width — concatenates to exactly the one-shot output for
+    every slab size, including ragged final slabs, and leaves the same
+    arena bytes."""
+    q, k, v = _prefill_qkv(12, s)
+    pages, row = _prefill_row(s)
+    arena, fmt = _prefill_arena(k, v, pages, packed=True)
+    one = flash_prefill_paged(q, *arena, row, 0, s, s, kv_fmt=fmt, acc=ACC,
+                              block_q=8)
+    kp = vp = jnp.zeros_like(arena[0])
+    kse = vse = jnp.zeros_like(arena[2])
     outs, t0 = [], 0
     while t0 < s:
         t1 = min(t0 + c_slab, s)
-        carry = None
-        if t0 > 0:
-            carry = flash_prefill(q[t0:t1], k[:t0], v[:t0], acc=ACC,
-                                  chunk=chunk, block_q=8, q_offset=t0,
-                                  return_carry=True)
-        o = flash_prefill(q[t0:t1], k[t0:t1], v[t0:t1], acc=ACC,
-                          chunk=chunk, block_q=8, q_offset=t0,
-                          kv_offset=t0, carry=carry)
-        outs.append(np.asarray(o))
+        slab = jnp.asarray(pages[t0 // _PS:-(-t1 // _PS)], jnp.int32)
+        kp, kse = KV.write_prompt(kp, kse, k[t0:t1], slab, fmt)
+        vp, vse = KV.write_prompt(vp, vse, v[t0:t1], slab, fmt)
+        qs = jnp.zeros((c_slab, _H, _DH), jnp.float32).at[:t1 - t0].set(
+            q[t0:t1])
+        o = flash_prefill_paged(qs, kp, vp, kse, vse, row, jnp.int32(t0),
+                                jnp.int32(t1 - t0), jnp.int32(t1),
+                                kv_fmt=fmt, acc=ACC, block_q=8)
+        outs.append(np.asarray(o[:t1 - t0]))
         t0 = t1
     np.testing.assert_array_equal(np.concatenate(outs, 0), np.asarray(one))
+    for got, want in zip((kp, vp, kse, vse), arena):
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
 
 
-def test_flash_prefill_rejects_unaligned_resume():
-    """A mid-block resumption would insert an extra carry-rounding event;
-    the kernel refuses it outright (the planner prices the hypothetical
-    via ``extra_carry_events`` instead)."""
-    rng = np.random.RandomState(13)
-    q = jnp.asarray(rng.standard_normal((4, 2, 8)).astype(np.float32))
-    with pytest.raises(ValueError, match="multiple of chunk"):
-        flash_prefill(q, q[:, :1], q[:, :1], acc=ACC, chunk=4, kv_offset=2)
+def test_flash_prefill_never_reads_past_the_live_pages():
+    """Page-row entries at or past ``ceil(kv_len / page_size)`` are never
+    read: pointed at a page of NaNs, the kernel still equals the oracle
+    over the clean row (the oracle walks every entry, so it runs on the
+    clean row only).  Two geometries put such pages in the causal view: a
+    slab padded past its live rows (raw carry out, so padded rows show),
+    and a history pass whose queries sit past ``kv_len``."""
+    s = 13
+    q, k, v = _prefill_qkv(13, s)
+    pages, clean = _prefill_row(s, width=6)
+    arena, _ = _prefill_arena(k, v, pages, packed=False)
+    nan_page = 1
+    assert nan_page not in pages
+    kp, vp, kse, vse = (arena[0].at[nan_page].set(jnp.nan),
+                        arena[1].at[nan_page].set(jnp.nan), *arena[2:])
+    for q_off, q_len, kv_len, width, carry in ((0, s, s, 24, True),
+                                               (8, 5, 8, 8, False)):
+        qs = jnp.zeros((width, _H, _DH), jnp.float32).at[:q_len].set(
+            q[q_off:q_off + q_len])
+        dirty = clean.at[-(-kv_len // _PS):].set(nan_page)
+        geom = (q_off, q_len, kv_len)
+        ref = flash_prefill_paged_reference(qs, *arena, clean, *geom,
+                                            acc=ACC, return_carry=carry)
+        out = flash_prefill_paged(qs, kp, vp, kse, vse, dirty, *geom,
+                                  acc=ACC, block_q=8, return_carry=carry)
+        for got, want in zip(out if carry else [out], ref if carry else [ref]):
+            np.testing.assert_array_equal(np.asarray(got), np.asarray(want),
+                                          err_msg=str(geom))
+            assert np.all(np.isfinite(np.asarray(got)))
 
 
 @pytest.mark.parametrize("n,c_slab", [(13, 8), (16, 8), (9, 12)])
@@ -312,14 +378,10 @@ def test_write_prompt_then_append_token_roundtrip():
     ar = KV.init_arena(pc)
     ka, kse = ar["k"][0], ar["k_se"][0]
     x = jnp.asarray(rng.standard_normal((6, 2, 8)).astype(np.float32))
-    ka, kse, deq = KV.write_prompt(ka, kse, x, jnp.asarray([1, 2]), fmt)
-    assert deq.shape == x.shape
-    # the dequantized view is what the arena holds
-    np.testing.assert_array_equal(
-        np.asarray(deq[:4]),
-        np.asarray(KV.dequantize_pages(ka, kse, fmt)[1]).transpose(1, 0, 2))
+    ka, kse = KV.write_prompt(ka, kse, x, jnp.asarray([1, 2]), fmt)
+    deq = KV.dequantize_pages(ka, kse, fmt)
     # token 6 lands in page 2 slot 2 under page 2's EXISTING scale, leaving
-    # the earlier tokens' codes untouched
+    # the earlier tokens' codes and decoded values untouched
     tok = jnp.asarray(rng.standard_normal((1, 2, 8)).astype(np.float32))
     ka2, kse2 = KV.append_token(ka, kse, tok, jnp.asarray([2]),
                                 jnp.asarray([2]), fmt)
@@ -327,26 +389,42 @@ def test_write_prompt_then_append_token_roundtrip():
     np.testing.assert_array_equal(np.asarray(ka2[1]), np.asarray(ka[1]))
     np.testing.assert_array_equal(np.asarray(ka2[2, :, :2]),
                                   np.asarray(ka[2, :, :2]))
+    deq2 = KV.dequantize_pages(ka2, kse2, fmt)
+    np.testing.assert_array_equal(np.asarray(deq2[1]), np.asarray(deq[1]))
+    np.testing.assert_array_equal(np.asarray(deq2[2, :, :2]),
+                                  np.asarray(deq[2, :, :2]))
     # a padded-row write (page_id 0) only ever touches the null page
     ka3, _ = KV.append_token(ka2, kse2, tok, jnp.asarray([0]),
                              jnp.asarray([0]), fmt)
     np.testing.assert_array_equal(np.asarray(ka3[1:]), np.asarray(ka2[1:]))
 
 
-def test_gather_pages_matches_write_prompt_view():
-    """The chunked-prefill history view must be the exact values the
-    cache holds — identical to what write_prompt returned when the pages
-    were written."""
+def test_write_prompt_pages_hold_the_rounded_prompt():
+    """A prompt written over two non-contiguous pages with a ragged tail
+    reads back (``dequantize_pages``) as its (1,5,2) rounding under each
+    page's power-of-two scale, and the tail slots past the prompt hold
+    code 0."""
     rng = np.random.RandomState(15)
     fmt = FP8_152
     pc = KV.PagedKVConfig(n_layers=1, n_kv_heads=2, head_dim=8,
                           n_pages=6, page_size=4, kv_fmt=fmt)
     ar = KV.init_arena(pc)
-    ka, kse = ar["k"][0], ar["k_se"][0]
-    x = jnp.asarray(rng.standard_normal((8, 2, 8)).astype(np.float32))
-    ka, kse, deq = KV.write_prompt(ka, kse, x, jnp.asarray([3, 1]), fmt)
-    view = KV.gather_pages(ka, kse, jnp.asarray([3, 1]), fmt)
-    np.testing.assert_array_equal(np.asarray(view), np.asarray(deq))
+    x = jnp.asarray(rng.standard_normal((7, 2, 8)).astype(np.float32) * 5.0)
+    pages = [3, 1]
+    ka, kse = KV.write_prompt(ar["k"][0], ar["k_se"][0], x,
+                              jnp.asarray(pages), fmt)
+    deq = np.asarray(KV.dequantize_pages(ka, kse, fmt))
+    xs = np.asarray(x)
+    for i, pg in enumerate(pages):
+        blk = xs[4 * i:4 * i + 4]                       # (<=4, KV, dh)
+        se = int(np.floor(np.log2(np.abs(blk).max())))
+        assert int(kse[pg]) == se
+        want = np.asarray(quantize_block(jnp.asarray(blk) * 2.0 ** -se,
+                                         fmt.e, fmt.m)) * 2.0 ** se
+        got = deq[pg].transpose(1, 0, 2)[:len(blk)]     # token-major
+        np.testing.assert_array_equal(got, want)
+    # the ragged tail: token 7 of page 1 was never written
+    assert np.all(np.asarray(ka[1, :, 3:]) == 0)
 
 
 def test_swap_roundtrip_byte_identical():
@@ -360,10 +438,10 @@ def test_swap_roundtrip_byte_identical():
     kv = KV.init_arena(pc)
     for layer in range(2):
         x = jnp.asarray(rng.standard_normal((7, 2, 8)).astype(np.float32)) * 9.0
-        k, kse, _ = KV.write_prompt(kv["k"][layer], kv["k_se"][layer], x,
-                                    jnp.asarray([1, 2]), fmt)
-        v, vse, _ = KV.write_prompt(kv["v"][layer], kv["v_se"][layer], 2 * x,
-                                    jnp.asarray([1, 2]), fmt)
+        k, kse = KV.write_prompt(kv["k"][layer], kv["k_se"][layer], x,
+                                 jnp.asarray([1, 2]), fmt)
+        v, vse = KV.write_prompt(kv["v"][layer], kv["v_se"][layer], 2 * x,
+                                 jnp.asarray([1, 2]), fmt)
         kv = {"k": kv["k"].at[layer].set(k), "v": kv["v"].at[layer].set(v),
               "k_se": kv["k_se"].at[layer].set(kse),
               "v_se": kv["v_se"].at[layer].set(vse)}

@@ -219,63 +219,6 @@ def test_sr_stats_epilogue_neutral():
     assert raw.shape == (N_STATS,)
 
 
-# --------------------------- attention carries -----------------------------
-
-
-def _attn_operands(s=96, h=4, kv=2, dh=32, seed=5):
-    rng = np.random.default_rng(seed)
-    q = jnp.asarray(rng.standard_normal((s, h, dh)), jnp.float32)
-    k = jnp.asarray(rng.standard_normal((s, kv, dh)), jnp.float32)
-    v = jnp.asarray(rng.standard_normal((s, kv, dh)), jnp.float32)
-    return q, k, v
-
-
-def test_attention_sr_deterministic_and_blockq_invariant():
-    from repro.kernels.attention import flash_prefill
-
-    q, k, v = _attn_operands()
-    kw = dict(acc=(6, 6), chunk=32)
-    rne = flash_prefill(q, k, v, block_q=32, **kw)
-    a = flash_prefill(q, k, v, block_q=32, rounding="sr", sr_seed=SR_SEED,
-                      **kw)
-    b = flash_prefill(q, k, v, block_q=32, rounding="sr", sr_seed=SR_SEED,
-                      **kw)
-    c = flash_prefill(q, k, v, block_q=32, rounding="sr",
-                      sr_seed=SR_SEED + 1, **kw)
-    d = flash_prefill(q, k, v, block_q=64, rounding="sr", sr_seed=SR_SEED,
-                      **kw)
-    np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
-    np.testing.assert_array_equal(np.asarray(a), np.asarray(d))
-    assert not np.array_equal(np.asarray(a), np.asarray(c))
-    assert not np.array_equal(np.asarray(a), np.asarray(rne))
-
-
-def test_attention_sr_kernel_matches_reference():
-    from repro.kernels.attention import flash_prefill, flash_prefill_reference
-
-    q, k, v = _attn_operands()
-    for kw in (dict(), dict(rounding="sr", sr_seed=SR_SEED)):
-        out = flash_prefill(q, k, v, acc=(6, 6), chunk=32, block_q=32, **kw)
-        ref = flash_prefill_reference(q, k, v, acc=(6, 6), chunk=32, **kw)
-        np.testing.assert_array_equal(np.asarray(out), np.asarray(ref))
-
-
-def test_attention_sr_resume_equals_one_shot():
-    # chunked-prefill resumption re-derives the SAME dither bits (keyed on
-    # the absolute kv-block index), so the split walk is bitwise one-shot
-    from repro.kernels.attention import flash_prefill
-
-    q, k, v = _attn_operands()
-    kw = dict(acc=(6, 6), chunk=32, block_q=32, rounding="sr",
-              sr_seed=SR_SEED)
-    one = flash_prefill(q, k, v, **kw)
-    half = 64
-    o, m, l = flash_prefill(q, k[:half], v[:half], return_carry=True, **kw)
-    res = flash_prefill(q, k[half:], v[half:], kv_offset=half,
-                        carry=(o, m, l), **kw)
-    np.testing.assert_array_equal(np.asarray(res), np.asarray(one))
-
-
 # -------------------- Monte-Carlo unbiasedness (satellite) -----------------
 
 
